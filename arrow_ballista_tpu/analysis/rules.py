@@ -36,8 +36,8 @@ class HotPathPurityRule(Rule):
     ``np.asarray``/``jax.device_get``/``jax.device_put``/
     ``.block_until_ready()``/``.tolist()`` inside ops/kernels.py,
     ops/operators.py, ops/expressions.py each force a device<->host
-    sync (~75 ms fixed latency per transfer on remote-attached
-    TPU backends) and silently turn a fused device pipeline into a host
+    sync (a fixed latency per transfer on accelerator
+    backends) and silently turn a fused device pipeline into a host
     round-trip.  ``jax.device_put`` is additionally banned because direct
     uploads bypass the transfer accounting in models/batch.py (the device
     observatory would under-report h2d bytes).  Deliberate host-mode paths

@@ -181,7 +181,15 @@ class BallistaContext:
 
         ``endpoints=[(host, port), ...]`` connects to a scheduler FLEET:
         calls stick to the first reachable shard and fail over down the
-        list when it dies (docs/user-guide/ha.md)."""
+        list when it dies (docs/user-guide/ha.md).
+
+        A process that has started no jax backend yet is pinned to the
+        CPU platform here: the client runs no stage, and the chip belongs
+        to the executor.  One that wants a standalone engine on the device
+        as well creates that context first."""
+        from ..models.batch import pin_to_host
+
+        pin_to_host()
         ctx = BallistaContext(config, engine="remote")
         from .remote import RemoteCluster
 

@@ -2,7 +2,7 @@
 
 Parity: reference ballista/executor/src/executor.rs:56-166 (task execution
 with cancellation + metrics) and lib.rs:36-102 (result -> TaskStatus
-mapping with the failure taxonomy).  The reference's DedicatedExecutor
+mapping with the failure classification).  The reference's DedicatedExecutor
 (separate runtime for CPU-bound work) maps to a ThreadPoolExecutor here:
 XLA dispatch releases the GIL, so pool threads genuinely overlap host IO
 with device compute.
@@ -137,7 +137,7 @@ class Executor:
         This wrapper owns observability — the task span tree (parented on
         the job's execution span via ``task.trace``) and the process
         counters; ``_run_task_inner`` owns execution and the failure
-        taxonomy.  Spans attach to every outcome, so failed tasks profile
+        classification.  Spans attach to every outcome, so failed tasks profile
         too."""
         tid = task.task
         launch_ms = int(time.time() * 1000)

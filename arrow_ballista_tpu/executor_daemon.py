@@ -48,16 +48,6 @@ def main(argv=None) -> None:
                          "job/trace correlation fields)")
     args = ap.parse_args(argv)
 
-    # XLA's C++ stderr (absl) logs bypass python logging; persistent-cache
-    # AOT loads emit a ~3KB benign feature-mismatch ERROR per program
-    # (prefer-no-* tuning pseudo-features never match the host probe) —
-    # enough to wedge a daemon whose stderr pipe nobody drains.  Daemons
-    # report operational errors through python logging, so silence the
-    # C++ channel unless the operator overrides.
-    import os as _os
-
-    _os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
-
     from .utils.logsetup import init_logging
 
     init_logging(args.log_level, args.log_dir, args.log_file_name_prefix,
@@ -67,8 +57,15 @@ def main(argv=None) -> None:
 
     faulthandler.enable()
 
+    import jax
+
     from .executor.server import ExecutorServer
     from .net import wire
+
+    # the device this executor's stages run on: starting the backend here
+    # makes a chip that cannot be had fail at start-up, before the executor
+    # registers, and not in its first task
+    dev = jax.devices()[0]
 
     # connect-with-retry (reference executor_process.rs:194-232)
     deadline = time.monotonic() + args.connect_timeout_s
@@ -87,9 +84,10 @@ def main(argv=None) -> None:
         external_host=args.external_host, policy=args.scheduling_policy,
         flight_port=args.flight_port, metrics_port=args.metrics_port)
     server.start()
-    logging.info("executor %s on %s:%s (work_dir %s)",
+    logging.info("executor %s on %s:%s (work_dir %s, device %s/%s x%d)",
                  server.metadata.executor_id, server.rpc.host, server.rpc.port,
-                 server.work_dir)
+                 server.work_dir, dev.platform, dev.device_kind,
+                 len(jax.devices()))
 
     stop = []
     signal.signal(signal.SIGTERM, lambda *a: stop.append(1))

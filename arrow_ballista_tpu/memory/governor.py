@@ -18,7 +18,7 @@ Two pools:
 The reserve path is a failpoint (``executor.memory.reserve``): chaos
 runs deny or delay grants here to force the spill path and prove it
 bit-identical.  A denial raises :class:`~..utils.errors.MemoryExhausted`
-— retryable back-pressure by taxonomy, and explicitly exempted from
+— retryable back-pressure by classification, and explicitly exempted from
 quarantine strikes (scheduler/scheduler.py): an executor protecting
 itself must not be blamed into quarantine for it.
 

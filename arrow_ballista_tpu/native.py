@@ -30,10 +30,15 @@ def _build(so_name: str, source: str) -> Optional[str]:
             os.path.getmtime(so_path) >= os.path.getmtime(src_path):
         return so_path
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-o", so_path,
+    # several processes (test workers, executor daemons) may find the
+    # library missing at once: each links under its own name and renames
+    # into place, so none ever loads a half-written file
+    tmp_path = f"{so_path}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-o", tmp_path,
            src_path, "-lpthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp_path, so_path)
         return so_path
     except (subprocess.SubprocessError, FileNotFoundError) as e:
         stderr = getattr(e, "stderr", b"") or b""

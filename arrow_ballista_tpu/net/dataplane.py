@@ -246,7 +246,7 @@ def fetch_partition_batches(host: str, port: int, path: str, schema: Schema,
                     **(fault_ctx or {})) from decode_err
             STATS.record("remote", len(data))
             return physical_table_to_batches(table, schema, capacity=capacity)
-        except Exception as e:  # noqa: BLE001 — caller maps to its taxonomy
+        except Exception as e:  # noqa: BLE001 — caller maps to its classification
             err = e
             if attempt + 1 < retries:
                 _sleep_for_retry(policy, attempt, e)
@@ -509,7 +509,7 @@ def fetch_partition_stream(host: str, port: int, path: str, schema: Schema,
                            host=host, port=port, path=path,
                            **(fault_ctx or {})) from e
             raise
-        except Exception as e:  # noqa: BLE001 — caller maps to its taxonomy
+        except Exception as e:  # noqa: BLE001 — caller maps to its classification
             err = e
             if attempt + 1 < retries:
                 _sleep_for_retry(policy, attempt, e)

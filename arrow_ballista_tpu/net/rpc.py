@@ -105,7 +105,7 @@ class RpcServer:
             except BallistaError as e:
                 # mid-stream failure: the error frame takes the slot of the
                 # next chunk; the client sees ok=false and maps error_kind
-                # back to its exception taxonomy
+                # back to its exception classification
                 send_frame(sock, {"ok": False, "error": str(e),
                                   "error_kind": type(e).__name__})
             except Exception as e:  # noqa: BLE001 — report, keep serving

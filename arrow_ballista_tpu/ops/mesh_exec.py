@@ -147,7 +147,7 @@ def _shard_batch(big: ColumnBatch, mesh, n_dev: int):
         if padded != rows:
             pad = jnp.full((padded - rows,), fill, arr.dtype)
             arr = jnp.concatenate([arr, pad])
-        # ballista: allow=host-device-boundary — mesh placement, not a host crossing: the source is already device-resident; byte accounting lands with the shard_map port (ROADMAP #1)
+        # ballista: allow=host-device-boundary — mesh placement, not a host crossing: the source is already device-resident; its bytes are not accounted yet
         return jax.device_put(arr, sharding)
 
     return ({k: shard(v) for k, v in big.columns.items()},
@@ -329,7 +329,7 @@ class MeshAggregateExec(ExecutionPlan):
                                 big.dicts, hidden_specs=hidden)
         # deferred: the count becomes host-known for free when the shuffle
         # writer's packed fetch materializes this batch (an eager .num_rows
-        # costs a ~75 ms scalar sync per task on remote-attached devices)
+        # costs a scalar sync per task where remote_device() holds)
         deferred_rows(self.metrics(), "output_rows", result)
         self.metrics().add("mesh_devices", n_dev)
         return [result]
@@ -440,7 +440,7 @@ class MeshPartialAggregateExec(ExecutionPlan):
                                 big.dicts, hidden_specs=hidden)
         # deferred: the count becomes host-known for free when the shuffle
         # writer's packed fetch materializes this batch (an eager .num_rows
-        # costs a ~75 ms scalar sync per task on remote-attached devices)
+        # costs a scalar sync per task where remote_device() holds)
         deferred_rows(self.metrics(), "output_rows", result)
         self.metrics().add("mesh_devices", n_dev)
         return [result]
@@ -587,7 +587,7 @@ class MeshJoinExec(ExecutionPlan):
                 if padded != rows:
                     arr = jnp.concatenate(
                         [arr, jnp.full((padded - rows,), fill, arr.dtype)])
-                # ballista: allow=host-device-boundary — mesh placement, not a host crossing: the source is already device-resident; byte accounting lands with the shard_map port (ROADMAP #1)
+                # ballista: allow=host-device-boundary — mesh placement, not a host crossing: the source is already device-resident; its bytes are not accounted yet
                 return jax.device_put(arr, sharding)
 
             return ({k: pad(v) for k, v in cols.items()},
@@ -676,7 +676,7 @@ class MeshJoinExec(ExecutionPlan):
                              _unshard(out_mask), dicts)
         # deferred: the count becomes host-known for free when the shuffle
         # writer's packed fetch materializes this batch (an eager .num_rows
-        # costs a ~75 ms scalar sync per task on remote-attached devices)
+        # costs a scalar sync per task where remote_device() holds)
         deferred_rows(self.metrics(), "output_rows", result)
         self.metrics().add("mesh_devices", n_dev)
         return [result]

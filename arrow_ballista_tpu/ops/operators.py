@@ -504,10 +504,11 @@ class HashAggregateExec(ExecutionPlan):
         if self.mode == "partial" and getattr(self, "clustered", None) \
                 is not None and self.clustered[0] is None:
             # presorted-only clustering: no early filter, but the disorder
-            # flag must still gate.  The scalar sync costs ~75 ms/task on
-            # remote devices — a deliberate trade against the sort-program
-            # family it replaces, which COMPILES 30-110 s per shape on the
-            # TPU backend (capacity ladders mint several shapes per query)
+            # flag must still gate.  The scalar sync costs its fixed latency
+            # per task on remote devices — a deliberate trade against the
+            # sort-program family it replaces, which compiles for tens of
+            # seconds per shape on the TPU backend (capacity ladders mint
+            # several shapes per query)
             if disorder is not None:
                 # stale-stats guard rides the same sync: declared range
                 # vs observed min/max (both device scalars, one roundtrip)
@@ -706,7 +707,7 @@ class HashAggregateExec(ExecutionPlan):
         if disorder is not None:
             # ONE device->host roundtrip for all scalars (device_get
             # batches pytree leaves — separate bool() + int() calls would
-            # pay the ~75 ms fixed transfer latency once per scalar)
+            # pay the fixed transfer latency once per scalar)
             fetch = (live, disorder,
                      mismatch if mismatch is not None else np.False_)
             # ballista: allow=hot-path-purity,host-device-boundary — deliberate single batched scalar sync; a handful of scalar bytes, accounted as operator host time rather than transfer volume
@@ -982,7 +983,7 @@ class HashAggregateExec(ExecutionPlan):
         # the downstream shuffle writer's packed fetch sets _num_rows on
         # this same batch object, so by the task-status snapshot
         # (collect_plan_metrics -> to_dict) the count is free — an eager
-        # .num_rows would pay a ~75 ms scalar sync per task.  Weakrefs so
+        # .num_rows would pay a scalar sync per task.  Weakrefs so
         # the metrics queue never pins device buffers.
         res_ref, inp_ref = weakref.ref(result), weakref.ref(big)
         inp_cap = big.capacity
@@ -1303,8 +1304,8 @@ class JoinExec(ExecutionPlan):
         def wcount_fn(pcols, pmask, bh_sorted, laux, chunk_rows, n_windows):
             # per-window candidate counts for the budget-chunked probe
             # loop: ONE program + ONE host transfer for every window
-            # (a per-window scalar sync would cost ~75 ms each on
-            # remote-attached devices)
+            # (a per-window scalar sync would cost its fixed latency each
+            # where remote_device() holds)
             pk = [c.fn(pcols, laux) for c in lkeys]
             ph = K.hash64(pk)
             lo = jnp.searchsorted(bh_sorted, ph, side="left")
@@ -1368,8 +1369,8 @@ class JoinExec(ExecutionPlan):
             # pass (a full extra hash+searchsorted sweep) once one task
             # discovered the bucket — CPU only, where the post-join
             # int(total) check verifies exactness and retries; the remote
-            # path keeps the count pass as its only safety (the 75 ms
-            # scalar sync there costs more than the count saves)
+            # path keeps the count pass as its only safety (the scalar
+            # sync there was judged to cost more than the count saves)
             hint_state = getattr(self, "_out_cap_hint", None)
             hint = None
             if hint_state is not None and hint_state[0] == ctx.job_id:
@@ -1428,7 +1429,7 @@ class JoinExec(ExecutionPlan):
             # uses the same hi-lo arithmetic as the count pass, so this
             # retry can only fire if something drifts between the two
             # compiled programs.  On remote-attached devices the eager
-            # int(total) check would cost a ~75 ms scalar sync per task for
+            # int(total) check would cost a scalar sync per task for
             # a never-taken branch — skipped there (count and join run the
             # same arithmetic on the same inputs; a disagreement would be an
             # XLA miscompile, which no host-side retry rescues anyway).
@@ -1686,7 +1687,7 @@ class JoinExec(ExecutionPlan):
         if self.join_type == "inner":
             dicts.update(build.dicts)
         # all window counts in ONE program + ONE host transfer (per-window
-        # scalar syncs would cost ~75 ms each on remote-attached devices)
+        # scalar syncs would cost their fixed latency each)
         # ballista: allow=hot-path-purity — deliberate single batched transfer
         window_counts = np.asarray(wcfn(probe.columns, probe.mask, bh_sorted,
                                         laux, chunk_rows, chunks))

@@ -55,7 +55,7 @@ class MetricsSet:
 
     def add_deferred(self, name: str, fn):
         """Record a metric whose value would cost a device->host sync right
-        now (~75 ms fixed latency on remote-attached devices).  ``fn()``
+        now (a fixed latency where remote_device() holds).  ``fn()``
         must return the value, or None while it is not yet host-known —
         not-ready entries stay queued for the next snapshot.  Downstream
         materialization (the shuffle writer's packed fetch) normally makes

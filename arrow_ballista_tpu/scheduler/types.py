@@ -2,7 +2,7 @@
 
 Python mirrors of the reference's protobuf contract
 (reference ballista/core/proto/ballista.proto): task identity/status with
-the full failure taxonomy (ballista.proto:360-431), executor metadata and
+the full failure classification (ballista.proto:360-431), executor metadata and
 heartbeats (284-358), and task definitions (440-463).  These are plain
 dataclasses — the wire encoding for remote mode lives in
 ``arrow_ballista_tpu/net/wire.py`` and serializes exactly these shapes.
@@ -15,7 +15,7 @@ from typing import Dict, List, Optional
 
 from ..ops.shuffle import PartitionLocation, ShuffleWritePartition
 
-# failure taxonomy (ballista.proto:391-431 FailedTask oneof)
+# failure classification (ballista.proto:391-431 FailedTask oneof)
 EXECUTION_ERROR = "ExecutionError"      # fatal: fails the job
 FETCH_PARTITION_ERROR = "FetchPartitionError"  # re-run producer stage
 IO_ERROR = "IOError"                    # retryable on another executor
@@ -67,7 +67,7 @@ class TaskDescription:
 
 @dataclasses.dataclass
 class FailedReason:
-    kind: str  # one of the taxonomy constants
+    kind: str  # one of the classification constants
     message: str = ""
     # FetchPartitionError details (ballista.proto:399-404)
     map_stage_id: int = -1

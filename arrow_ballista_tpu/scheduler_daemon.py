@@ -51,15 +51,11 @@ def main(argv=None) -> None:
                          "job/trace correlation fields)")
     args = ap.parse_args(argv)
 
-    # XLA's C++ stderr (absl) logs bypass python logging; persistent-cache
-    # AOT loads emit a ~3KB benign feature-mismatch ERROR per program
-    # (prefer-no-* tuning pseudo-features never match the host probe) —
-    # enough to wedge a daemon whose stderr pipe nobody drains.  Daemons
-    # report operational errors through python logging, so silence the
-    # C++ channel unless the operator overrides.
-    import os as _os
+    # the scheduler plans and tracks; it runs no stage.  The chip belongs
+    # to the executor process, so this one stays on the CPU platform.
+    from .models.batch import pin_to_host
 
-    _os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
+    pin_to_host()
 
     from .utils.logsetup import init_logging
 
@@ -69,6 +65,8 @@ def main(argv=None) -> None:
     import faulthandler
 
     faulthandler.enable()
+
+    import jax
 
     from .scheduler.netservice import SchedulerNetService
     from .scheduler.scheduler import SchedulerConfig
@@ -88,8 +86,10 @@ def main(argv=None) -> None:
         cluster_url=args.cluster_backend,
         flight_port=None if args.flight_port < 0 else args.flight_port)
     svc.start()
-    logging.info("scheduler listening on %s:%s (rest: %s)", svc.host, svc.port,
-                 svc.rest.port if svc.rest else "disabled")
+    logging.info("scheduler listening on %s:%s (rest: %s, jax platforms: %s)",
+                 svc.host, svc.port,
+                 svc.rest.port if svc.rest else "disabled",
+                 jax.config.jax_platforms)
 
     stop = []
     signal.signal(signal.SIGTERM, lambda *a: stop.append(1))

@@ -1,4 +1,4 @@
-"""Error taxonomy.
+"""Error classification.
 
 Mirrors the reference's ``BallistaError`` retry semantics
 (reference ballista/core/src/error.rs:36-58, 228-277): the *kind* of a task

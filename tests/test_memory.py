@@ -416,7 +416,7 @@ def test_resource_exhausted_takes_no_quarantine_strike():
         server.shutdown()
 
 
-def test_resource_exhausted_taxonomy():
+def test_resource_exhausted_classification():
     """RESOURCE_EXHAUSTED is retryable (the scheduler re-runs the task,
     ideally elsewhere) AND bounds retries (count_to_failures, so a
     saturated cluster cannot loop a task forever) — while staying exempt

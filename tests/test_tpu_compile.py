@@ -1,0 +1,246 @@
+"""Compile the main path's device programs for a described v5e chip.
+
+No chip is attached here: the TPU's compiler is installed and compiles for
+a topology that is described (``v5e:2x2``), so what it refuses is found
+without chip time.  Nothing runs, so these say nothing about results or
+speed; each prints its compile seconds, because a sort that takes a minute
+to compile here takes it on the chip.
+
+Shapes are TPC-H SF1's under chip_smoke.py's configuration (batch size 2^20,
+8 shuffle partitions), read off a CPU run of the same queries.
+
+The topology is described inside a module-scoped fixture and nowhere at
+import: only one process may load the TPU library at a time, the suite
+runs under several workers that each import this file, and only the worker
+that is given the file may load it.
+"""
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from arrow_ballista_tpu.ops import kernels as K
+
+BATCH = 1 << 20  # ballista.batch.size of the SF1 runs
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    """Code that asks which backend it runs on still sees the CPU here:
+    steer the kernels onto their TPU branches, as tests/test_kernels.py
+    does."""
+    K._tpu_backend.cache_clear()
+    monkeypatch.setattr(K, "_tpu_backend", lambda: True)
+    yield
+    monkeypatch.undo()
+    K._tpu_backend.cache_clear()
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def compile_for_chip(name, fn, *args, **jit_kw):
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn, **jit_kw).lower(*args).compile()
+    print(f"\n[tpu-compile] {name}: {time.perf_counter() - t0:.1f}s")
+    return compiled
+
+
+@pytest.mark.parametrize("segments", [
+    pytest.param(64, id="q1-dense-onehot-matmul"),
+    pytest.param(4 * K._MATMUL_SEG_LIMIT, id="chunk-offset"),
+])
+def test_grouped_sums_i64_tpu_branch(one_chip, tpu_branches, segments):
+    """q1's aggregate: 8 int64 value vectors over one batch."""
+    vals = [sds((BATCH,), jnp.int64, one_chip) for _ in range(8)]
+    seg = sds((BATCH,), jnp.int32, one_chip)
+    compile_for_chip(
+        f"grouped_sums_i64 S={segments}",
+        lambda vals, seg: K.grouped_sums_i64(vals, seg, segments), vals, seg)
+
+
+@pytest.mark.parametrize("is_min", [True, False], ids=["min", "max"])
+def test_grouped_minmax_i64_tpu_branch(one_chip, tpu_branches, is_min):
+    v = sds((BATCH,), jnp.int64, one_chip)
+    ok = sds((BATCH,), jnp.bool_, one_chip)
+    seg = sds((BATCH,), jnp.int32, one_chip)
+    compile_for_chip(
+        f"grouped_minmax_i64 is_min={is_min}",
+        lambda v, ok, seg: K.grouped_minmax_i64(v, ok, seg, 1024, is_min),
+        v, ok, seg)
+
+
+def test_pack_for_host_mixed_columns(one_chip):
+    """int64, f64 and 32-bit columns in one packed transfer: the s32<->s64
+    bitcasts under x64 emulation and the separate f64 leaf."""
+    rows = 1 << 17
+    cols = {"k": sds((rows,), jnp.int64, one_chip),
+            "s": sds((rows,), jnp.int64, one_chip),
+            "avg": sds((rows,), jnp.float64, one_chip),
+            "d": sds((rows,), jnp.int32, one_chip),
+            "f": sds((rows,), jnp.float32, one_chip),
+            "b": sds((rows,), jnp.bool_, one_chip)}
+    mask = sds((rows,), jnp.bool_, one_chip)
+    raw = K.pack_for_host.__wrapped__
+    compile_for_chip(
+        "pack_for_host i64+f64+32-bit",
+        lambda cols, mask: raw(cols, mask, rows // 2, ("k", "s"), ("avg",),
+                               ("d", "f", "b")),
+        cols, mask)
+
+
+def test_join_build_sort_and_probe_q3_shapes(one_chip):
+    """q3's orders x lineitem join: one partition of orders as the build
+    side (2^18 slots), one lineitem batch as the probe."""
+    build_cap, out_cap = 1 << 18, BATCH
+
+    def join(bk, bmask, pk, pmask):
+        bh_sorted, border, _ = K.build_side_sort([bk], bmask)
+        pi, bp, valid, total = K.probe_join(K.hash64([pk]), pmask,
+                                            bh_sorted, out_cap)
+        bidx = border[bp]
+        return valid & bmask[bidx] & (pk[pi] == bk[bidx]), total
+
+    compile_for_chip(
+        "build_side_sort + probe_join", join,
+        sds((build_cap,), jnp.int64, one_chip),
+        sds((build_cap,), jnp.bool_, one_chip),
+        sds((BATCH,), jnp.int64, one_chip),
+        sds((BATCH,), jnp.bool_, one_chip))
+
+
+def test_sort_order_and_topk_q3_shapes(one_chip):
+    """q3's ORDER BY revenue desc, o_orderdate LIMIT 10 over one final
+    partition (2^14 slots)."""
+    n = 1 << 14
+    rev = sds((n,), jnp.int64, one_chip)
+    date = sds((n,), jnp.int32, one_chip)
+    mask = sds((n,), jnp.bool_, one_chip)
+    keys = lambda r, d: [(r, False), (d, True)]  # noqa: E731
+    # one program: topk_order is sort_order's head, and each device sort
+    # costs about twenty seconds of compiling
+    compile_for_chip(
+        "sort_order + topk_order",
+        lambda r, d, m: (K.sort_order(keys(r, d), m),
+                         K.topk_order(keys(r, d), m, 10)),
+        rev, date, mask)
+
+
+def test_fused_stage_q6_with_donation(one_chip, tpu_branches):
+    """q6's filter -> projection -> partial aggregate as ONE fused program,
+    lowered with the donation compile/fused.py asks for off the CPU."""
+    import pyarrow as pa
+
+    from arrow_ballista_tpu.compile.fused import FusedStageExec
+    from arrow_ballista_tpu.models import expr as E
+    from arrow_ballista_tpu.models.schema import DATE32, Field, Schema, decimal
+    from arrow_ballista_tpu.ops import operators as O
+    from arrow_ballista_tpu.ops.physical import MemoryScanExec, TaskContext
+    from arrow_ballista_tpu.utils.config import BallistaConfig
+
+    schema = Schema([Field("l_quantity", decimal(2)),
+                     Field("l_extendedprice", decimal(2)),
+                     Field("l_discount", decimal(2)),
+                     Field("l_shipdate", DATE32)])
+    table = pa.table({
+        "l_quantity": pa.array([1], pa.int64()),
+        "l_extendedprice": pa.array([1], pa.int64()),
+        "l_discount": pa.array([1], pa.int64()),
+        "l_shipdate": pa.array([9000], pa.int32())})
+    scan = MemoryScanExec(schema, table, 1, [])
+    col, lit = E.Column, E.Lit
+    pred = None
+    for p in (E.BinOp(">=", col("l_shipdate"), lit("1994-01-01", "date")),
+              E.BinOp("<", col("l_shipdate"), lit("1995-01-01", "date")),
+              E.BinOp(">=", col("l_discount"), lit(0.05)),
+              E.BinOp("<=", col("l_discount"), lit(0.07)),
+              E.BinOp("<", col("l_quantity"), lit(24))):
+        pred = p if pred is None else E.BinOp("and", pred, p)
+    filt = O.FilterExec(scan, pred)
+    proj = O.ProjectionExec(
+        filt, [(E.BinOp("*", col("l_extendedprice"), col("l_discount")),
+                "x")])
+    agg = O.HashAggregateExec(proj, [], [O.AggSpec("sum", col("x"), "rev")],
+                              "partial")
+    fused = FusedStageExec([agg, proj, filt], donate=True)
+    ctx = TaskContext(config=BallistaConfig(), job_id="tpu-compile")
+    thread, jfn, (comp_a, _group_c, _agg_c, _tracked) = fused._build(ctx)
+    auxs, dicts = fused._auxs_and_dicts(thread, {})
+    auxs = tuple(auxs) + (comp_a.aux_arrays(dicts),)
+    assert not jax.tree_util.tree_leaves(auxs), "q6 needs no lookup tables"
+
+    cols = {f.name: sds((BATCH,), f.dtype.np_dtype, one_chip) for f in schema}
+    mask = sds((BATCH,), jnp.bool_, one_chip)
+    # ObservedJit offers no .lower: compile the function it wraps, with the
+    # arguments static and donated as compile/fused.py's _build makes them
+    c = compile_for_chip(
+        "fused q6 filter+projection+partial-agg, donate (0, 1)",
+        jfn.__wrapped__, cols, mask, auxs, BATCH, (),
+        static_argnums=(3, 4), donate_argnums=(0, 1))
+    assert "fusion" in c.as_text()
+
+
+def test_mesh_exchange_all_to_all_on_four_devices(topo, tpu_branches,
+                                                  monkeypatch):
+    """The exchange across chips: the grouped aggregate's key repartition,
+    built by the program's own runner (parallel/distributed.py) over a mesh
+    of the four described devices.  Arguments are shapes with shardings;
+    the compiled text must hold an all-to-all."""
+    from arrow_ballista_tpu.parallel import distributed
+
+    mesh = Mesh(np.asarray(topo.devices), ("part",))
+    rows = NamedSharding(mesh, P("part"))
+    n = 4 * (1 << 18)
+
+    def lower_only(cache, lock, sig, build, args):
+        t0 = time.perf_counter()
+        compiled = build().lower(*args).compile()
+        print(f"\n[tpu-compile] mesh grouped aggregate: "
+              f"{time.perf_counter() - t0:.1f}s")
+        return compiled
+
+    monkeypatch.setattr(distributed, "_compile_once", lower_only)
+    run = distributed.distributed_grouped_aggregate(
+        mesh, ["l_orderkey"], [("l_quantity", "sum"), ("__ones", "sum")],
+        partial_capacity=1 << 16, final_capacity=1 << 16, axis="part")
+    cols = {"l_orderkey": sds((n,), jnp.int64, rows),
+            "l_quantity": sds((n,), jnp.int64, rows),
+            "__ones": sds((n,), jnp.int64, rows)}
+    compiled = run(cols, sds((n,), jnp.bool_, rows))
+    text = compiled.as_text()
+    assert "all-to-all" in text, "no all-to-all in the mesh program"
+    mem = compiled.memory_analysis()
+    print(f"[tpu-compile] mesh program bytes per device: "
+          f"args {mem.argument_size_in_bytes}, temp {mem.temp_size_in_bytes}")
