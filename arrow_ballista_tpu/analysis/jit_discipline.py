@@ -46,8 +46,9 @@ Four rules consume the model:
     float64 promotion are host round-trips or weak-type hazards that
     the shape-keyed trace cache cannot see.  Outside traced bodies:
     ``jax.device_get``/``jax.device_put`` in a function that never
-    calls ``record_transfer`` is an unaccounted transfer — the
-    observatory's byte counters silently lie about it.
+    opens ``device_wait``/``h2d`` (or calls ``record_transfer``) is an
+    unaccounted transfer — the observatory's byte counters and the
+    task's span tree silently lie about it.
 
 ``fusion-verdict-consistency``
     ``compile/fuse.py``'s ``DEFAULT_OPERATORS`` allowlist, the
@@ -988,14 +989,19 @@ class DonationSafetyRule(Rule):
 # rule 3: host-device-boundary
 # --------------------------------------------------------------------------
 
+# obs/device.py: the boundary scopes (a span plus the byte counters, from
+# the same two readings of the clock) and the bare counter call
+_TRANSFER_ACCOUNTING = ("record_transfer", "device_wait", "h2d")
+
+
 @register
 class HostDeviceBoundaryRule(Rule):
     name = "host-device-boundary"
     description = ("traced bodies must stay on-device (no host numpy, "
                    ".tolist/.item, float()/int()/bool() concretization, "
                    "or float64 promotion); device_get/device_put outside "
-                   "the accounted materialization sites must call "
-                   "record_transfer")
+                   "the accounted materialization sites must run inside "
+                   "device_wait/h2d (or call record_transfer)")
 
     def check(self, project: Project) -> Iterable[Violation]:
         model = build_model(project)
@@ -1077,7 +1083,7 @@ class HostDeviceBoundaryRule(Rule):
                     continue
                 dn = dotted_name(sub.func) or ""
                 leaf = dn.rsplit(".", 1)[-1]
-                if leaf == "record_transfer":
+                if leaf in _TRANSFER_ACCOUNTING:
                     accounted = True
                 elif leaf in ("device_get", "device_put") and (
                         "." not in dn or dn.split(".", 1)[0] in jax_names):
@@ -1086,8 +1092,8 @@ class HostDeviceBoundaryRule(Rule):
                 for line, leaf in transfers:
                     yield Violation(
                         self.name, mod.path, line,
-                        f"'{leaf}' in '{node.name}' without a "
-                        f"record_transfer call — the transfer is "
+                        f"'{leaf}' in '{node.name}' outside device_wait/"
+                        f"h2d and without record_transfer — the transfer is "
                         f"invisible to the device observatory's byte "
                         f"accounting (models/batch.py shows the "
                         f"sanctioned pattern)")

@@ -25,6 +25,7 @@ from ..catalog import CsvTable, MemoryTable, ParquetTable, SchemaCatalog, TableP
 from ..models import logical as L
 from ..models.batch import ColumnBatch
 from ..models.schema import Field, Schema
+from ..obs.tracing import ROOT, span
 from ..ops.physical import ExecutionPlan, TaskContext
 from ..scheduler.physical_planner import PhysicalPlanner, PlannedQuery
 from ..sql import ast
@@ -33,6 +34,28 @@ from ..sql.parser import parse_sql
 from ..sql.planner import SqlToRel, parse_type_name
 from ..utils.config import BallistaConfig
 from ..utils.errors import PlanningError
+
+
+def _collect(frame, decode=None):
+    """``client.collect``: execute, fetch and (with ``decode``, under a
+    ``decode`` span) convert one statement's batches, in the trace
+    ``ctx.sql`` began; the scheduler's ``job`` span is its child.  The
+    shared null context where that trace is empty (tracing off)."""
+    trace = frame._trace
+    with span("client.collect", "client",
+              {"trace_id": trace["trace_id"]} if trace else None):
+        batches = frame._execute()
+        if decode is None:
+            return batches
+        with span("decode", "client"):
+            return decode(batches)
+
+
+def _to_pandas(batches):
+    import pandas as pd
+
+    frames = [b.to_pandas() for b in batches]
+    return pd.concat(frames, ignore_index=True) if frames else pd.DataFrame()
 
 
 class BallistaDataFrame:
@@ -46,6 +69,9 @@ class BallistaDataFrame:
         self.ctx = ctx
         self.logical = logical
         self._static = static
+        # the trace ``ctx.sql`` began ({} = tracing off): ``client.collect``
+        # and everything under it continue it
+        self._trace: Dict[str, str] = {}
         # original statement text for pristine sql() SELECTs: lets the
         # standalone engine route through the serving caches (plan/result
         # reuse keyed on normalized text); None for DDL/EXPLAIN/derived
@@ -63,31 +89,32 @@ class BallistaDataFrame:
             return ""
         return optimize(self.logical).display()
 
-    def collect(self) -> List[ColumnBatch]:
+    def _execute(self) -> List[ColumnBatch]:
         if self.logical is None:
             return []
         return self.ctx._execute_logical(self.logical, self._sql_text)
+
+    def collect(self) -> List[ColumnBatch]:
+        return _collect(self)
 
     def to_arrow(self):
         import pyarrow as pa
 
         if self._static is not None:
             return pa.Table.from_pandas(self._static)
-        batches = self.collect()
-        tables = [b.to_arrow() for b in batches if b.num_rows > 0]
-        if not tables:
-            return batches[0].to_arrow() if batches else pa.table({})
-        return pa.concat_tables(tables)
+
+        def decode(batches):
+            tables = [b.to_arrow() for b in batches if b.num_rows > 0]
+            if not tables:
+                return batches[0].to_arrow() if batches else pa.table({})
+            return pa.concat_tables(tables)
+
+        return _collect(self, decode)
 
     def to_pandas(self):
-        import pandas as pd
-
         if self._static is not None:
             return self._static
-        batches = self.collect()
-        frames = [b.to_pandas() for b in batches]
-        out = pd.concat(frames, ignore_index=True) if frames else pd.DataFrame()
-        return out
+        return _collect(self, _to_pandas)
 
 
 class RemoteDataFrame:
@@ -98,27 +125,32 @@ class RemoteDataFrame:
         self.ctx = ctx
         self._sql = sql
         self._static = static  # pre-computed frame (SHOW …)
+        self._trace: Dict[str, str] = {}  # as BallistaDataFrame._trace
 
-    def collect(self) -> List[ColumnBatch]:
+    def _execute(self) -> List[ColumnBatch]:
         if self._sql is None:
             return []  # DDL / SHOW
         return self.ctx._remote.execute_sql(self._sql)
 
-    def to_pandas(self):
-        import pandas as pd
+    def collect(self) -> List[ColumnBatch]:
+        return _collect(self)
 
+    def to_pandas(self):
         if self._static is not None:
             return self._static
-        frames = [b.to_pandas() for b in self.collect()]
-        return pd.concat(frames, ignore_index=True) if frames else pd.DataFrame()
+        return _collect(self, _to_pandas)
 
     def to_arrow(self):
         import pyarrow as pa
 
         if self._static is not None:
             return pa.Table.from_pandas(self._static)
-        tables = [b.to_arrow() for b in self.collect() if b.num_rows > 0]
-        return pa.concat_tables(tables) if tables else pa.table({})
+
+        def decode(batches):
+            tables = [b.to_arrow() for b in batches if b.num_rows > 0]
+            return pa.concat_tables(tables) if tables else pa.table({})
+
+        return _collect(self, decode)
 
 
 class BallistaContext:
@@ -248,8 +280,18 @@ class BallistaContext:
 
     # --- SQL ------------------------------------------------------------
     def sql(self, sql: str) -> "BallistaDataFrame":
-        if self._remote is not None:
-            return self._remote_sql(sql)
+        """Parse and plan one statement.  ``client.sql`` is the root of the
+        statement's trace; the frame returned carries it to ``collect``."""
+        from ..utils.config import OBS_TRACING
+
+        with span("client.sql", "client",
+                  ROOT if self.config.get(OBS_TRACING) else None) as sp:
+            df = self._remote_sql(sql) if self._remote is not None \
+                else self._local_sql(sql)
+            df._trace = sp.context()
+            return df
+
+    def _local_sql(self, sql: str) -> "BallistaDataFrame":
         stmt = self._parse_cached(sql)
         if isinstance(stmt, ast.SetVariable):
             self.config.set(stmt.key, stmt.value)

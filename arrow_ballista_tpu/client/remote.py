@@ -14,6 +14,7 @@ from typing import Dict, List, Optional, Tuple
 from .. import serde
 from ..models.batch import ColumnBatch
 from ..net import wire
+from ..obs.tracing import current_context, span
 from ..utils.config import BallistaConfig
 from ..utils.errors import ExecutionError, ResourceExhausted
 
@@ -215,27 +216,56 @@ class RemoteCluster:
         """One submit+poll+fetch round.  Returns the batches, or None when
         the job was lost without a trace in the fleet's shared KV and the
         caller should resubmit (never when ``final``: then it raises)."""
-        from ..obs import new_trace_context
-
         # the client owns the trace root: the scheduler parents its job
-        # span on this context, executors parent task spans below that
-        payload, _ = self._call("execute_query",
-                                {"sql": sql,
-                                 "config": dict(self.config._settings),
-                                 "trace": new_trace_context()})
-        job_id = payload["job_id"]
+        # span on client.collect, executors parent task spans below that
+        trace = current_context()
+        with span("submit", "client") as sp:
+            payload, _ = self._call("execute_query",
+                                    {"sql": sql,
+                                     "config": dict(self.config._settings),
+                                     "trace": trace})
+            job_id = payload["job_id"]
+            sp.set(job_id=job_id, cached=bool(payload.get("cached")))
         if payload.get("cached"):
             # result-cache hit: no job ran; pull the parked bytes in one
             # round-trip instead of polling
             return self._fetch_cached(job_id)
+        # ``wait`` ends when this client learns of the terminal status, so
+        # ``wait.end - job.end`` is what the poll cost
+        with span("wait", "client", job_id=job_id) as sp:
+            status = self._poll(job_id, deadline, final, sp)
+        if status is None:
+            return None
+        if status.get("cached"):
+            return self._fetch_cached(job_id)
+        schema = serde.schema_from_obj(status["schema"])
+        batches: List[ColumnBatch] = []
+        with span("fetch", "client", job_id=job_id) as sp:
+            nbytes = 0
+            for part in sorted(status["locations"], key=int):
+                for obj in status["locations"][part]:
+                    loc = serde.location_from_obj(obj)
+                    if not loc.num_rows:
+                        continue
+                    nbytes += loc.num_bytes
+                    batches.extend(self._fetch(loc, schema))
+            sp.set(bytes=nbytes)
+        return batches
+
+    def _poll(self, job_id: str, deadline: float, final: bool,
+              sp) -> Optional[dict]:
+        """Poll ``get_job_status`` until the job is terminal: the
+        successful status, or None where the job was lost and the caller
+        should resubmit.  ``sp`` (the ``wait`` span) counts the polls."""
         lost_since: Optional[float] = None
+        polls = 0
         while True:
             status, _ = self._call("get_job_status", {"job_id": job_id})
+            polls += 1
+            sp.set(polls=polls)
             state = status["state"]
             if state == "successful":
-                if status.get("cached"):
-                    return self._fetch_cached(job_id)
-                break
+                return status
             if state == "not_found" and len(self._endpoints) > 1:
                 if status.get("owner") and status.get("endpoint"):
                     # a sibling named the current lease owner: re-stick
@@ -267,16 +297,6 @@ class RemoteCluster:
                 self._call("cancel_job", {"job_id": job_id})
                 raise ExecutionError(f"job {job_id} timed out")
             time.sleep(POLL_INTERVAL_S)
-
-        schema = serde.schema_from_obj(status["schema"])
-        batches: List[ColumnBatch] = []
-        for part in sorted(status["locations"], key=int):
-            for obj in status["locations"][part]:
-                loc = serde.location_from_obj(obj)
-                if not loc.num_rows:
-                    continue
-                batches.extend(self._fetch(loc, schema))
-        return batches
 
     # --- lifecycle control -----------------------------------------------
     def cancel_job(self, job_id: str) -> None:

@@ -38,6 +38,7 @@ from ..obs.device import observed_jit
 from ..ops import kernels as K
 from ..ops.expressions import ExprCompiler
 from ..ops.operators import (FilterExec, HashAggregateExec, ProjectionExec,
+                             _null_restore,
                              RenameExec, _substitute_scalars, null_check_of)
 from ..ops.physical import (ExecutionPlan, TaskContext, deferred_rows,
                             schema_sig, shared_program)
@@ -202,7 +203,8 @@ class FusedStageExec(ExecutionPlan):
             return raw_agg(cols, mask, auxs[-1], out_cap, key_ranges)
 
         jfn = observed_jit(self.fused_sig(), fused_agg,
-                           static_argnums=(3, 4), **donate_kw)
+                           static_argnums=(3, 4),
+                           variant=agg.program_variant(), **donate_kw)
         return (thread, jfn, (comp_a, group_c, agg_c, tracked))
 
     def _ensure_compiled(self, ctx: TaskContext):
@@ -349,8 +351,9 @@ class FusedStageExec(ExecutionPlan):
         for i, cnt in zip(tracked, out_vals[len(agg_c):]):
             name = agg_c[i][2]
             f = agg.schema.field(name)
-            sent = jnp.asarray(f.dtype.null_sentinel, dtype=f.dtype.np_dtype)
-            cols[name] = jnp.where(cnt > 0, cols[name], sent)
+            cols[name] = _null_restore(cnt, cols[name],
+                                       f.dtype.np_dtype.type(
+                                           f.dtype.null_sentinel))
         result = ColumnBatch(agg.schema, cols, out_mask, dicts)
 
         # adaptive passthrough probe (same thresholds as the interpreted

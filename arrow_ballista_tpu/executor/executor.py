@@ -130,9 +130,10 @@ class Executor:
             journal.set_actor(metadata.executor_id)
 
     # --- task execution --------------------------------------------------
-    def run_task(self, task: TaskDescription) -> TaskStatus:
+    def run_task(self, task: TaskDescription,
+                 launch_ns: Optional[int] = None) -> TaskStatus:
         """Execute one task synchronously (callers use ``submit_task`` for
-        pool execution).
+        pool execution; ``launch_ns`` is when the launch reached it).
 
         This wrapper owns observability — the task span tree (parented on
         the job's execution span via ``task.trace``) and the process
@@ -140,12 +141,16 @@ class Executor:
         classification.  Spans attach to every outcome, so failed tasks profile
         too."""
         tid = task.task
-        launch_ms = int(time.time() * 1000)
+        start_ns = time.time_ns()
+        launch_ns = launch_ns or start_ns
+        launch_ms = launch_ns // 1_000_000
         recorder = None
         if self._tracing:
             from ..obs.tracing import TaskSpanRecorder
 
             trace = task.trace or {}
+            # launch_ns -> start_ns is the wait for a slot: the launch
+            # arrived, a pool thread picked it up
             recorder = TaskSpanRecorder(
                 trace.get("trace_id"), trace.get("span_id", ""),
                 name=f"task {tid.job_id}/{tid.stage_id}/{tid.partition}",
@@ -154,9 +159,34 @@ class Executor:
                        "partition": tid.partition,
                        "task_attempt": tid.task_attempt,
                        "executor_id": self.metadata.executor_id,
+                       "launch_ns": launch_ns, "start_ns": start_ns,
                        "actor": f"executor {self.metadata.executor_id}",
                        "lane": f"stage {tid.stage_id} / p{tid.partition}"})
-        t0 = time.perf_counter()
+        try:
+            status = self._run_task_observed(task, launch_ms, recorder)
+        except BaseException:
+            if recorder is not None:
+                recorder.finish("error")  # leave the thread's stack clean
+            raise
+        if recorder is not None:
+            if status.shuffle_writes:
+                recorder.annotate(
+                    rows_written=int(sum(w.num_rows
+                                         for w in status.shuffle_writes)),
+                    bytes_shuffled=int(sum(w.num_bytes
+                                           for w in status.shuffle_writes)),
+                    output_partitions=len(status.shuffle_writes))
+            status.spans = recorder.finish(
+                "ok" if status.state == "success" else status.state)
+        self.metrics.record_task(status,
+                                 (time.time_ns() - start_ns) / 1e9)
+        return status
+
+    def _run_task_observed(self, task: TaskDescription, launch_ms: int,
+                           recorder) -> TaskStatus:
+        """The log, device-accounting and flight-recorder scopes around
+        one task."""
+        tid = task.task
         from ..obs import device as device_obs
         from ..obs import journal
         from ..utils.logsetup import log_scope
@@ -189,17 +219,6 @@ class Executor:
             # (merged into the job timeline scheduler-side); empty buffer =
             # no wire key, same contract as device_stats
             status.journal = jbuf
-        if recorder is not None:
-            if status.shuffle_writes:
-                recorder.annotate(
-                    rows_written=int(sum(w.num_rows
-                                         for w in status.shuffle_writes)),
-                    bytes_shuffled=int(sum(w.num_bytes
-                                           for w in status.shuffle_writes)),
-                    output_partitions=len(status.shuffle_writes))
-            status.spans = recorder.finish(
-                "ok" if status.state == "success" else status.state)
-        self.metrics.record_task(status, time.perf_counter() - t0)
         return status
 
     def _run_task_inner(self, task: TaskDescription, launch_ms: int,
@@ -304,8 +323,10 @@ class Executor:
 
     def submit_task(self, task: TaskDescription,
                     on_done: Callable[[TaskStatus], None]) -> None:
+        launch_ns = time.time_ns()
+
         def run():
-            on_done(self.run_task(task))
+            on_done(self.run_task(task, launch_ns))
 
         self.pool.submit(run)
 

@@ -176,7 +176,8 @@ class ExecutorServer:
                  flight_port: int = -1,
                  metrics_port: int = -1,
                  heartbeat_interval_s: float = HEARTBEAT_INTERVAL_S,
-                 scheduler_endpoints: Optional[List[Tuple[str, int]]] = None):
+                 scheduler_endpoints: Optional[List[Tuple[str, int]]] = None,
+                 profile_dir: Optional[str] = None):
         import socket as socketmod
         import tempfile
         import uuid
@@ -314,6 +315,14 @@ class ExecutorServer:
                                           {"/metrics": _metrics,
                                            "/health": _health})
 
+        # a profiler session around task execution, written by stop() and
+        # by profile.write() (the daemon's SIGUSR2); None = no session
+        self.profile = None
+        if profile_dir:
+            from ..obs.device import ProfilerSession
+
+            self.profile = ProfilerSession(profile_dir)
+
         self.rpc.register("launch_multi_task", self._launch_multi_task)
         self.rpc.register("cancel_tasks", self._cancel_tasks)
         self.rpc.register("cancel_task", self._cancel_task)
@@ -326,6 +335,8 @@ class ExecutorServer:
 
     # --- lifecycle -------------------------------------------------------
     def start(self, register: bool = True) -> None:
+        if self.profile is not None:
+            self.profile.start()
         self.rpc.start()
         if self.flight is not None:
             self.flight.start()
@@ -468,6 +479,8 @@ class ExecutorServer:
             except Exception:  # noqa: BLE001 — scheduler may be gone
                 pass
         self.executor.shutdown()
+        if self.profile is not None:
+            self.profile.write(reopen=False)
         self.rpc.stop()
         if self.flight is not None:
             self.flight.stop()

@@ -36,6 +36,11 @@ def main(argv=None) -> None:
                     help="observability HTTP port serving prometheus "
                          "/metrics and /health (0 = ephemeral, "
                          "-1 = disabled)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="keep a jax.profiler session open around task "
+                         "execution and write it here on shutdown and on "
+                         "SIGUSR2 (device operations plus the executor's "
+                         "task/operator/device_wait spans, one clock)")
     ap.add_argument("--log-level", default="INFO")
     ap.add_argument("--log-dir", default=None,
                     help="write rotating log files here instead of stderr")
@@ -82,18 +87,25 @@ def main(argv=None) -> None:
         args.scheduler_host, args.scheduler_port, args.bind_host,
         args.bind_port, args.work_dir, args.concurrent_tasks,
         external_host=args.external_host, policy=args.scheduling_policy,
-        flight_port=args.flight_port, metrics_port=args.metrics_port)
+        flight_port=args.flight_port, metrics_port=args.metrics_port,
+        profile_dir=args.profile_dir)
     server.start()
     logging.info("executor %s on %s:%s (work_dir %s, device %s/%s x%d)",
                  server.metadata.executor_id, server.rpc.host, server.rpc.port,
                  server.work_dir, dev.platform, dev.device_kind,
                  len(jax.devices()))
 
-    stop = []
+    stop, write = [], []
     signal.signal(signal.SIGTERM, lambda *a: stop.append(1))
     signal.signal(signal.SIGINT, lambda *a: stop.append(1))
+    if server.profile is not None:
+        signal.signal(signal.SIGUSR2, lambda *a: write.append(1))
     while not stop:
         time.sleep(0.5)
+        if write:
+            write.clear()
+            server.profile.write()
+            logging.info("profile written under %s", args.profile_dir)
     logging.info("executor draining %d tasks", server.executor.active_tasks())
     server.drain_and_stop()
 

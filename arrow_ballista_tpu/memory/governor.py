@@ -33,6 +33,7 @@ import threading
 from typing import Dict, Optional
 
 from .. import faults
+from ..obs.tracing import TracedLock
 from ..utils.config import (
     MEM_DEVICE_BUDGET,
     MEM_HOST_BUDGET,
@@ -134,7 +135,7 @@ class MemoryGovernor:
 
     def __init__(self, host_budget: int = 0, device_budget: int = 0,
                  spill_enabled: bool = True):
-        self._lock = threading.Lock()
+        self._lock = TracedLock("memory_governor")
         self._budget = {"host": int(host_budget),
                         "device": int(device_budget)}
         self._reserved = {p: 0 for p in POOLS}

@@ -26,7 +26,6 @@ with schema + dictionaries.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Dict, Optional, Sequence
 
 import numpy as np
@@ -154,9 +153,8 @@ class ColumnBatch:
         # ONE transfer call for the whole batch: per-column jnp.asarray would
         # pay a host->device dispatch round-trip per column
         nbytes = mask.nbytes + sum(c.nbytes for c in cols.values())
-        t0 = time.perf_counter()
-        cols, mask = jax.device_put((cols, mask))
-        device_obs.record_transfer("h2d", nbytes, time.perf_counter() - t0)
+        with device_obs.h2d(nbytes):
+            cols, mask = jax.device_put((cols, mask))
         return ColumnBatch(schema, cols, mask, dicts, num_rows=n)
 
     @staticmethod
@@ -172,7 +170,8 @@ class ColumnBatch:
     @property
     def num_rows(self) -> int:
         if self._num_rows is None:
-            self._num_rows = int(jnp.sum(self.mask))
+            with device_obs.device_wait("scalar"):
+                self._num_rows = int(_live_rows(self.mask))
         return self._num_rows
 
     def column(self, name: str) -> jnp.ndarray:
@@ -247,13 +246,12 @@ class ColumnBatch:
         cols = dict(self.columns)
         cols.update(extra32)
         while True:
-            t0 = time.perf_counter()
-            buf, fbuf = jax.device_get(pack_for_host(
-                cols, self.mask, target, namesi64, namesf64, names32))
-            device_obs.record_transfer(
-                "d2h",
-                buf.nbytes + (fbuf.nbytes if fbuf is not None else 0),
-                time.perf_counter() - t0)
+            packed = pack_for_host(cols, self.mask, target, namesi64,
+                                   namesf64, names32)
+            with device_obs.device_wait("d2h") as wait:
+                buf, fbuf = jax.device_get(packed)
+                wait.nbytes = buf.nbytes + (fbuf.nbytes if fbuf is not None
+                                            else 0)
             out, n = unpack_from_host(buf, fbuf, target, i64, f64, f32)
             if out is not None:
                 break
@@ -441,6 +439,9 @@ def _concat_impl(cols_list, mask_list, pad: int):
     mask = jnp.concatenate(mparts) if len(mparts) > 1 else mparts[0]
     return cols, mask
 
+
+_live_rows = device_obs.observed_jit("batch.num_rows",
+                                     lambda mask: jnp.sum(mask))
 
 _concat_device = device_obs.observed_jit("batch.concat", _concat_impl,
                                          static_argnames=("pad",))

@@ -2,21 +2,25 @@
 
 Parity: the reference crate's `ballista/core/src/metrics` +
 tracing-opentelemetry wiring, reduced to the pieces this engine needs —
-a span layer propagated client -> scheduler -> executor -> operator, a
-per-job profile ring buffer behind the REST API, and a pluggable span
-collector (noop / in-memory / OTLP-shaped export hook).
+a span layer propagated client -> scheduler -> executor -> operator ->
+device boundary, a process-wide ring of finished spans, a per-job profile
+ring buffer behind the REST API, and a pluggable span collector (noop /
+the ring / OTLP-shaped export hook).
 """
 from .tracing import (  # noqa: F401
-    InMemorySpanCollector,
+    RING,
+    ROOT,
     NoopSpanCollector,
     OtlpSpanCollector,
     Span,
     SpanCollector,
+    SpanRing,
     TaskSpanRecorder,
+    TracedLock,
     make_collector,
     new_span_id,
-    new_trace_context,
     new_trace_id,
+    span,
     span_from_obj,
     span_to_obj,
 )

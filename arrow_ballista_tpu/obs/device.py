@@ -7,8 +7,10 @@ JAX layer underneath, the part that actually decides single-query speed
 on an accelerator:
 
 - **JIT compiles / retraces / cache hits** per (operator signature,
-  shape key).  ``observed_jit`` wraps ``jax.jit`` and mirrors XLA's own
-  trace-cache discipline: arrays key by (shape, dtype), static args by
+  shape key).  ``observed_jit`` wraps ``jax.jit``, names the program
+  after its signature (the name XLA, the profiler and the compile cache
+  see) and mirrors XLA's own trace-cache discipline: arrays key by
+  (shape, dtype), static args by
   value, traced Python scalars by type only.  First key seen through a
   wrapper is a *compile*, every later new key is a *retrace*, a repeat
   key is a *cache hit*.  Compile wall-time is the dispatch time of the
@@ -16,7 +18,10 @@ on an accelerator:
   synchronously inside it).
 - **Host<->device transfer bytes** through the engine's two sanctioned
   materialization sites (``ColumnBatch.from_numpy`` / ``packed_numpy``,
-  models/batch.py) — the same boundary the hot-path-purity lint models.
+  models/batch.py) — the same boundary the hot-path-purity lint models —
+  and, as intervals, every place a task thread waits for the device:
+  ``device_wait`` / ``h2d`` scopes that are spans of obs/tracing.py and
+  feed the byte counters from the same two readings of the clock.
 - **Memory watermarks**: live device-buffer bytes (``jax.live_arrays``)
   and host RSS peak, sampled at task and operator boundaries.
 
@@ -43,10 +48,14 @@ null context manager.
 from __future__ import annotations
 
 import contextlib
+import functools
 import inspect
+import re
 import threading
-import time
+import types
 from typing import Dict, Iterable, Optional, Tuple
+
+from . import tracing
 
 # process-wide switches; flipped from config by Executor.__init__ and the
 # local-engine entry points (module default matches the config default)
@@ -264,6 +273,46 @@ def record_transfer(direction: str, nbytes: int, seconds: float = 0.0) -> None:
         _record(f"{direction}_time", seconds)
 
 
+class _Boundary:
+    """A task thread at the host/device boundary, as an interval: a
+    ``device_wait`` span (the thread blocks until the device has produced
+    what it fetches; ``site`` ``d2h`` for a bulk fetch, ``scalar`` for a
+    flag or a count) or an ``h2d`` span (the enqueue cost of
+    ``device_put``).  Bulk transfers feed ``record_transfer`` from the
+    span's own two readings of the clock; set ``nbytes`` inside the block
+    where the size is only known afterwards.  Scalar waits carry a handful
+    of bytes and stay out of the byte counters."""
+    __slots__ = ("name", "site", "nbytes", "_scope", "_span", "_t0")
+
+    def __init__(self, name: str, site: str, nbytes: int = 0):
+        self.name, self.site, self.nbytes = name, site, nbytes
+
+    def __enter__(self):
+        self._scope = tracing.span(self.name, "device", site=self.site)
+        self._span = sp = self._scope.__enter__()
+        self._t0 = sp.start_ns or tracing.now_ns()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        sp = self._span
+        if self.nbytes:
+            sp.set(bytes=int(self.nbytes))
+        self._scope.__exit__(et, ev, tb)
+        if self.site != "scalar" and et is None:
+            record_transfer(self.site, self.nbytes,
+                            ((sp.end_ns or tracing.now_ns()) - self._t0)
+                            / 1e9)
+        return False
+
+
+def device_wait(site: str, nbytes: int = 0) -> _Boundary:
+    return _Boundary("device_wait", site, nbytes)
+
+
+def h2d(nbytes: int) -> _Boundary:
+    return _Boundary("h2d", "h2d", nbytes)
+
+
 def record_program_cache(hit: bool) -> None:
     """Hit/miss accounting for the process-wide shared_program cache
     (ops/physical.py)."""
@@ -305,6 +354,43 @@ def sample_watermarks() -> Optional[Tuple[int, int]]:
     return dev, rss
 
 
+class ProfilerSession:
+    """A ``jax.profiler`` session held open around an executor's task
+    execution (``executor_daemon --profile-dir``): the device's operations
+    and, in ``/host:CPU``, every span of obs/tracing.py on the thread that
+    did the work.  ``write`` stops the session, which writes
+    ``<dir>/plugins/profile/<time>/*.xplane.pb``, and opens the next one
+    unless told not to.  The profiler is process-wide: one session at a
+    time, whoever starts it."""
+
+    def __init__(self, directory: str):
+        self.directory = directory
+        self._lock = threading.Lock()
+        self._open = False
+
+    def start(self) -> None:
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0   # the program's own spans suffice
+        opts.host_tracer_level = 1
+        with self._lock:
+            if not self._open:
+                jax.profiler.start_trace(self.directory,
+                                         profiler_options=opts)
+                self._open = True
+
+    def write(self, reopen: bool = True) -> None:
+        import jax
+
+        with self._lock:
+            if self._open:
+                jax.profiler.stop_trace()
+                self._open = False
+        if reopen:
+            self.start()
+
+
 # --------------------------------------------------------------------------
 # observed_jit: the compile/retrace observatory
 # --------------------------------------------------------------------------
@@ -331,11 +417,42 @@ def _static_key(x):
         return ("s", repr(x))
 
 
+def program_name(sig: str, variant: str = "") -> str:
+    """The name a program carries into XLA (module ``jit_<name>``), the
+    profiler's trace and the persistent compilation cache's key: the
+    operator signature, plus the build site's ``variant`` where one
+    signature covers several programs (``agg_grouped__partial_k2``).  A
+    pure function of its arguments: the same in every process and run, or
+    every run would compile cold."""
+    name = re.sub(r"[^0-9A-Za-z_]+", "_", sig.replace(":", "__")).strip("_")
+    if variant:
+        name += "__" + re.sub(r"[^0-9A-Za-z_]+", "_", variant).strip("_")
+    return name
+
+
+def _renamed(fn, name: str):
+    """``fn`` under another ``__name__``, its signature untouched
+    (``static_argnames`` resolve through it)."""
+    if isinstance(fn, types.FunctionType):
+        out = types.FunctionType(fn.__code__, fn.__globals__, name,
+                                 fn.__defaults__, fn.__closure__)
+        out.__kwdefaults__ = fn.__kwdefaults__
+        out.__dict__.update(fn.__dict__)
+        out.__doc__, out.__module__ = fn.__doc__, fn.__module__
+    else:  # a partial, a bound method, a callable object
+        @functools.wraps(fn)
+        def out(*args, **kwargs):
+            return fn(*args, **kwargs)
+    out.__name__ = out.__qualname__ = name
+    return out
+
+
 class ObservedJit:
-    """A ``jax.jit`` wrapper that mirrors the trace cache's keying to
-    count compiles (first key), retraces (later new keys) and cache hits
-    (repeat keys), attributing each — plus compile wall-time — to the
-    enclosing operator/task scope.
+    """A ``jax.jit`` wrapper that names the program after what it computes
+    (``program_name(sig, variant)``) and mirrors the trace cache's keying
+    to count compiles (first key), retraces (later new keys) and cache
+    hits (repeat keys), attributing each — plus compile wall-time, as a
+    ``compile <name>`` span — to the enclosing operator/task scope.
 
     The wrapper travels with the closure through ``shared_program``, so
     its key set is shared exactly as far as the underlying executable
@@ -345,15 +462,16 @@ class ObservedJit:
     two racing first calls can both count a compile, which matches what
     XLA does on a trace race anyway."""
 
-    __slots__ = ("sig", "_fn", "_jfn", "_static_idx", "_static_names",
-                 "_seen", "__wrapped__")
+    __slots__ = ("sig", "name", "_fn", "_jfn", "_static_idx",
+                 "_static_names", "_seen", "__wrapped__")
 
     def __init__(self, sig: str, fn, static_argnums: Iterable[int] = (),
                  static_argnames: Iterable[str] = (),
-                 donate_argnums: Iterable[int] = ()):
+                 donate_argnums: Iterable[int] = (), variant: str = ""):
         import jax
 
         self.sig = sig
+        self.name = program_name(sig, variant)
         self._fn = fn
         self.__wrapped__ = fn
         kw = {}
@@ -366,7 +484,7 @@ class ObservedJit:
             # promises the donated inputs are dead after the call; XLA may
             # alias them into the outputs, eliding the copy
             kw["donate_argnums"] = tuple(donate_argnums)
-        self._jfn = jax.jit(fn, **kw)
+        self._jfn = jax.jit(_renamed(fn, self.name), **kw)
         idx = set(static_argnums or ())
         names = set(static_argnames or ())
         # resolve static names to positions for positional call sites
@@ -401,24 +519,27 @@ class ObservedJit:
             _record("jit_cache_hits", 1)
             return self._jfn(*args, **kwargs)
         first = not self._seen
-        t0 = time.perf_counter()
-        out = self._jfn(*args, **kwargs)
-        dt = time.perf_counter() - t0
+        with tracing.span(f"compile {self.name}", "device", sig=self.sig,
+                          retrace=not first) as sp:
+            t0 = sp.start_ns or tracing.now_ns()
+            out = self._jfn(*args, **kwargs)
         self._seen.add(key)
         _record("jit_compiles" if first else "jit_retraces", 1)
-        _record("jit_compile_time", dt)
+        _record("jit_compile_time",
+                ((sp.end_ns or tracing.now_ns()) - t0) / 1e9)
         return out
 
 
 def observed_jit(sig: str, fn=None, *, static_argnums: Iterable[int] = (),
                  static_argnames: Iterable[str] = (),
-                 donate_argnums: Iterable[int] = ()):
+                 donate_argnums: Iterable[int] = (), variant: str = ""):
     """Drop-in for ``jax.jit(fn, ...)`` with compile/retrace accounting
-    under operator signature ``sig``.  Usable inline
+    under operator signature ``sig``, the program named
+    ``program_name(sig, variant)``.  Usable inline
     (``observed_jit("filter", fn)``) or as a decorator
     (``@observed_jit("kernels.pack_for_host", static_argnames=(...))``)."""
     if fn is None:
         return lambda f: ObservedJit(sig, f, static_argnums, static_argnames,
-                                     donate_argnums)
+                                     donate_argnums, variant)
     return ObservedJit(sig, fn, static_argnums, static_argnames,
-                       donate_argnums)
+                       donate_argnums, variant)

@@ -20,13 +20,7 @@ from collections import OrderedDict
 from typing import Dict, List, Optional
 
 from .trace_event import spans_to_chrome
-from .tracing import (
-    Span,
-    SpanCollector,
-    make_collector,
-    new_trace_id,
-    now_ms,
-)
+from .tracing import ROOT, Span, SpanCollector, make_collector, now_ns, span
 
 # phase progression; on_finished closes whatever is still open
 _PHASES = ("admission", "planning", "execution")
@@ -103,13 +97,7 @@ class JobObservability:
                      trace: Optional[Dict[str, str]] = None) -> None:
         if not self.tracing:
             return
-        trace = trace or {}
-        root = Span(f"job {job_id}",
-                    trace.get("trace_id") or new_trace_id(),
-                    parent_id=trace.get("span_id", ""), kind="scheduler",
-                    attrs={"job_id": job_id, "actor": "scheduler",
-                           "lane": f"job {job_id}"})
-        jt = _JobTrace(job_id, root)
+        jt = _JobTrace(job_id, _job_span(job_id, trace))
         self._start_phase(jt, "admission")
         with self._lock:
             self._jobs.pop(job_id, None)
@@ -144,22 +132,11 @@ class JobObservability:
         epoch, then an execution phase for the relaunched tasks."""
         if not self.tracing:
             return
-        trace = trace or {}
-        root = Span(f"job {job_id} (adopted)",
-                    trace.get("trace_id") or new_trace_id(),
-                    parent_id=trace.get("span_id", ""), kind="scheduler",
-                    attrs={"job_id": job_id, "actor": "scheduler",
-                           "lane": f"job {job_id}", "adopted": True,
-                           "adoption_epoch": int(epoch),
-                           "adopted_by": scheduler_id})
+        root = _job_span(job_id, trace, " (adopted)", adopted=True,
+                         adoption_epoch=int(epoch), adopted_by=scheduler_id)
         jt = _JobTrace(job_id, root)
-        marker = Span("lease adoption", root.trace_id,
-                      parent_id=root.span_id, kind="scheduler",
-                      attrs={"job_id": job_id, "actor": "scheduler",
-                             "lane": f"job {job_id}",
-                             "adoption_epoch": int(epoch),
-                             "previous_owner": prev_owner,
-                             "adopted_by": scheduler_id})
+        marker = _child(root, "lease adoption", adoption_epoch=int(epoch),
+                        previous_owner=prev_owner, adopted_by=scheduler_id)
         marker.end()
         jt.phases[f"adoption@{epoch}"] = marker
         self._start_phase(jt, "execution")
@@ -182,16 +159,10 @@ class JobObservability:
             jt = self._jobs.pop(job_id, None)
         if jt is None:
             return
-        marker = Span("lease stand-down", jt.root.trace_id,
-                      parent_id=jt.root.span_id, kind="scheduler",
-                      attrs={"job_id": job_id, "actor": "scheduler",
-                             "lane": f"job {job_id}", "reason": why})
+        marker = _child(jt.root, "lease stand-down", reason=why)
         marker.end()
         jt.phases["stand-down"] = marker
-        for span in jt.phases.values():
-            if not span.end_ms:
-                span.end("stand-down")
-        jt.root.end("stand-down")
+        self._close(jt, "stand-down")
         spans = self._job_spans(jt, None)
         profile = self._build_profile(jt, None, None)
         profile["state"] = "stood-down"
@@ -214,15 +185,9 @@ class JobObservability:
             if self.profiles.get(job_id) is not None:
                 return  # double terminal status
             # job the scheduler adopted without a submit hook (recovery)
-            jt = _JobTrace(job_id, Span(
-                f"job {job_id}", new_trace_id(), kind="scheduler",
-                attrs={"job_id": job_id, "actor": "scheduler",
-                       "lane": f"job {job_id}"}))
-        ok = status.state == "successful"
-        for name, span in jt.phases.items():
-            if not span.end_ms:
-                span.end("ok" if ok else status.state)
-        jt.root.end("ok" if ok else status.state)
+            jt = _JobTrace(job_id, _job_span(job_id, None))
+        self._close(jt, "ok" if status.state == "successful"
+                    else status.state, graph)
         spans = self._job_spans(jt, graph)
         profile = self._build_profile(jt, status, graph)
         self.profiles.put(job_id, profile, spans)
@@ -256,10 +221,21 @@ class JobObservability:
         with self._lock:
             return self._jobs.get(job_id)
 
-    def _start_phase(self, jt: _JobTrace, name: str) -> None:
-        jt.phases[name] = Span(name, jt.root.trace_id,
-                               parent_id=jt.root.span_id, kind="scheduler",
-                               attrs=dict(jt.root.attrs))
+    def _start_phase(self, jt: _JobTrace, name: str,
+                     at_ns: Optional[int] = None) -> None:
+        jt.phases[name] = sp = _child(jt.root, name)
+        if at_ns:
+            sp.start_ns = at_ns
+
+    @staticmethod
+    def _close(jt: _JobTrace, status: str, graph=None) -> None:
+        """End the open phases (and whatever stage a failed job left open)
+        and the job on ONE reading of the clock, so the phases add up to
+        the job exactly."""
+        at = now_ns()
+        for sp in [*jt.phases.values(), *getattr(graph, "stage_spans", ())]:
+            sp.end(None if sp.end_ns else status, at)
+        jt.root.end(status, at)
 
     def _advance(self, job_id: str, next_phase: str) -> None:
         if not self.tracing:
@@ -268,9 +244,10 @@ class JobObservability:
             jt = self._jobs.get(job_id)
             if jt is None or next_phase in jt.phases:
                 return
-            for span in jt.phases.values():
-                span.end()
-            self._start_phase(jt, next_phase)
+            at = now_ns()   # one phase ends where the next begins
+            for sp in jt.phases.values():
+                sp.end(at_ns=at)
+            self._start_phase(jt, next_phase, at)
 
     @staticmethod
     def _task_spans(graph) -> List[Span]:
@@ -293,7 +270,9 @@ class JobObservability:
         return spans
 
     def _job_spans(self, jt: _JobTrace, graph) -> List[Span]:
-        return [jt.root] + list(jt.phases.values()) + self._task_spans(graph)
+        return [jt.root] + list(jt.phases.values()) \
+            + list(getattr(graph, "stage_spans", ())) \
+            + self._task_spans(graph)
 
     def _build_profile(self, jt: _JobTrace, status, graph) -> Dict:
         state = getattr(status, "state", None) or \
@@ -336,6 +315,20 @@ class JobObservability:
                 "tasks": tasks,
             })
         return prof
+
+
+def _job_span(job_id: str, trace: Optional[Dict[str, str]],
+              suffix: str = "", **attrs) -> Span:
+    """The job's root span: a child of the client's ``client.collect``
+    where the submission carried its context, else a trace of its own."""
+    return span(f"job {job_id}{suffix}", "scheduler", trace or ROOT,
+                job_id=job_id, actor="scheduler", lane=f"job {job_id}",
+                **attrs).begin()
+
+
+def _child(root: Span, name: str, **attrs) -> Span:
+    return span(name, "scheduler", root, job_id=root.attrs["job_id"],
+                actor="scheduler", lane=root.attrs["lane"], **attrs).begin()
 
 
 def _task_profile(info) -> Dict:

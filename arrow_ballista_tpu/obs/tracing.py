@@ -1,28 +1,45 @@
-"""Span layer: trace ids, span trees, and pluggable collectors.
+"""Span layer: one way to open a span, one in-memory store, pluggable export.
 
-A Span is a named wall-clock interval in a trace.  The scheduler opens a
-root "job" span per query (plus admission/planning/execution phase
-children); each executor task opens a task span parented on the job's
-execution span, and `TaskSpanRecorder.op_span` nests one child span per
-operator `execute` call.  Spans serialize to plain JSON dicts so they
-ride the existing wire format back with task status updates.
+A Span is a named interval in a trace, stamped in integer nanoseconds of
+the realtime clock (``time.time_ns``) — the clock ``jax.profiler`` stamps
+host events with, so a span lies where it belongs on a device profile
+(``profile_start_time`` of the trace's ``Task Environment`` plane plus an
+event's ``start_ns`` is the same clock).  ``start_ms``/``end_ms`` are
+derived, for serde, REST and the Chrome export.
 
-Collectors are the export seam: Noop (default), a bounded in-memory
-buffer, and an OTLP/HTTP-JSON-shaped exporter (stdlib urllib only; the
-payload matches the opentelemetry-proto JSON mapping closely enough for
-a generic OTLP gateway, and a custom `sink` callable can divert it).
+``span(name, kind, parent, **attrs)`` is the single entry point.  Used as a
+context manager it also enters a ``jax.profiler.TraceAnnotation`` of the
+same name, so with a profiler session open the span is in ``/host:CPU`` on
+the thread that did the work; with none open that costs one predicate.
+Intervals that begin and end on different threads (the scheduler's job,
+phase and stage spans) use ``span(...).begin()`` and ``Span.end()``: same
+clock, same store, no annotation.  The tree:
+
+    client.sql, client.collect -> submit / wait / fetch / decode   (client)
+      job -> admission / planning / execution -> stage <id>        (scheduler)
+        task -> operator -> device_wait / h2d / compile / lock_wait
+
+A finished span enters, once, the process-wide ``RING`` (bounded,
+drop-oldest, counted).  Task trees also ride ``TaskStatus.spans`` back to
+the scheduler.  Collectors are the export seam: noop (default), ``memory``
+(the ring itself) and an OTLP/HTTP-JSON-shaped exporter (stdlib urllib
+only; the payload matches the opentelemetry-proto JSON mapping closely
+enough for a generic OTLP gateway, and a custom ``sink`` can divert it).
 """
+import collections
 import contextlib
+import random
 import threading
 import time
 import urllib.request
 import uuid
-from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
+
+now_ns = time.time_ns
 
 
 def now_ms() -> float:
-    return time.time() * 1000.0
+    return time.time_ns() / 1e6
 
 
 def new_trace_id() -> str:
@@ -30,44 +47,209 @@ def new_trace_id() -> str:
 
 
 def new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return "%016x" % random.getrandbits(64)
 
 
-def new_trace_context() -> Dict[str, str]:
-    """Fresh propagation context: what a client attaches to a submission."""
-    return {"trace_id": new_trace_id(), "span_id": new_span_id()}
-
-
-@dataclass
 class Span:
-    name: str
-    trace_id: str = ""
-    span_id: str = field(default_factory=new_span_id)
-    parent_id: str = ""
-    kind: str = "internal"  # scheduler | executor | operator | internal
-    start_ms: float = field(default_factory=now_ms)
-    end_ms: float = 0.0
-    status: str = "ok"
-    attrs: Dict = field(default_factory=dict)
+    __slots__ = ("name", "trace_id", "span_id", "parent_id", "kind",
+                 "start_ns", "end_ns", "status", "attrs", "_ringed")
 
-    def end(self, status: Optional[str] = None) -> "Span":
-        if not self.end_ms:
-            self.end_ms = now_ms()
+    def __init__(self, name: str, trace_id: str = "",
+                 span_id: Optional[str] = None, parent_id: str = "",
+                 kind: str = "internal", start_ms: Optional[float] = None,
+                 end_ms: float = 0.0, status: str = "ok",
+                 attrs: Optional[Dict] = None,
+                 start_ns: Optional[int] = None, end_ns: int = 0):
+        self.name = name
+        self.trace_id = trace_id
+        self.span_id = span_id or new_span_id()
+        self.parent_id = parent_id
+        # client | scheduler | executor (a task) | operator | device | lock
+        # | internal
+        self.kind = kind
+        if start_ns is None:
+            start_ns = now_ns() if start_ms is None else int(start_ms * 1e6)
+        self.start_ns = start_ns
+        self.end_ns = end_ns or int(end_ms * 1e6)
+        self.status = status
+        self.attrs = {} if attrs is None else attrs
+        self._ringed = False
+
+    @property
+    def start_ms(self) -> float:
+        return self.start_ns / 1e6
+
+    @property
+    def end_ms(self) -> float:
+        return self.end_ns / 1e6
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+
+    def end(self, status: Optional[str] = None,
+            at_ns: Optional[int] = None) -> "Span":
+        """Close the span (first call wins) and enter it in ``RING``."""
+        if not self.end_ns:
+            self.end_ns = at_ns or now_ns()
         if status is not None:
             self.status = status
+        if not self._ringed:
+            self._ringed = True
+            RING.add(self)
         return self
 
     @property
     def duration_ms(self) -> float:
-        return max((self.end_ms or now_ms()) - self.start_ms, 0.0)
+        return max(((self.end_ns or now_ns()) - self.start_ns) / 1e6, 0.0)
 
     def context(self) -> Dict[str, str]:
         """Propagation context for children of this span."""
         return {"trace_id": self.trace_id, "span_id": self.span_id}
 
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, kind={self.kind!r}, "
+                f"{self.duration_ms:.3f} ms, {self.attrs})")
+
+
+class _NullSpan:
+    """What a disabled ``span()`` yields: takes attributes, keeps none."""
+    __slots__ = ()
+    start_ns = end_ns = 0
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def context(self) -> Dict[str, str]:
+        return {}
+
+    def __bool__(self) -> bool:
+        return False
+
+
+class _NullScope:
+    __slots__ = ()
+
+    def __enter__(self):
+        return NULL_SPAN
+
+    def __exit__(self, *exc):
+        return False
+
+
+NULL_SPAN = _NullSpan()
+_NULL_SCOPE = _NullScope()
+ROOT = object()     # ``parent=ROOT``: the span starts a trace of its own
+
+_tls = threading.local()
+_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+_ID_KEYS = ("job_id", "stage_id", "partition")
+_LANE_KEYS = ("actor", "lane")
+
+
+class _Scope:
+    """An open span on this thread's stack, inside its TraceAnnotation."""
+    __slots__ = ("span", "ids", "_ann")
+
+    def __init__(self, sp: Span, ids: Dict):
+        self.span, self.ids = sp, ids
+
+    def begin(self) -> Span:
+        """Cross-thread form: the caller ends it with ``Span.end()``."""
+        return self.span
+
+    def __enter__(self) -> Span:
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation as _annotation
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        stack.append(self)
+        self._ann = ann = _annotation(self.span.name, **self.ids)
+        self.span.start_ns = now_ns()
+        ann.__enter__()
+        return self.span
+
+    def __exit__(self, et, ev, tb):
+        self._ann.__exit__(et, ev, tb)
+        sp = self.span
+        sp.end("error" if et is not None and sp.status == "ok" else None)
+        _tls.stack.pop()
+        sink = getattr(_tls, "sink", None)
+        if sink is not None:
+            sink.append(sp)
+        return False
+
+
+def span(name: str, kind: str = "internal", parent=None, **attrs):
+    """Open a span.  ``parent``: ``None`` = the span open on this thread
+    (and the shared null context where there is none: tracing is off, or
+    the caller runs outside any traced task); ``ROOT`` = start a trace; a
+    ``Span`` or a propagation context ``{"trace_id", "span_id"}``."""
+    if parent is None:
+        stack = getattr(_tls, "stack", None)
+        if not stack:
+            return _NULL_SCOPE
+        up = stack[-1]
+        for k in _LANE_KEYS:  # the Chrome export's lanes
+            if k in up.span.attrs:
+                attrs.setdefault(k, up.span.attrs[k])
+        return _Scope(Span(name, up.span.trace_id,
+                           parent_id=up.span.span_id, kind=kind,
+                           attrs=attrs), up.ids)
+    if parent is ROOT:
+        trace_id, parent_id = new_trace_id(), ""
+    elif isinstance(parent, Span):
+        trace_id, parent_id = parent.trace_id, parent.span_id
+    else:
+        trace_id = parent.get("trace_id") or new_trace_id()
+        parent_id = parent.get("span_id", "")
+    ids = {"trace_id": trace_id}
+    for k in _ID_KEYS:
+        if k in attrs:
+            ids[k] = attrs[k]
+    return _Scope(Span(name, trace_id, parent_id=parent_id, kind=kind,
+                       attrs=attrs), ids)
+
+
+def current_context() -> Dict[str, str]:
+    """Propagation context of the span open on this thread, or ``{}``."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1].span.context() if stack else {}
+
+
+class TracedLock:
+    """A ``threading.Lock`` whose contended acquires are ``lock_wait``
+    spans: an acquire that succeeds at once costs one extra call."""
+    __slots__ = ("_lock", "name")
+
+    def __init__(self, name: str):
+        self._lock = threading.Lock()
+        self.name = name
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._lock.acquire(False):
+            return True
+        if not blocking:
+            return False
+        with span("lock_wait", "lock", lock=self.name):
+            return self._lock.acquire(True, timeout)
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def locked(self) -> bool:
+        return self._lock.locked()
+
+    def __enter__(self) -> bool:
+        return self.acquire()
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
+
 
 _SPAN_FIELDS = ("name", "trace_id", "span_id", "parent_id", "kind",
-                "start_ms", "end_ms", "status")
+                "start_ms", "end_ms", "status", "start_ns", "end_ns")
 
 
 def span_to_obj(s: Span) -> Dict:
@@ -96,24 +278,49 @@ class NoopSpanCollector(SpanCollector):
         pass
 
 
-class InMemorySpanCollector(SpanCollector):
-    """Bounded buffer of exported spans (oldest dropped first)."""
+# Twice the largest benchmark cell's run, rounded up to a power of two:
+# ``sf1_cluster_streams`` leaves 5 628 spans (76 queries of 74, warm-up
+# included; ``sf1_join`` 1 576, ``sf10_scanagg`` 1 100: chip runs, PR 26).
+RING_CAPACITY = 16384
 
-    def __init__(self, capacity: int = 8192):
+
+class SpanRing(SpanCollector):
+    """The process's one in-memory store of finished spans: bounded,
+    oldest dropped first, ``dropped`` counts what went."""
+
+    def __init__(self, capacity: int = RING_CAPACITY):
         self.capacity = max(int(capacity), 1)
-        self._spans: List[Span] = []
+        self.dropped = 0
+        self._spans = collections.deque()
         self._lock = threading.Lock()
 
-    def export(self, spans: List[Span]) -> None:
+    def add(self, s: Span) -> None:
         with self._lock:
-            self._spans.extend(spans)
-            if len(self._spans) > self.capacity:
-                del self._spans[:len(self._spans) - self.capacity]
+            if len(self._spans) >= self.capacity:
+                self._spans.popleft()
+                self.dropped += 1
+            self._spans.append(s)
+
+    def export(self, spans: List[Span]) -> None:
+        """Collector seam: spans this process closed are in already; what
+        another process shipped (task trees on a scheduler) enters here."""
+        for s in spans:
+            if not s._ringed:
+                s._ringed = True
+                self.add(s)
 
     def snapshot(self, trace_id: Optional[str] = None) -> List[Span]:
         with self._lock:
             return [s for s in self._spans
                     if trace_id is None or s.trace_id == trace_id]
+
+    def clear(self) -> None:  # test hook
+        with self._lock:
+            self._spans.clear()
+            self.dropped = 0
+
+
+RING = SpanRing()
 
 
 def otlp_payload(spans: List[Span], service_name: str) -> Dict:
@@ -142,8 +349,8 @@ def otlp_payload(spans: List[Span], service_name: str) -> Dict:
                 "parentSpanId": s.parent_id,
                 "name": s.name,
                 "kind": 1,
-                "startTimeUnixNano": str(int(s.start_ms * 1e6)),
-                "endTimeUnixNano": str(int((s.end_ms or now_ms()) * 1e6)),
+                "startTimeUnixNano": str(s.start_ns),
+                "endTimeUnixNano": str(s.end_ns or now_ns()),
                 "status": {"code": 2 if s.status not in ("ok", "success")
                            else 1},
                 "attributes": attrs(s.attrs),
@@ -187,32 +394,35 @@ class OtlpSpanCollector(SpanCollector):
 def make_collector(kind: str, endpoint: str = "") -> SpanCollector:
     kind = (kind or "noop").strip().lower()
     if kind == "memory":
-        return InMemorySpanCollector()
+        return RING
     if kind == "otlp":
         return OtlpSpanCollector(endpoint)
     return NoopSpanCollector()
 
 
 class TaskSpanRecorder:
-    """Builds one task's span tree on the task's executing thread.
+    """One task's span tree, built on the task's executing thread.
 
-    A task runs its operator tree depth-first on a single thread, so a
-    plain stack gives correct parenting for nested `op_span` calls.
-    Operator MetricsSets are cumulative per plan instance and shared by
-    same-stage tasks; the recorder snapshots `to_dict()` around each
-    execute call and attaches the *delta* as span attributes, which is
-    this task's contribution (up to interleaving with concurrent tasks
-    of the same stage on this executor).
+    The task span opens here and closes in ``finish``; every span that
+    closes on this thread in between (operators, and the ``device_wait``,
+    ``h2d``, ``compile`` and ``lock_wait`` spans below them) is kept for
+    ``TaskStatus.spans``.  Operator MetricsSets are cumulative per plan
+    instance and shared by same-stage tasks; ``op_span`` snapshots
+    ``to_dict()`` around each execute call and attaches the *delta* as span
+    attributes, which is this task's contribution (up to interleaving with
+    concurrent tasks of the same stage on this executor).
     """
 
     def __init__(self, trace_id: Optional[str] = None, parent_id: str = "",
                  name: str = "task", kind: str = "executor",
                  attrs: Optional[Dict] = None):
-        self.root = Span(name, trace_id or new_trace_id(),
-                         parent_id=parent_id or "", kind=kind,
-                         attrs=dict(attrs or {}))
+        self._scope = span(name, kind, {"trace_id": trace_id,
+                                        "span_id": parent_id or ""},
+                           **(attrs or {}))
         self._done: List[Span] = []
-        self._stack: List[Span] = [self.root]
+        self._outer_sink = getattr(_tls, "sink", None)
+        self.root = self._scope.__enter__()
+        _tls.sink = self._done
 
     def annotate(self, **attrs) -> None:
         self.root.attrs.update(attrs)
@@ -227,31 +437,21 @@ class TaskSpanRecorder:
                 before = ms().to_dict()
             except Exception:
                 ms = None
-        span = Span(name, self.root.trace_id,
-                    parent_id=self._stack[-1].span_id, kind="operator",
-                    attrs=dict(attrs))
-        for k in ("actor", "lane"):  # inherit the task's trace lanes
-            if k in self.root.attrs:
-                span.attrs.setdefault(k, self.root.attrs[k])
-        self._stack.append(span)
-        try:
-            yield span
-        except BaseException:
-            span.status = "error"
-            raise
-        finally:
-            self._stack.pop()
-            if callable(ms):
-                try:
-                    for k, v in ms().to_dict().items():
-                        delta = v - before.get(k, 0.0)
-                        if delta:
-                            span.attrs[k] = round(float(delta), 6)
-                except Exception:
-                    pass
-            span.end()
-            self._done.append(span)
+        with span(name, "operator", **attrs) as sp:
+            try:
+                yield sp
+            finally:
+                if callable(ms):
+                    try:
+                        for k, v in ms().to_dict().items():
+                            delta = v - before.get(k, 0.0)
+                            if delta:
+                                sp.attrs[k] = round(float(delta), 6)
+                    except Exception:
+                        pass
 
     def finish(self, status: str = "ok") -> List[Span]:
-        self.root.end(status)
+        _tls.sink = self._outer_sink
+        self.root.status = status
+        self._scope.__exit__(None, None, None)
         return [self.root] + list(self._done)

@@ -29,6 +29,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..models import expr as E
+from ..obs.tracing import TracedLock
 from ..models.schema import BOOL, DataType, DATE32, FLOAT64, INT32, INT64, Schema
 from ..utils.errors import InternalError, PlanningError
 from . import kernels as K
@@ -186,7 +187,7 @@ class ExprCompiler:
         self.xp = jnp if mode == "device" else np
         self.aux_builders: Dict[str, Callable] = {}
         self._aux_cache: Dict = {}
-        self._aux_lock = threading.Lock()
+        self._aux_lock = TracedLock("expr_aux")
         self._n = 0
 
     # --- public ---------------------------------------------------------

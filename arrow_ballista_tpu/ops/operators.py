@@ -23,12 +23,26 @@ from ..models.batch import ColumnBatch, concat_batches, remote_device
 from ..models.schema import BOOL, DataType, Field, INT64, Schema
 from ..utils.config import AGG_CAPACITY, JOIN_MAX_CAPACITY
 from ..utils.errors import CapacityError, ExecutionError, InternalError
-from ..obs.device import observed_jit
+from ..obs.device import device_wait, observed_jit
 from .expressions import Compiled, ExprCompiler
 from . import kernels as K
 from .physical import (ExecutionPlan, Partitioning, TaskContext,
                        deferred_rows, exprs_sig, has_scalar_subquery,
                        schema_sig, shared_program)
+
+
+@observed_jit("agg.null_restore")
+def _null_restore(cnt, col, sentinel):
+    """All-NULL groups: the output sentinel where the hidden valid count
+    is zero."""
+    return jnp.where(cnt > 0, col, sentinel)
+
+
+def _scalar(x) -> int:
+    """A device scalar on the host: the thread waits here for the program
+    that produces it."""
+    with device_wait("scalar"):
+        return int(x)
 
 
 # job-keyed weakref registry of join operators holding a materialized
@@ -514,13 +528,15 @@ class HashAggregateExec(ExecutionPlan):
                 # vs observed min/max (both device scalars, one roundtrip)
                 mismatch = self._declared_range_mismatch(ctx, big, partition)
                 if mismatch is not None:
-                    # ballista: allow=hot-path-purity,host-device-boundary — deliberate single batched scalar sync; a handful of scalar bytes, accounted as operator host time rather than transfer volume
-                    dis_v, mis_v = jax.device_get((disorder, mismatch))
+                    with device_wait("scalar"):
+                        # ballista: allow=hot-path-purity — deliberate single batched scalar sync; a handful of scalar bytes, a device_wait span rather than transfer volume
+                        dis_v, mis_v = jax.device_get((disorder, mismatch))
                     if bool(mis_v):
                         self.metrics().add("clustered_range_mismatches", 1)
                     bad = bool(dis_v) or bool(mis_v)
                 else:
-                    bad = bool(disorder)
+                    with device_wait("scalar"):
+                        bad = bool(disorder)
                 if bad:
                     out = self._latch_sorted_fallback(ctx, in_schema,
                                                       cfg_cap, big)
@@ -710,8 +726,9 @@ class HashAggregateExec(ExecutionPlan):
             # pay the fixed transfer latency once per scalar)
             fetch = (live, disorder,
                      mismatch if mismatch is not None else np.False_)
-            # ballista: allow=hot-path-purity,host-device-boundary — deliberate single batched scalar sync; a handful of scalar bytes, accounted as operator host time rather than transfer volume
-            live_v, dis_v, mis_v = jax.device_get(fetch)
+            with device_wait("scalar"):
+                # ballista: allow=hot-path-purity — deliberate single batched scalar sync; a handful of scalar bytes, a device_wait span rather than transfer volume
+                live_v, dis_v, mis_v = jax.device_get(fetch)
             if bool(mis_v):
                 # declared ranges are wrong (stale stats): the overlap
                 # windows can't be trusted, so the early filter itself is
@@ -798,6 +815,13 @@ class HashAggregateExec(ExecutionPlan):
                 and len(self.group_exprs) == 1
                 and not getattr(self, "_no_presort", False))
 
+    def program_variant(self) -> str:
+        """What tells this aggregate's program from the others that share
+        the ``agg.grouped`` signature, in the trace: mode, number of group
+        keys (q6's global aggregate is ``k0``, q1's ``k2``), presorted."""
+        return (f"{self.mode}_k{len(self.group_exprs)}"
+                + ("_presorted" if self._presorted() else ""))
+
     def _make_compiled(self, ctx, in_schema):
         """Build (or fetch shared) compiled closures and RETURN them —
         callers assign to self._compiled in one atomic statement so
@@ -883,7 +907,8 @@ class HashAggregateExec(ExecutionPlan):
                                        key_ranges=key_ranges)
 
         return (comp, group_c, agg_c, tracked,
-                observed_jit("agg.grouped", agg_fn, static_argnums=(3, 4)))
+                observed_jit("agg.grouped", agg_fn, static_argnums=(3, 4),
+                             variant=self.program_variant()))
 
     def _execute_device(self, ctx, cfg_cap, big):
         comp, group_c, agg_c, tracked, jfn = self._compiled
@@ -960,8 +985,9 @@ class HashAggregateExec(ExecutionPlan):
         for i, cnt in zip(tracked, out_vals[len(agg_c) :]):
             name = agg_c[i][2]
             f = self._schema.field(name)
-            sent = jnp.asarray(f.dtype.null_sentinel, dtype=f.dtype.np_dtype)
-            cols[name] = jnp.where(cnt > 0, cols[name], sent)
+            cols[name] = _null_restore(cnt, cols[name],
+                                       f.dtype.np_dtype.type(
+                                           f.dtype.null_sentinel))
 
         result = ColumnBatch(self._schema, cols, out_mask, dicts)
 
@@ -1378,8 +1404,8 @@ class JoinExec(ExecutionPlan):
             if hint is not None and not remote_device():
                 out_cap = hint
             else:
-                total_est = int(cfn(probe.columns, probe.mask, bh_sorted,
-                                    laux))
+                total_est = _scalar(cfn(probe.columns, probe.mask,
+                                        bh_sorted, laux))
                 if total_est > ceiling:
                     raise CapacityError(
                         f"join produced {total_est} candidate pairs, above "
@@ -1433,7 +1459,7 @@ class JoinExec(ExecutionPlan):
             # a never-taken branch — skipped there (count and join run the
             # same arithmetic on the same inputs; a disagreement would be an
             # XLA miscompile, which no host-side retry rescues anyway).
-            if not remote_device() and int(total) > out_cap:
+            if not remote_device() and _scalar(total) > out_cap:
                 need = 1 << (int(total) - 1).bit_length()
                 if need > ceiling:
                     raise CapacityError(
@@ -1688,9 +1714,11 @@ class JoinExec(ExecutionPlan):
             dicts.update(build.dicts)
         # all window counts in ONE program + ONE host transfer (per-window
         # scalar syncs would cost their fixed latency each)
-        # ballista: allow=hot-path-purity — deliberate single batched transfer
-        window_counts = np.asarray(wcfn(probe.columns, probe.mask, bh_sorted,
-                                        laux, chunk_rows, chunks))
+        counts_dev = wcfn(probe.columns, probe.mask, bh_sorted, laux,
+                          chunk_rows, chunks)
+        with device_wait("scalar"):
+            # ballista: allow=hot-path-purity — deliberate single batched transfer
+            window_counts = np.asarray(counts_dev)
         grand_total = 0  # the cross-join guard must see the SUM of windows
         for i in range(chunks):
             ctx.check_cancelled()
@@ -1712,7 +1740,7 @@ class JoinExec(ExecutionPlan):
             out_cols, out_mask, total = jfn(
                 probe.columns, pmask_c, build.columns, build.mask,
                 bh_sorted, border, laux, raux, faux, out_cap)[:3]
-            if not remote_device() and int(total) > out_cap:
+            if not remote_device() and _scalar(total) > out_cap:
                 need = 1 << (int(total) - 1).bit_length()
                 if need > ceiling:
                     raise CapacityError(

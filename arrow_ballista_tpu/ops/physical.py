@@ -25,6 +25,7 @@ from ..models.batch import ColumnBatch, concat_batches, round_capacity
 from ..models.schema import DataType, Schema
 from ..obs import device as device_obs
 from ..obs.device import observed_jit
+from ..obs.tracing import TracedLock
 from ..utils.config import BallistaConfig
 from ..utils.errors import ExecutionError, InternalError
 from .expressions import ExprCompiler
@@ -97,7 +98,7 @@ class MetricsSet:
 
 _program_cache = collections.OrderedDict()
 _PROGRAM_CACHE_MAX = 256
-_program_cache_lock = threading.Lock()
+_program_cache_lock = TracedLock("program_cache")
 
 
 def shared_program(key, build):
@@ -365,7 +366,7 @@ class ExecutionPlan:
     def schema(self) -> Schema:
         return self._schema
 
-    def xla_lock(self) -> threading.Lock:
+    def xla_lock(self) -> TracedLock:
         """Per-operator lock guarding the lazy jit-closure build.
 
         Same-stage tasks share one operator instance; without this, N pool
@@ -382,7 +383,8 @@ class ExecutionPlan:
             with _LOCK_CREATE:
                 lock = getattr(self, "_xla_lock", None)
                 if lock is None:
-                    self._xla_lock = lock = threading.Lock()
+                    self._xla_lock = lock = TracedLock(
+                        f"xla:{type(self).__name__}")
         return lock
 
     def children(self) -> List["ExecutionPlan"]:

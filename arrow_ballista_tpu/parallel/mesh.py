@@ -24,6 +24,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.tracing import TracedLock
+
 PART_AXIS = "part"
 
 # process-global serialization of COLLECTIVE program dispatch: two mesh
@@ -32,7 +34,7 @@ PART_AXIS = "part"
 # -> hard abort / hang; observed again as a 180s job timeout when two
 # warm-cache hybrid-join tasks dispatched concurrently).  Collectives
 # already use every local device, so serializing them costs nothing.
-MESH_DISPATCH_LOCK = threading.Lock()
+MESH_DISPATCH_LOCK = TracedLock("mesh_dispatch")
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = PART_AXIS,

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from typing import Dict, List, Optional, Tuple
 
 from ..ops.shuffle import (
@@ -120,6 +121,9 @@ class ExecutionStage:
         self.outputs: Dict[int, Tuple[str, List[ShuffleWritePartition]]] = {}
         # AQE rewrite records applied to this stage (scheduler/aqe.py);
         # append-only, entries carry their stage_attempt epoch
+        # the open "stage <id>" span of this attempt (ExecutionGraph
+        # _open_stage_span; None when tracing is off or the stage is done)
+        self.span = None
         self.aqe_rewrites: List[dict] = []
         # whole-stage-fusion decisions for this stage (compile/fuse.py):
         # one record per detected chain — fused or rejected, with reasons;
@@ -378,8 +382,11 @@ class ExecutionGraph:
         self.deadline_ts = 0.0
         self.deadline_s = 0.0
         # trace propagation context handed to every task of this job
-        # ({"trace_id", "span_id"}; empty when tracing is off)
+        # ({"trace_id", "span_id"}; empty when tracing is off), set by
+        # start_trace; and one "stage <id>" span per stage attempt, open
+        # from the stage turning runnable to its last status absorbed
         self.trace: Dict[str, str] = {}
+        self.stage_spans: List[object] = []
         # executor_id -> (host, port) of the data plane; None = local-only
         self.addr_resolver = None
         # live per-stage runtime summaries (skew, histograms, duration
@@ -409,6 +416,35 @@ class ExecutionGraph:
         stages = DistributedPlanner().plan_query_stages(job_id, plan)
         return ExecutionGraph(job_id, stages)
 
+    # --- tracing ---------------------------------------------------------
+    def start_trace(self, trace: Dict[str, str]) -> None:
+        """Adopt the job's execution-span context.  The graph is built (and
+        its leaf stages resolved) before the scheduler has one to give, so
+        the stages already runnable get their spans here."""
+        self.trace = dict(trace or {})
+        for stage in self.stages.values():
+            if stage.state == RUNNING:
+                self._open_stage_span(stage)
+
+    def _open_stage_span(self, stage: ExecutionStage) -> None:
+        if not self.trace:
+            return
+        from ..obs.tracing import span
+
+        self._close_stage_span(stage, "superseded")
+        stage.span = span(f"stage {stage.stage_id}", "scheduler", self.trace,
+                          job_id=self.job_id, stage_id=stage.stage_id,
+                          stage_attempt=stage.stage_attempt,
+                          actor="scheduler", lane=f"job {self.job_id}",
+                          launched_at={}).begin()
+        self.stage_spans.append(stage.span)
+
+    @staticmethod
+    def _close_stage_span(stage: ExecutionStage, status: str = "ok") -> None:
+        if stage.span is not None:
+            stage.span.end(status)
+            stage.span = None
+
     # --- scheduling ------------------------------------------------------
     def revive(self) -> bool:
         """Resolve every UNRESOLVED stage whose producers are all
@@ -433,6 +469,7 @@ class ExecutionGraph:
                         stage.maybe_coalesce()
                 stage.state = RUNNING
                 changed = True
+                self._open_stage_span(stage)
                 if self.compiler is not None and self.compiler.enabled:
                     # whole-stage fusion rides the resolve: applied to the
                     # freshly resolved plan (after AQE), before any task
@@ -519,6 +556,11 @@ class ExecutionGraph:
                          attempt=info.attempt,
                          executor_id=info.executor_id,
                          speculative=info.speculative)
+        if stage.span is not None:
+            # when each task was handed out: the first entry less the
+            # producers' last status is the stage hand-off
+            stage.span.attrs["launched_at"][
+                f"{info.partition}.{info.attempt}"] = time.time_ns()
         return TaskDescription(tid, stage.resolved_plan,
                                task_internal_id=next(self._task_id_gen),
                                scalars=self.scalars,
@@ -631,6 +673,7 @@ class ExecutionGraph:
         completed = stage.all_successful() and stage.state == RUNNING
         if completed:
             stage.state = SUCCESSFUL
+            self._close_stage_span(stage)
         # refold AFTER the state transition (the final summary must record
         # the stage as successful) and BEFORE downstream stages resolve:
         # the AQE passes read the completed stage's folded stats

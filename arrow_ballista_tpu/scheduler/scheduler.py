@@ -951,7 +951,7 @@ class SchedulerServer:
             journal.emit_job("job.planned", ev.job_id,
                              stages=len(ev.graph.stages))
         # hand the execution span's context to every task of this job
-        ev.graph.trace = self.obs.task_parent(ev.job_id)
+        ev.graph.start_trace(self.obs.task_parent(ev.job_id))
         self.jobs.submit_job(ev.job_id, ev.graph)
         with self._meta_lock:
             queued_at = self._queued_at_ms.get(ev.job_id, 0)
@@ -1204,7 +1204,7 @@ class SchedulerServer:
         self.obs.on_adopted(job_id, lease.epoch, prev_owner=prev_owner,
                             scheduler_id=self.scheduler_id,
                             trace=dict(getattr(graph, "trace", {}) or {}))
-        graph.trace = self.obs.task_parent(job_id)
+        graph.start_trace(self.obs.task_parent(job_id))
         log.info("adopted job %s at lease epoch %d", job_id, lease.epoch)
         return True
 

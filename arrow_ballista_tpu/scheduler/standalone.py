@@ -18,6 +18,7 @@ from typing import Dict, List, Optional
 from ..executor.executor import Executor
 from ..models.batch import ColumnBatch
 from ..models.ipc import read_ipc_files
+from ..obs.tracing import current_context, span
 from ..ops.physical import TaskContext
 from ..utils.config import BallistaConfig
 from ..utils.errors import ExecutionError
@@ -172,39 +173,24 @@ class StandaloneCluster:
 
         config = config or self.config
         job_id = random_job_id()
-        cached, plan_fn, serving = prepare_sql_submission(
-            self.scheduler, sql_text, catalog, config, job_id,
-            subplan_ok=True, work_dir=self.work_dir, statement=statement)
+        trace = current_context()
+        with span("submit", "client", job_id=job_id) as sp:
+            cached, plan_fn, serving = prepare_sql_submission(
+                self.scheduler, sql_text, catalog, config, job_id,
+                subplan_ok=True, work_dir=self.work_dir, statement=statement)
+            sp.set(cached=cached is not None)
+            if cached is None:
+                self._submit(job_id, plan_fn, config, trace,
+                             serving=serving)
         if cached is not None:
             batches: List[ColumnBatch] = []
-            for _part, blobs in cached["partitions"]:
-                batches.extend(read_ipc_buffers(blobs, cached["schema"],
-                                                capacity=config.batch_size))
+            with span("fetch", "client", job_id=job_id):
+                for _part, blobs in cached["partitions"]:
+                    batches.extend(read_ipc_buffers(
+                        blobs, cached["schema"], capacity=config.batch_size))
             return batches
-        self.last_job_id = job_id
-        from ..admission import AdmissionRequest
-        from ..obs import new_trace_context
-
-        self.scheduler.submit_job(
-            job_id, plan_fn,
-            admission=AdmissionRequest.from_config(config),
-            trace=new_trace_context(), config=config, serving=serving)
-        status = self.scheduler.wait_for_job(
-            job_id, timeout=float(config.job_timeout_s))
-        if status.state == "failed":
-            if status.retriable:
-                from ..utils.errors import ResourceExhausted
-
-                raise ResourceExhausted(f"job {job_id} shed: {status.error}")
-            raise ExecutionError(f"job {job_id} failed: {status.error}")
-        if status.state != "successful":
-            raise ExecutionError(f"job {job_id} ended as {status.state}")
-        batches = []
-        for part in sorted(status.locations):
-            paths = [loc.path for loc in status.locations[part] if loc.num_rows]
-            batches.extend(read_ipc_files(paths, serving.schema,
-                                          capacity=config.batch_size))
-        return batches
+        # the schema is known once the job has been planned
+        return self._await_result(job_id, config, lambda: serving.schema)
 
     def execute(self, planned) -> List[ColumnBatch]:
         """Run a PlannedQuery through the distributed machinery and fetch
@@ -212,31 +198,46 @@ class StandaloneCluster:
         DistributedQueryExec, reference distributed_query.rs:226-329)."""
         from ..client.context import extract_scalar
 
-        # scalar subqueries run first, host-side (they are tiny by
-        # construction: single-row reductions)
-        scalar_ctx = TaskContext(config=self.config, work_dir=self.work_dir,
-                                 job_id="scalars")
-        scalars: Dict[str, object] = {}
-        for sid, splan in planned.scalars:
-            scalar_ctx.scalars = scalars
-            scalars[sid] = extract_scalar(splan, scalar_ctx)
-
         job_id = random_job_id()
+        trace = current_context()
+        with span("submit", "client", job_id=job_id):
+            # scalar subqueries run first, host-side (they are tiny by
+            # construction: single-row reductions)
+            scalar_ctx = TaskContext(config=self.config,
+                                     work_dir=self.work_dir, job_id="scalars")
+            scalars: Dict[str, object] = {}
+            for sid, splan in planned.scalars:
+                scalar_ctx.scalars = scalars
+                scalars[sid] = extract_scalar(splan, scalar_ctx)
+            self._submit(job_id, lambda: (planned.plan, scalars),
+                         self.config, trace)
+        return self._await_result(job_id, self.config,
+                                  lambda: planned.plan.schema)
+
+    def _submit(self, job_id: str, plan_fn, config: BallistaConfig,
+                trace: Dict[str, str], **kwargs) -> None:
+        from ..admission import AdmissionRequest
+
         # remembered so explain_analyze can find the job's retained graph
         # (and its RuntimeStatsStore) after execute() returns
         self.last_job_id = job_id
-        from ..admission import AdmissionRequest
-        from ..obs import new_trace_context
+        # ``trace``: the client span the job span hangs under
+        # (client.collect; {} = a trace of its own, for a direct caller)
+        self.scheduler.submit_job(
+            job_id, plan_fn, admission=AdmissionRequest.from_config(config),
+            trace=trace, config=config, **kwargs)
 
-        self.scheduler.submit_job(job_id, lambda: (planned.plan, scalars),
-                                  admission=AdmissionRequest.from_config(self.config),
-                                  trace=new_trace_context(),
-                                  config=self.config)
+    def _await_result(self, job_id: str, config: BallistaConfig,
+                      schema_of) -> List[ColumnBatch]:
+        """The client's half after submission: ``wait`` ends when this
+        thread learns of the terminal status (``wait.end - job.end`` is
+        what noticing cost), ``fetch`` reads the final stage's files."""
         # deadline is config-driven (round-2 failure mode: a slow first-compile
         # TPU run blew through a hard-coded 300 s wait and "failed" a job that
         # would have finished)
-        status = self.scheduler.wait_for_job(job_id,
-                                             timeout=float(self.config.job_timeout_s))
+        with span("wait", "client", job_id=job_id, polls=0):
+            status = self.scheduler.wait_for_job(
+                job_id, timeout=float(config.job_timeout_s))
         if status.state == "failed":
             if status.retriable:
                 from ..utils.errors import ResourceExhausted
@@ -245,13 +246,16 @@ class StandaloneCluster:
             raise ExecutionError(f"job {job_id} failed: {status.error}")
         if status.state != "successful":
             raise ExecutionError(f"job {job_id} ended as {status.state}")
-
-        schema = planned.plan.schema
         batches: List[ColumnBatch] = []
-        for part in sorted(status.locations):
-            paths = [loc.path for loc in status.locations[part] if loc.num_rows]
-            batches.extend(read_ipc_files(paths, schema,
-                                          capacity=self.config.batch_size))
+        with span("fetch", "client", job_id=job_id) as sp:
+            nbytes = 0
+            for part in sorted(status.locations):
+                locs = [loc for loc in status.locations[part] if loc.num_rows]
+                nbytes += sum(loc.num_bytes for loc in locs)
+                batches.extend(read_ipc_files([loc.path for loc in locs],
+                                              schema_of(),
+                                              capacity=config.batch_size))
+            sp.set(bytes=nbytes)
         return batches
 
     def shutdown(self) -> None:

@@ -24,6 +24,8 @@ import threading
 from collections import OrderedDict
 from typing import List, Optional, Tuple
 
+from ..obs.tracing import TracedLock
+
 DEFAULT_BUDGET = 6 << 30  # fits SF10 lineitem device form in 16 GB HBM
 # CPU backends: "device" arrays ARE host RAM, and every CPU-only daemon
 # process would pin its own duplicate copy — keep the pool small there
@@ -39,7 +41,7 @@ def _batch_bytes(b) -> int:
 
 class DeviceTableCache:
     def __init__(self, budget_bytes: int = DEFAULT_BUDGET):
-        self._lock = threading.Lock()
+        self._lock = TracedLock("scan_cache")
         self._entries: "OrderedDict[Tuple, Tuple[list, int]]" = OrderedDict()
         self._bytes = 0
         self._budget = budget_bytes
