@@ -511,6 +511,15 @@ class ObservedJit:
                         else _shape_key(kwargs[k])))
         return tuple(key)
 
+    def jaxpr(self, *args):
+        """The program's jaxpr at ``args`` (arrays or ``ShapeDtypeStruct``s,
+        statics by position): traces the wrapped function, compiles and
+        counts nothing.  For tests of what a program is made of."""
+        import jax
+
+        return jax.make_jaxpr(
+            self._fn, static_argnums=tuple(sorted(self._static_idx)))(*args)
+
     def __call__(self, *args, **kwargs):
         if not _enabled:
             return self._jfn(*args, **kwargs)

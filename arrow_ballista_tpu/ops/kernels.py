@@ -292,20 +292,53 @@ def grouped_aggregate(
     impossible there; external callers passing literal ranges own the
     guarantee.
     """
-    if key_cols:
-        domain = dense_domain(key_ranges)
-        if domain is not None:
-            return _grouped_aggregate_dense(key_cols, val_cols, mask,
-                                            out_capacity, key_ranges, domain)
+    if not key_cols:
+        return _global_aggregate(val_cols, mask, out_capacity)
+    domain = dense_domain(key_ranges)
+    if domain is not None:
+        return _grouped_aggregate_dense(key_cols, val_cols, mask,
+                                        out_capacity, key_ranges, domain)
     n = mask.shape[0]
-    if key_cols:
-        order = sort_order([(k, True) for k in key_cols], mask)
-    else:
-        order = compaction_order(mask)
+    order = sort_order([(k, True) for k in key_cols], mask)
     mask_s = mask[order]
     return _grouped_aggregate_on_order(
         [k[order] for k in key_cols],
         [(v[order], how) for v, how in val_cols], mask_s, out_capacity, n)
+
+
+def _global_aggregate(
+    val_cols: List[Tuple[jnp.ndarray, str]],
+    mask: jnp.ndarray,
+    out_capacity: int,
+):
+    """No group keys: one group, so each aggregate is ONE masked reduction
+    over the rows as they lie — no compaction, no gather, no segment ids, no
+    scatter.  Slot 0 holds the state and is live iff any row is (a partial
+    over no live rows emits no row); a caller's ``out_capacity`` > 1 is
+    honoured by padding.  int64 sums and counts are exact mod 2^64, int64
+    min/max over no live row give INT64_MAX / INT64_MIN (the merge identities
+    ``grouped_minmax_i64`` gives an empty slot), float sums stay in the
+    column's dtype.  One group cannot overflow: the flag is statically
+    None."""
+    out_vals = []
+    for a, how in val_cols:
+        if how == AGG_COUNT:
+            v = jnp.sum(mask, dtype=jnp.int64)
+        elif how == AGG_SUM:
+            v = jnp.sum(jnp.where(mask, a, jnp.zeros((), a.dtype)))
+        elif how == AGG_MIN:
+            v = jnp.min(jnp.where(mask, a, _max_ident(a.dtype)))
+        elif how == AGG_MAX:
+            v = jnp.max(jnp.where(mask, a, _min_ident(a.dtype)))
+        else:
+            raise ValueError(f"unknown agg {how}")
+        out_vals.append(_in_slot0(v, out_capacity))
+    return [], out_vals, _in_slot0(jnp.any(mask), out_capacity), None
+
+
+def _in_slot0(v: jnp.ndarray, capacity: int) -> jnp.ndarray:
+    """Scalar -> [capacity] with ``v`` in slot 0 and zeros (False) after."""
+    return jnp.pad(v[None], (0, capacity - 1))
 
 
 def _grouped_aggregate_on_order(
@@ -319,15 +352,11 @@ def _grouped_aggregate_on_order(
     equal keys adjacent): boundary flags -> segment reductions.  Shared by
     the sort path (grouped_aggregate) and the clustered presorted path
     (grouped_aggregate_presorted)."""
-    if keys_s:
-        first = jnp.zeros(n, dtype=bool).at[0].set(True)
-        diff = jnp.zeros(n, dtype=bool)
-        for k in keys_s:
-            diff = diff | (k != jnp.roll(k, 1))
-        boundary = mask_s & (first | diff)
-    else:
-        # global aggregate: one group iff any live row
-        boundary = (jnp.arange(n) == 0) & (jnp.sum(mask_s) > 0)
+    first = jnp.zeros(n, dtype=bool).at[0].set(True)
+    diff = jnp.zeros(n, dtype=bool)
+    for k in keys_s:
+        diff = diff | (k != jnp.roll(k, 1))
+    boundary = mask_s & (first | diff)
 
     seg = jnp.cumsum(boundary) - 1  # group index per sorted row (-1 before first)
     num_groups = jnp.sum(boundary)

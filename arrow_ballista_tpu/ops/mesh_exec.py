@@ -218,7 +218,10 @@ class MeshAggregateExec(ExecutionPlan):
     @staticmethod
     def eligible(group_exprs, aggs, in_schema) -> bool:
         if not group_exprs:
-            return False  # global aggregates: the plain path is already cheap
+            # global aggregates: the plain path is cheap — one masked
+            # reduction into one row per partition since PR 27 (before it,
+            # a scatter into capacity + 1 groups: 8 s a q6 at SF10)
+            return False
         for a in aggs:
             if a.name.startswith(_HIDDEN_PREFIX):
                 # the hidden validity columns ride in-band under this prefix;

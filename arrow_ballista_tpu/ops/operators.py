@@ -935,8 +935,9 @@ class HashAggregateExec(ExecutionPlan):
                 key_ranges.append(None)
         key_ranges = tuple(key_ranges)
         # plan-ahead capacity: the group count is bounded a priori — by
-        # the dense key domain when the ranges are static, else by the
-        # input capacity (distinct groups can never exceed live rows) —
+        # one when there are no keys, by the dense key domain when the
+        # ranges are static, else by the input capacity (distinct groups
+        # can never exceed live rows) —
         # so out_cap provably holds every group and the kernel's overflow
         # flag is statically None (kernels.py returns None whenever
         # out_cap covers the bound).  ONE kernel call per input: the old
@@ -947,7 +948,12 @@ class HashAggregateExec(ExecutionPlan):
         # (reserve -> spill), not a recompile loop's.
         out_cap = big.capacity
         domain = K.dense_domain(key_ranges)
-        if domain is not None:
+        if not group_c:
+            # no keys, one group: the kernel reduces into one row, and
+            # everything downstream (pack, D2H, partition file) is one row
+            out_cap = 1
+            self.metrics().add("global_reductions", 1)
+        elif domain is not None:
             # dense domain bounds distinct groups exactly: don't allocate
             # (or device->host transfer) a 64k-row output for 12 groups
             out_cap = min(out_cap, domain)

@@ -3,7 +3,9 @@ import numpy as np
 import pandas as pd
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from arrow_ballista_tpu.ops import kernels as K
 
@@ -197,3 +199,131 @@ def test_i64_limb_reductions_match_plain_paths(monkeypatch):
     for f, s in zip(dout_f[0] + dout_f[1], dout_s[0] + dout_s[1]):
         assert np.array_equal(np.asarray(f), np.asarray(s))
     assert np.array_equal(np.asarray(dout_f[2]), np.asarray(dout_s[2]))
+
+
+# --------------------------------------------------------------------------
+# keyless (global) aggregate: one masked reduction into one row
+# --------------------------------------------------------------------------
+
+_I64 = np.iinfo(np.int64)
+
+
+def _global_case(name):
+    """(value columns as numpy, hows, mask, expected slot-0 values)."""
+    rng = np.random.default_rng(11)
+    n = 4096
+    mask = rng.random(n) < 0.7
+    if name == "sum_wraps_mod_2_64":
+        # every partial sum is past 2^53 and the total wraps int64
+        v = rng.integers(2**61, 2**62, n).astype(np.int64)
+        assert int(v[mask].astype(object).sum()) > _I64.max
+        return [v], [K.AGG_SUM], mask, [v[mask].sum(dtype=np.int64)]
+    if name == "negative_values":
+        v = rng.integers(-2**40, 2**40, n).astype(np.int64)
+        w = -rng.integers(1, 2**52, n).astype(np.int64)
+        return [v, w], [K.AGG_SUM, K.AGG_SUM], mask, \
+            [v[mask].sum(dtype=np.int64), w[mask].sum(dtype=np.int64)]
+    if name == "count_star":
+        return [np.zeros(n, np.int64)], [K.AGG_COUNT], mask, \
+            [np.int64(mask.sum())]
+    if name == "minmax_at_extremes":
+        v = rng.integers(-2**62, 2**62, n).astype(np.int64)
+        live = np.flatnonzero(mask)
+        v[live[0]], v[live[1]] = _I64.min, _I64.max
+        dead = np.flatnonzero(~mask)
+        v[dead[:2]] = [_I64.min, _I64.max]     # masked rows must not win
+        small = rng.integers(-9, 9, n).astype(np.int64)
+        return [v, v, small, small], \
+            [K.AGG_MIN, K.AGG_MAX, K.AGG_MIN, K.AGG_MAX], mask, \
+            [_I64.min, _I64.max, small[mask].min(), small[mask].max()]
+    if name == "float64_sum":
+        v = rng.random(n) * 1e6
+        return [v, v, v], [K.AGG_SUM, K.AGG_MIN, K.AGG_MAX], mask, \
+            [v[mask].sum(), v[mask].min(), v[mask].max()]
+    if name == "n_not_a_multiple_of_2_15":
+        n = 2 * (1 << 15) + 77
+        mask = rng.random(n) < 0.5
+        v = rng.integers(-2**62, 2**62, n).astype(np.int64)
+        return [v, np.zeros(n, np.int64)], [K.AGG_SUM, K.AGG_COUNT], mask, \
+            [v[mask].sum(dtype=np.int64), np.int64(mask.sum())]
+    if name == "every_row_masked":
+        v = rng.integers(-2**40, 2**40, n).astype(np.int64)
+        f = rng.random(n)
+        return [v, v, v, v, f, f], \
+            [K.AGG_SUM, K.AGG_COUNT, K.AGG_MIN, K.AGG_MAX, K.AGG_SUM,
+             K.AGG_MIN], np.zeros(n, bool), \
+            [np.int64(0), np.int64(0), _I64.max, _I64.min, 0.0, np.inf]
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("out_capacity", [1, 4])
+@pytest.mark.parametrize("case", [
+    "sum_wraps_mod_2_64", "negative_values", "count_star",
+    "minmax_at_extremes", "float64_sum", "n_not_a_multiple_of_2_15",
+    "every_row_masked"])
+def test_global_aggregate_matches_numpy(case, out_capacity):
+    """No keys: slot 0 holds each aggregate over the live rows, exactly
+    (int64 mod 2^64, identities on no live row), the rest is padding.  One
+    formulation on every backend (a plain int64 reduction read 0.73 ms
+    against 1.5 ms for 16-bit limbs at 2^23 rows on the v5e, PR 27), so
+    there is no backend to patch here."""
+    cols, hows, mask, want = _global_case(case)
+    keys, vals, out_mask, overflow = K.grouped_aggregate(
+        [], [(jnp.asarray(c), h) for c, h in zip(cols, hows)],
+        jnp.asarray(mask), out_capacity)
+    assert keys == [] and overflow is None
+    assert np.asarray(out_mask).tolist() == \
+        [bool(mask.any())] + [False] * (out_capacity - 1)
+    for got, col, how, exp in zip(vals, cols, hows, want):
+        got = np.asarray(got)
+        assert got.shape == (out_capacity,)
+        assert got.dtype == (np.int64 if how == K.AGG_COUNT else col.dtype)
+        if got.dtype.kind == "f" and how == K.AGG_SUM:
+            np.testing.assert_allclose(got[0], exp, rtol=1e-12)
+        else:
+            assert got[0] == exp, (how, got[0], exp)
+
+
+def _primitives(jaxpr):
+    """Every primitive's name, through the sub-jaxprs that eqn params hold
+    (pjit, cond branches, loop bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    yield from _primitives(sub)
+
+
+def test_keyless_program_is_reductions_into_one_row():
+    """q6's ``agg_grouped__partial_k0`` at a partition's real shape: no
+    scatter, gather, sort or scan anywhere in the program the operator
+    compiles, and every output one row."""
+    from arrow_ballista_tpu import BallistaConfig, Field, INT64, Schema, decimal
+    from arrow_ballista_tpu.models import expr as E
+    from arrow_ballista_tpu.ops.operators import (
+        AggSpec, HashAggregateExec, _SchemaSource)
+    from arrow_ballista_tpu.ops.physical import TaskContext
+
+    schema = Schema([Field("price", decimal(2)), Field("disc", decimal(2)),
+                     Field("qty", INT64, nullable=True)])
+    op = HashAggregateExec(
+        _SchemaSource(schema), [],
+        [AggSpec("sum", E.BinOp("*", E.Column("price"), E.Column("disc")),
+                 "revenue"),
+         AggSpec("count", None, "c"), AggSpec("min", E.Column("qty"), "mn"),
+         AggSpec("max", E.Column("qty"), "mx")], "partial")
+    assert op.program_variant() == "partial_k0"
+    op._ensure_compiled(TaskContext(config=BallistaConfig()), schema)
+    comp, _, _, _, jfn = op._compiled
+    n = 1 << 20
+    cols = {f.name: jax.ShapeDtypeStruct((n,), f.dtype.np_dtype)
+            for f in schema}
+    closed = jfn.jaxpr(cols, jax.ShapeDtypeStruct((n,), np.bool_),
+                       comp.aux_arrays({}), 1, ())
+    bad = {p for p in _primitives(closed.jaxpr)
+           if any(w in p for w in ("scatter", "gather", "sort", "cum"))}
+    assert not bad, bad
+    assert closed.out_avals and all(a.shape == (1,) for a in closed.out_avals)

@@ -10,6 +10,7 @@ from arrow_ballista_tpu import BallistaConfig, Field, INT64, STRING, Schema, dec
 from arrow_ballista_tpu.models import expr as E
 from arrow_ballista_tpu.ops.operators import (
     AggSpec,
+    CoalescePartitionsExec,
     FilterExec,
     HashAggregateExec,
     JoinExec,
@@ -108,6 +109,82 @@ def test_global_aggregate_empty_input_returns_one_row():
     agg = HashAggregateExec(plan, [], [AggSpec("count", None, "c")], mode="single")
     got = run_all(agg)
     assert len(got) == 1 and got["c"][0] == 0
+
+
+_GLOBAL_AGGS = [
+    AggSpec("sum", E.BinOp("*", E.Column("price"), E.Column("qty")), "rev"),
+    AggSpec("count", None, "c"),
+    AggSpec("min", E.Column("price"), "mn"),
+    AggSpec("max", E.Column("qty"), "mx"),
+]
+# the same aggregates as a final pass reads them: states named as outputs
+_GLOBAL_MERGE = [AggSpec(a.func, E.Column(a.name), a.name)
+                 for a in _GLOBAL_AGGS]
+
+
+def test_keyless_partial_is_one_row_of_capacity_one():
+    """No group keys: the partial state leaves as a batch of capacity 1
+    (so pack, D2H and the partition file are one row wide), and the
+    operator counts that the reduction path ran."""
+    df = lineitem_like()
+    partial = HashAggregateExec(scan_of(df, 2), [], _GLOBAL_AGGS, "partial")
+    c = ctx()
+    outs = [partial.execute(p, c) for p in range(2)]
+    assert [[b.capacity for b in out] for out in outs] == [[1], [1]]
+    assert partial.metrics().to_dict()["global_reductions"] == 2
+    final = HashAggregateExec(CoalescePartitionsExec(partial), [],
+                              _GLOBAL_MERGE, "final")
+    got = run_all(final)
+    assert len(got) == 1 and got["c"][0] == len(df)
+    cents = lambda s: np.round(s * 100).astype(np.int64)  # noqa: E731
+    assert round(got["rev"][0] * 10**4) == int(
+        (cents(df["price"]) * cents(df["qty"])).sum())
+    assert got["mn"][0] == df["price"].min() and got["mx"][0] == df["qty"].max()
+    # a partial over no live rows emits no row
+    none = HashAggregateExec(
+        FilterExec(scan_of(df, 1), E.BinOp(">", E.Column("qty"), E.Lit(10**9))),
+        [], _GLOBAL_AGGS, "partial")
+    (b,) = none.execute(0, c)
+    assert b.capacity == 1 and b.num_rows == 0
+
+
+@pytest.mark.parametrize("mode", ["single", "final"])
+def test_keyless_aggregate_over_empty_input_is_one_row(mode):
+    """SQL: count = 0 and sum/min/max = NULL, from 'single' directly and
+    from 'final' over partials that emitted nothing."""
+    df = lineitem_like(10)
+    plan = FilterExec(scan_of(df, 1), E.BinOp(">", E.Column("qty"), E.Lit(10**9)))
+    aggs = _GLOBAL_AGGS
+    if mode == "final":
+        plan = HashAggregateExec(plan, [], aggs, "partial")
+        aggs = _GLOBAL_MERGE
+    got = run_all(HashAggregateExec(plan, [], aggs, mode))
+    assert len(got) == 1 and got["c"][0] == 0
+    assert got[["rev", "mn", "mx"]].isna().all(axis=None)
+
+
+def test_keyless_aggregate_spilled_equals_in_memory(tmp_path):
+    """A denied reservation aggregates batch by batch and merges the
+    spilled one-row states: the same bits as the one-shot reduction."""
+    from arrow_ballista_tpu.memory.governor import MemoryGovernor
+
+    df = lineitem_like(3000, seed=3)
+    df.loc[:999, "k"] = 40
+    # three batches a partition, the first with no live row
+    src = FilterExec(CoalescePartitionsExec(scan_of(df, 3)),
+                     E.BinOp("<", E.Column("k"), E.Lit(25)))
+    frames = {}
+    for leg, gov in (("inmem", None),
+                     ("spilled", MemoryGovernor(host_budget=1))):
+        agg = HashAggregateExec(src, [], _GLOBAL_AGGS, "single")
+        c = TaskContext(config=BallistaConfig(), governor=gov,
+                        work_dir=str(tmp_path), job_id=leg)
+        (b,) = agg.execute(0, c)
+        frames[leg] = b.compacted_numpy()
+        assert ("spill_runs" in agg.metrics().to_dict()) == (gov is not None)
+    assert frames["inmem"]["c"][0] == (df["k"] < 25).sum() > 0
+    for name, arr in frames["inmem"].items():
+        assert arr.tobytes() == frames["spilled"][name].tobytes(), name
 
 
 def test_inner_join_matches_pandas():
