@@ -86,6 +86,7 @@ _COUNTER_KEYS = (
     "h2d_bytes", "d2h_bytes", "h2d_transfers", "d2h_transfers",
     "h2d_time", "d2h_time",
     "program_cache_hits", "program_cache_misses",
+    "mesh_reshard_bytes", "mesh_programs", "mesh_collective_bytes",
 )
 _PEAK_KEYS = ("device_live_peak_bytes", "host_rss_peak_bytes")
 
@@ -277,11 +278,12 @@ class _Boundary:
     """A task thread at the host/device boundary, as an interval: a
     ``device_wait`` span (the thread blocks until the device has produced
     what it fetches; ``site`` ``d2h`` for a bulk fetch, ``scalar`` for a
-    flag or a count) or an ``h2d`` span (the enqueue cost of
-    ``device_put``).  Bulk transfers feed ``record_transfer`` from the
-    span's own two readings of the clock; set ``nbytes`` inside the block
-    where the size is only known afterwards.  Scalar waits carry a handful
-    of bytes and stay out of the byte counters."""
+    flag or a count, ``ready`` for arrays that stay on the device) or an
+    ``h2d`` span (the enqueue cost of ``device_put``).  Bulk transfers
+    feed ``record_transfer`` from the span's own two readings of the
+    clock; set ``nbytes`` inside the block where the size is only known
+    afterwards.  Scalar waits carry a handful of bytes, ``ready`` waits
+    none, and both stay out of the byte counters."""
     __slots__ = ("name", "site", "nbytes", "_scope", "_span", "_t0")
 
     def __init__(self, name: str, site: str, nbytes: int = 0):
@@ -298,7 +300,7 @@ class _Boundary:
         if self.nbytes:
             sp.set(bytes=int(self.nbytes))
         self._scope.__exit__(et, ev, tb)
-        if self.site != "scalar" and et is None:
+        if self.site in ("d2h", "h2d") and et is None:
             record_transfer(self.site, self.nbytes,
                             ((sp.end_ns or tracing.now_ns()) - self._t0)
                             / 1e9)
@@ -311,6 +313,23 @@ def device_wait(site: str, nbytes: int = 0) -> _Boundary:
 
 def h2d(nbytes: int) -> _Boundary:
     return _Boundary("h2d", "h2d", nbytes)
+
+
+def record_mesh_reshard(nbytes: int) -> None:
+    """Bytes of one batch placed row-sharded over the device mesh
+    (ops/mesh_exec.py ``_shard_rows``): device to devices, no host
+    crossing, so not ``h2d_bytes``."""
+    if _enabled:
+        _record("mesh_reshard_bytes", int(nbytes))
+
+
+def record_mesh_program(collective_bytes: int) -> None:
+    """One dispatched mesh program and the bytes it hands to its
+    collective, summed over the devices (parallel/distributed.py
+    ``MeshProgram.collective_bytes``)."""
+    if _enabled:
+        _record("mesh_programs", 1)
+        _record("mesh_collective_bytes", int(collective_bytes))
 
 
 def record_program_cache(hit: bool) -> None:

@@ -441,9 +441,10 @@ def _grouped_aggregate_on_order(
 # reductions decompose into exact 16-bit limbs:
 #
 # - sums: limb rows x one-hot(segment) matmul per row-chunk (chunk bound
-#   keeps per-chunk limb sums inside int32), recombined in int64 — measured
-#   ~1000x the segment_sum x8 shape; falls back to chunk-offset int32
-#   segment_sums when the segment count makes one-hot tiles too large.
+#   keeps per-chunk limb sums inside int32), summed over the chunks and
+#   recombined in 32-bit arithmetic alone (_recombine_chunk_limbs) —
+#   measured ~1000x the segment_sum x8 shape; falls back to chunk-offset
+#   int32 segment_sums when the segment count makes one-hot tiles too large.
 # - min/max: lexicographic two-pass over (hi32, lo32-with-flipped-sign)
 #   int32 segment_min/max; identity values recombine to exactly the int64
 #   idents, so empty slots stay mergeable (mesh pmin/pmax).
@@ -473,9 +474,41 @@ def _i64_limbs(v: jnp.ndarray) -> List[jnp.ndarray]:
             for i in range(4)]
 
 
-def _recombine_limbs(parts: jnp.ndarray) -> jnp.ndarray:
-    """parts: int64[4, S] limb sums -> int64[S]."""
-    return sum(parts[i] << (16 * i) for i in range(4))
+def _recombine_chunk_limbs(parts: jnp.ndarray) -> jnp.ndarray:
+    """parts: int32[C, 4, ...], per chunk the sums (each in [0, 2^31)) of
+    the four 16-bit limbs -> int64[...], the sum over the chunks recombined
+    exactly mod 2^64.
+
+    In 32-bit arithmetic alone, and the 64-bit value only assembled from
+    its two words by a bitcast: the chip's emulated 64-bit addition is not
+    to be trusted with it.  As ``sum(limb_sum[i] << 16 * i)`` over int64
+    limb sums the v5e compiler gave 0x1e8f40f0694c for limb sums 0xc130694c,
+    0x1e8effec, 0, 0 (right: 0x1e8fc11c694c) in q1's sum_disc_price, inside
+    this kernel and in some fusions of the recombination alone, not in
+    others (PR 28: one group of one sum wrong on one of two seeds).
+
+    The chunk sums are split into 16-bit halves so that their sums over up
+    to 2^15 chunks stay under 2^31, then the carries are taken out digit by
+    digit (base 2^16; every intermediate under 2^32)."""
+    p = parts.astype(jnp.uint32)
+    lo16 = jnp.sum(p & jnp.uint32(0xFFFF), axis=0, dtype=jnp.uint32)
+    hi16 = jnp.sum(p >> jnp.uint32(16), axis=0, dtype=jnp.uint32)
+    digits = []
+    carry = jnp.zeros_like(lo16[0])
+    for i in range(4):
+        t = lo16[i] + carry
+        if i:
+            t = t + hi16[i - 1]
+        digits.append(t & jnp.uint32(0xFFFF))
+        carry = t >> jnp.uint32(16)
+    words = jnp.stack([digits[0] | (digits[1] << jnp.uint32(16)),
+                       digits[2] | (digits[3] << jnp.uint32(16))], axis=-1)
+    return jax.lax.bitcast_convert_type(words, jnp.int64)
+
+
+# chunks a call's rows may make: the 16-bit halves of 2^15 chunk sums add
+# up to less than 2^31 and every digit of the carry chain stays under 2^32
+_MAX_CHUNKS = 1 << 15
 
 
 def grouped_sums_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
@@ -489,6 +522,9 @@ def grouped_sums_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
                 for v in vals]
     n = seg.shape[0]
     S = num_segments
+    if -(-n // _SEG_CHUNK) > _MAX_CHUNKS:
+        # 2^30 rows in one call: past what the 32-bit recombination holds
+        return [jax.ops.segment_sum(v, seg, num_segments=S) for v in vals]
     if S <= _MATMUL_SEG_LIMIT:
         chunk = min(_SEG_CHUNK, n)
         pad = (-n) % chunk
@@ -513,11 +549,12 @@ def grouped_sums_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
             return None, jax.lax.dot_general(l, oh, (((1,), (0,)), ((), ())))
 
         _, parts = jax.lax.scan(body, None, (lhs, segc))
-        acc = jnp.sum(parts.astype(jnp.int64), axis=0)
-        return [_recombine_limbs(acc[4 * i:4 * i + 4])
-                for i in range(len(vals))]
+        # [chunks, values x limbs, S] -> [chunks, limbs, values, S]
+        return list(_recombine_chunk_limbs(
+            parts.reshape(parts.shape[0], len(vals), 4, S).transpose(
+                0, 2, 1, 3)))
     # large segment count: chunk-offset int32 segment_sums per limb (per
-    # chunk x segment a limb sum stays < 2^31), recombined in int64
+    # chunk x segment a limb sum stays < 2^31), recombined as above
     chunk = min(_SEG_CHUNK, n)
     S1 = S + 1  # one scratch slot for padded rows
     n_chunks = -(-n // chunk)
@@ -539,12 +576,9 @@ def grouped_sums_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
     for v in vals:
         if pad:
             v = jnp.concatenate([v, jnp.zeros(pad, v.dtype)])
-        parts = []
-        for limb in _i64_limbs(v):
-            p = jax.ops.segment_sum(limb, ids, num_segments=C * S1)
-            parts.append(jnp.sum(p.reshape(C, S1).astype(jnp.int64),
-                                 axis=0)[:S])
-        out.append(_recombine_limbs(jnp.stack(parts)))
+        parts = [jax.ops.segment_sum(limb, ids, num_segments=C * S1)
+                 .reshape(C, S1) for limb in _i64_limbs(v)]
+        out.append(_recombine_chunk_limbs(jnp.stack(parts, axis=1))[:S])
     return out
 
 

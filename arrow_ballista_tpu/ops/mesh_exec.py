@@ -25,11 +25,15 @@ import jax.numpy as jnp
 from ..models import expr as E
 from ..models.batch import ColumnBatch, concat_batches
 from ..models.schema import Field, Schema
+from ..obs import device as device_obs
+from ..obs.tracing import span
 from ..utils.config import AGG_CAPACITY, JOIN_OUTPUT_FACTOR, MESH_BROADCAST_ROWS
 from ..utils.errors import CapacityError
 from .expressions import ExprCompiler
 from .operators import AggSpec, HashAggregateExec, null_check_of, valid_of
-from .physical import ExecutionPlan, Partitioning, TaskContext, deferred_rows
+from .physical import (ExecutionPlan, Partitioning, TaskContext, deferred_rows,
+                       exprs_sig, has_scalar_subquery, schema_sig,
+                       shared_program)
 
 
 def _pow2(n: int) -> int:
@@ -39,17 +43,70 @@ def _pow2(n: int) -> int:
     return max(64, 1 << max(0, int(n) - 1).bit_length())
 
 
-def _unshard(x: jnp.ndarray) -> jnp.ndarray:
-    """Collapse a mesh-sharded result to one ordinary single-device array.
+def _unshard(tree):
+    """Collapse a mesh program's outputs to ordinary single-device arrays.
 
     Downstream operators run eager single-device ops; feeding them sharded
     arrays makes every eager op an 8-device collective program, and
     concurrently dispatched collective programs deadlock XLA's CPU
     rendezvous (observed: 'Expected 8 threads to join ... only 6 arrived'
-    -> hard abort).  The fused program's outputs are small (group states /
-    join rows), so one host hop is cheap and keeps the mesh strictly
-    inside shard_map."""
-    return jnp.asarray(np.asarray(x))
+    -> hard abort).  So the outputs take one host hop, all of them in one
+    fetch and one placement, accounted like any other (``device_wait``
+    ``d2h``, ``h2d``): small for group states, the size of the result for
+    a join."""
+    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
+    with device_obs.device_wait("d2h", nbytes):
+        host = jax.device_get(tree)
+    with device_obs.h2d(nbytes):
+        return jax.device_put(host)
+
+
+def _shard_rows(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray, mesh,
+                n_dev: int):
+    """Rows data-parallel over the mesh, padded to a device-count multiple:
+    ``(cols, mask, padded_rows)``.  A ``mesh_reshard`` span that ends when
+    every shard is in place (the copies are asynchronous; the wait is a
+    ``device_wait``), and ``mesh_reshard_bytes`` for what was placed."""
+    from ..parallel.mesh import row_sharding
+
+    rows = int(mask.shape[0])
+    padded = -(-rows // n_dev) * n_dev
+
+    def pad(arr, fill=0):
+        if padded == rows:
+            return arr
+        return jnp.concatenate(
+            [arr, jnp.full((padded - rows,), fill, arr.dtype)])
+
+    with span("mesh_reshard", "device", devices=n_dev, rows=rows) as sp:
+        cols = {k: pad(v) for k, v in cols.items()}
+        mask = pad(mask, fill=False)
+        nbytes = mask.nbytes + sum(v.nbytes for v in cols.values())
+        sp.set(bytes=nbytes)
+        # ballista: allow=host-device-boundary — mesh placement, not a host crossing: the source is already device-resident; counted as mesh_reshard_bytes
+        cols, mask = jax.device_put((cols, mask), row_sharding(mesh))
+        with device_obs.device_wait("ready"):
+            jax.block_until_ready((cols, mask))
+    device_obs.record_mesh_reshard(nbytes)
+    return cols, mask, padded
+
+
+def _dispatch(prog, *args):
+    """One call of a mesh program (parallel/distributed.py ``MeshProgram``):
+    a ``mesh_program`` span from the dispatch, under the process-wide
+    dispatch lock, until the outputs are ready.  The overflow flag is the
+    program's last output, so fetching it (a ``device_wait``) waits for all
+    of them.  Returns the outputs with the flag as a host bool."""
+    from ..parallel.mesh import MESH_DISPATCH_LOCK
+
+    with span("mesh_program", "device", program=prog.name,
+              collective=prog.collective):
+        with MESH_DISPATCH_LOCK:
+            *out, overflow = prog(*args)
+        with device_obs.device_wait("scalar"):
+            overflow = bool(overflow)
+    device_obs.record_mesh_program(prog.collective_bytes(*args))
+    return (*out, overflow)
 
 
 # --- shared pieces of the two mesh aggregate operators ---------------------
@@ -95,15 +152,17 @@ def _agg_specs(val_c):
     return specs, hidden
 
 
-def _make_derive(key_c, val_c, aux):
+def _make_derive(key_c, val_c):
     """Per-shard projection: group keys + aggregate operand columns.
     NULL operand rows are neutralized per aggregate (0 for sum, the
     fold identity for min/max, a 0/1 indicator for count) and tracked via
-    hidden validity columns."""
+    hidden validity columns.  ``aux`` (the expressions' lookup tables) is
+    an argument of the program, replicated, so one compiled program serves
+    every dictionary of the same shape."""
 
     from . import kernels as K
 
-    def derive(cols, mask):
+    def derive(cols, mask, aux):
         out = {}
         for kc, n in key_c:
             out[n] = kc.fn(cols, aux)
@@ -133,27 +192,6 @@ def _make_derive(key_c, val_c, aux):
     return derive
 
 
-def _shard_batch(big: ColumnBatch, mesh, n_dev: int):
-    """Rows data-parallel over the mesh, padded to a device-count multiple.
-    Returns (cols, mask, padded_rows)."""
-    from ..parallel.mesh import row_sharding
-
-    rows = big.capacity
-    per = -(-rows // n_dev)
-    padded = per * n_dev
-    sharding = row_sharding(mesh)
-
-    def shard(arr, fill=0):
-        if padded != rows:
-            pad = jnp.full((padded - rows,), fill, arr.dtype)
-            arr = jnp.concatenate([arr, pad])
-        # ballista: allow=host-device-boundary — mesh placement, not a host crossing: the source is already device-resident; its bytes are not accounted yet
-        return jax.device_put(arr, sharding)
-
-    return ({k: shard(v) for k, v in big.columns.items()},
-            shard(big.mask, fill=False), padded)
-
-
 def _agg_key_ranges(key_c, dicts):
     """Static per-key bounds for the dense sort-free grouping path
     (kernels.grouped_aggregate): dict-code ranges for strings, {0,1} for
@@ -173,24 +211,46 @@ def _finish_states(schema, key_c, val_c, ks, vs, msk, big_dicts,
     (``hidden_specs`` order); all-NULL groups are restored to the output
     sentinel here, after the exchange."""
     n_main = len(val_c)
+    ks, vs, msk = _unshard((list(ks), list(vs), msk))
     out_cols: Dict[str, jnp.ndarray] = {}
     dicts: Dict[str, np.ndarray] = {}
     for (kc, name), arr in zip(key_c, ks):
-        out_cols[name] = _unshard(arr)
+        out_cols[name] = arr
         if kc.dict_fn is not None:
             dicts[name] = kc.dict_fn(big_dicts)
     for (cc, a, _nc), arr in zip(val_c, vs[:n_main]):
         want = schema.field(a.name).dtype.np_dtype
-        arr = _unshard(arr)
         out_cols[a.name] = arr.astype(want) if arr.dtype != want else arr
     for (hname, _how), cnt in zip(hidden_specs, vs[n_main:]):
         name = _hidden_base(hname)
-        f = schema.field(name)
-        cnt = np.asarray(_unshard(cnt))
-        col = np.asarray(out_cols[name])
-        out_cols[name] = jnp.asarray(
-            np.where(cnt > 0, col, col.dtype.type(f.dtype.null_sentinel)))
-    return ColumnBatch(schema, out_cols, _unshard(msk), dicts)
+        sentinel = out_cols[name].dtype.type(
+            schema.field(name).dtype.null_sentinel)
+        out_cols[name] = jnp.where(cnt > 0, out_cols[name], sentinel)
+    return ColumnBatch(schema, out_cols, msk, dicts)
+
+
+def _program(op, key, build):
+    """The mesh program for ``key``: shared across jobs
+    (``shared_program``), and kept on the operator, which is all the
+    sharing a key holding None gets (one build for a stage's tasks)."""
+    prog = op._progs.get(key)
+    if prog is None:
+        prog = op._progs[key] = shared_program(key, build)
+    return prog
+
+
+def _agg_program_key(in_schema, group_exprs, aggs):
+    """What a mesh aggregate's compiled expressions and programs depend on
+    besides shapes: the key they are shared under across jobs
+    (``shared_program``), or one holding None where a scalar subquery bakes
+    a job's own literal in."""
+    exprs = [e for e, _ in group_exprs] + [a.operand for a in aggs]
+    if has_scalar_subquery(*exprs):
+        return (None,)
+    return (schema_sig(in_schema), exprs_sig([e for e, _ in group_exprs]),
+            tuple(n for _, n in group_exprs),
+            tuple((a.func, a.name) for a in aggs),
+            exprs_sig([a.operand for a in aggs]))
 
 
 class MeshAggregateExec(ExecutionPlan):
@@ -214,6 +274,7 @@ class MeshAggregateExec(ExecutionPlan):
             fields.append(ref.schema.field(a.name))
         self._schema = Schema(fields)
         self._compiled = None
+        self._progs = {}
 
     @staticmethod
     def eligible(group_exprs, aggs, in_schema) -> bool:
@@ -264,8 +325,10 @@ class MeshAggregateExec(ExecutionPlan):
             return self._execute(partition, ctx)
 
     def _execute(self, partition: int, ctx: TaskContext) -> List[ColumnBatch]:
-        from ..parallel.distributed import distributed_filter_aggregate
-        from ..parallel.mesh import MESH_DISPATCH_LOCK, make_mesh, row_sharding
+        from ..parallel.distributed import (distributed_dense_aggregate,
+                                            distributed_filter_aggregate)
+        from ..parallel.mesh import make_mesh
+        from .kernels import dense_domain
 
         assert partition == 0
         in_schema = self.input.schema
@@ -273,21 +336,28 @@ class MeshAggregateExec(ExecutionPlan):
         for p in range(self.input.output_partition_count()):
             batches.extend(self.input.execute(p, ctx))
         big = concat_batches(in_schema, batches)
+        del batches
 
         n_dev = len(jax.devices())
         mesh = make_mesh(n_dev)
 
+        base = _agg_program_key(in_schema, self.group_exprs, self.aggs)
         if self._compiled is None:
-            self._compiled = _compile_agg_exprs(in_schema, self.group_exprs,
-                                                self.aggs)
+            self._compiled = shared_program(
+                ("mesh_agg_exprs",) + base,
+                lambda: _compile_agg_exprs(in_schema, self.group_exprs,
+                                           self.aggs))
         comp, key_c, val_c = self._compiled
-        aux = comp.aux_arrays(big.dicts)  # replicated constants in the program
+        dicts = big.dicts
+        aux = comp.aux_arrays(dicts)  # replicated operands of the program
 
         key_names = [n for _, n in key_c]
         specs, hidden = _agg_specs(val_c)
         agg_specs = specs + hidden
-        derive = _make_derive(key_c, val_c, aux)
-        cols, mask, padded = _shard_batch(big, mesh, n_dev)
+        cols, mask, padded = _shard_rows(big.columns, big.mask, mesh, n_dev)
+        # the concatenated copy is dead once its shards are in place: drop
+        # it before the program asks for its workspace beside it
+        del big
 
         cap = ctx.config.get(AGG_CAPACITY)
         # partial states are bounded by the shard size; the final aggregate
@@ -295,41 +365,44 @@ class MeshAggregateExec(ExecutionPlan):
         # bound must respond to the config knob
         partial_cap = max(256, min(cap, padded // n_dev + 1))
         final_cap = max(256, min(cap, padded + 1))
-        key_ranges = _agg_key_ranges(key_c, big.dicts)
-        from .kernels import dense_domain
-
+        key_ranges = _agg_key_ranges(key_c, dicts)
         domain = dense_domain(key_ranges)
+        # one program a plan shape, device count and static bound, shared
+        # across jobs: a re-run of the query traces and compiles nothing
         if domain is not None:
             # dense domain: slot-aligned accumulators merge by ONE
             # psum/pmin/pmax — the exchange disappears entirely
             # (distributed_dense_aggregate); overflow here can only mean a
             # key escaped its declared range
-            from ..parallel.distributed import distributed_dense_aggregate
-
-            run = distributed_dense_aggregate(
-                mesh, derive, key_names, agg_specs, key_ranges, domain)
-            with MESH_DISPATCH_LOCK:
-                fk, fv, fmask, overflow = run(cols, mask)
-            if bool(overflow):
+            prog = _program(
+                self, ("mesh_agg_dense", n_dev, key_ranges, domain) + base,
+                lambda: distributed_dense_aggregate(
+                    mesh, _make_derive(key_c, val_c), key_names, agg_specs,
+                    key_ranges, domain))
+            fk, fv, fmask, overflow = _dispatch(prog, cols, mask, aux)
+            if overflow:
                 raise CapacityError(
                     "mesh dense aggregation saw keys outside their declared "
                     "ranges (dictionary/batch mismatch)")
             self.metrics().add("dense_reduce_collective", 1)
         else:
-            run = distributed_filter_aggregate(
-                mesh, derive, key_names, agg_specs,
-                partial_capacity=partial_cap, final_capacity=final_cap,
-                key_ranges=key_ranges)
-            with MESH_DISPATCH_LOCK:
-                fk, fv, fmask, overflow = run(cols, mask)
-            if bool(overflow):
+            prog = _program(
+                self, ("mesh_agg_exchange", n_dev, key_ranges, partial_cap,
+                       final_cap) + base,
+                lambda: distributed_filter_aggregate(
+                    mesh, _make_derive(key_c, val_c), key_names, agg_specs,
+                    partial_capacity=partial_cap, final_capacity=final_cap,
+                    key_ranges=key_ranges))
+            fk, fv, fmask, overflow = _dispatch(prog, cols, mask, aux)
+            if overflow:
                 raise CapacityError(
                     f"mesh aggregation exceeded its group capacity "
                     f"(partial {partial_cap}/device, final {final_cap}/device); "
                     f"raise {AGG_CAPACITY}")
+        del cols, mask
 
         result = _finish_states(self._schema, key_c, val_c, fk, fv, fmask,
-                                big.dicts, hidden_specs=hidden)
+                                dicts, hidden_specs=hidden)
         # deferred: the count becomes host-known for free when the shuffle
         # writer's packed fetch materializes this batch (an eager .num_rows
         # costs a scalar sync per task where remote_device() holds)
@@ -369,6 +442,7 @@ class MeshPartialAggregateExec(ExecutionPlan):
         ref = HashAggregateExec(input, group_exprs, aggs, mode="partial")
         self._schema = ref.schema
         self._compiled = None
+        self._progs = {}
 
     eligible = MeshAggregateExec.eligible
 
@@ -384,7 +458,8 @@ class MeshPartialAggregateExec(ExecutionPlan):
 
     def _execute(self, partition: int, ctx: TaskContext) -> List[ColumnBatch]:
         from ..parallel.distributed import distributed_partial_aggregate
-        from ..parallel.mesh import MESH_DISPATCH_LOCK, make_mesh, row_sharding
+        from ..parallel.mesh import make_mesh
+        from .kernels import dense_domain
 
         in_schema = self.input.schema
         big = concat_batches(in_schema, self.input.execute(partition, ctx))
@@ -392,55 +467,51 @@ class MeshPartialAggregateExec(ExecutionPlan):
         n_dev = len(jax.devices())
         mesh = make_mesh(n_dev)
 
+        base = _agg_program_key(in_schema, self.group_exprs, self.aggs)
         with self.xla_lock():
             if self._compiled is None:
-                self._compiled = _compile_agg_exprs(
-                    in_schema, self.group_exprs, self.aggs)
-                self._runs = {}
+                self._compiled = shared_program(
+                    ("mesh_agg_exprs",) + base,
+                    lambda: _compile_agg_exprs(
+                        in_schema, self.group_exprs, self.aggs))
             comp, key_c, val_c = self._compiled
-            aux = comp.aux_arrays(big.dicts)
+            dicts = big.dicts
+            aux = comp.aux_arrays(dicts)
 
             key_names = [n for _, n in key_c]
             specs, hidden = _agg_specs(val_c)
             agg_specs = specs + hidden
-            cols, mask, padded = _shard_batch(big, mesh, n_dev)
+            cols, mask, padded = _shard_rows(big.columns, big.mask, mesh,
+                                             n_dev)
+            del big
 
             cap = ctx.config.get(AGG_CAPACITY)
             per_dev_cap = max(64, min(cap, padded // n_dev + 1))
-            key_ranges = _agg_key_ranges(key_c, big.dicts)
-            from .kernels import dense_domain
-
+            key_ranges = _agg_key_ranges(key_c, dicts)
             domain = dense_domain(key_ranges)
             if domain is not None:
                 per_dev_cap = min(per_dev_cap, domain)
-            # reuse the compiled shard_map program across a stage's N
-            # partition tasks — they share this operator instance, and
-            # re-tracing an identical program per task would serialize N
-            # duplicate compiles under xla_lock.  aux LUTs are baked into
-            # the closure as constants, so their content is part of the key
-            # (per-partition scans can build different dictionaries).
-            aux_key = tuple(sorted(
-                (k, hash(v.tobytes()) if hasattr(v, "tobytes") else hash(str(v)))
-                for k, v in aux.items()))
-            run_key = (padded, per_dev_cap, key_ranges, aux_key)
-            run = self._runs.get(run_key)
-            if run is None:
-                run = distributed_partial_aggregate(
-                    mesh, _make_derive(key_c, val_c, aux), key_names,
-                    agg_specs, per_dev_cap, key_ranges=key_ranges)
-                self._runs[run_key] = run
-            with MESH_DISPATCH_LOCK:
-                pk, pv, pmask, overflow = run(cols, mask)
-            if bool(overflow):
+            # one program for a stage's N partition tasks and for every
+            # later job of the same plan shape (the lookup tables are
+            # operands, so per-partition dictionaries share it too)
+            prog = _program(
+                self,
+                ("mesh_agg_partial", n_dev, key_ranges, per_dev_cap) + base,
+                lambda: distributed_partial_aggregate(
+                    mesh, _make_derive(key_c, val_c), key_names, agg_specs,
+                    per_dev_cap, key_ranges=key_ranges))
+            pk, pv, pmask, overflow = _dispatch(prog, cols, mask, aux)
+            if overflow:
                 raise CapacityError(
                     f"mesh partial aggregation exceeded {per_dev_cap} "
                     f"groups/device; raise {AGG_CAPACITY}")
+            del cols, mask
 
         # all-NULL partial states become sentinels here, exactly like the
         # file partial mode — the downstream final aggregate's value-based
         # null_check then skips them when merging across hosts
         result = _finish_states(self._schema, key_c, val_c, pk, pv, pmask,
-                                big.dicts, hidden_specs=hidden)
+                                dicts, hidden_specs=hidden)
         # deferred: the count becomes host-known for free when the shuffle
         # writer's packed fetch materializes this batch (an eager .num_rows
         # costs a scalar sync per task where remote_device() holds)
@@ -482,6 +553,7 @@ class MeshJoinExec(ExecutionPlan):
         else:
             self._schema = left.schema.merge(right.schema)
         self._compiled = None
+        self._progs = {}
 
     @staticmethod
     def eligible(on, join_type, filter, lsch, rsch) -> bool:
@@ -523,17 +595,16 @@ class MeshJoinExec(ExecutionPlan):
 
     def _join_batches(self, probe: ColumnBatch, build: ColumnBatch,
                       ctx: TaskContext) -> List[ColumnBatch]:
-        from ..parallel.distributed import distributed_hash_join
-        from ..parallel.mesh import MESH_DISPATCH_LOCK, make_mesh, row_sharding
+        from ..parallel.distributed import (distributed_broadcast_join,
+                                            distributed_hash_join)
+        from ..parallel.mesh import make_mesh
 
         lsch, rsch = self.left.schema, self.right.schema
         n_dev = len(jax.devices())
         mesh = make_mesh(n_dev)
 
-        # compile + run-factory state is shared across a stage's tasks
-        # (MeshTaskJoinExec runs one task per partition); the factories'
-        # inner jits retrace per shape, so one run object per capacity
-        # signature serves every task
+        # the compiled keys are shared across a stage's tasks
+        # (MeshTaskJoinExec runs one task per partition)
         with self.xla_lock():
             if self._compiled is None:
                 lcomp = ExprCompiler(lsch, "device")
@@ -541,7 +612,6 @@ class MeshJoinExec(ExecutionPlan):
                 lkeys = [lcomp.compile_key(le) for le, _ in self.on]
                 rkeys = [rcomp.compile_key(re_) for _, re_ in self.on]
                 self._compiled = (lcomp, rcomp, lkeys, rkeys)
-                self._runs = {}
         lcomp, rcomp, lkeys, rkeys = self._compiled
         laux = lcomp.aux_arrays(probe.dicts)
         raux = rcomp.aux_arrays(build.dicts)
@@ -579,104 +649,75 @@ class MeshJoinExec(ExecutionPlan):
                                  probe.columns, probe.mask, laux)
 
         # shard rows over the mesh (pad to a multiple of the device count)
-        sharding = row_sharding(mesh)
-
-        def shard_side(cols, mask):
-            rows = mask.shape[0]
-            per = -(-rows // n_dev)
-            padded = per * n_dev
-
-            def pad(arr, fill=0):
-                if padded != rows:
-                    arr = jnp.concatenate(
-                        [arr, jnp.full((padded - rows,), fill, arr.dtype)])
-                # ballista: allow=host-device-boundary — mesh placement, not a host crossing: the source is already device-resident; its bytes are not accounted yet
-                return jax.device_put(arr, sharding)
-
-            return ({k: pad(v) for k, v in cols.items()},
-                    pad(mask, fill=False), padded)
-
-        dp, dpm, p_rows = shard_side(pcols, pmask_in)
-        db, dbm, b_rows = shard_side(bcols, bmask_in)
+        dp, dpm, p_rows = _shard_rows(pcols, pmask_in, mesh, n_dev)
+        db, dbm, b_rows = _shard_rows(bcols, bmask_in, mesh, n_dev)
 
         out_factor = ctx.config.get(JOIN_OUTPUT_FACTOR)
         rfill = {f.name: f.dtype.null_sentinel for f in rsch}
         sentinel = int(ExprCompiler.NULL_KEY_SENTINEL)
         broadcast = build.num_rows <= ctx.config.get(MESH_BROADCAST_ROWS)
+        # the join programs depend on names, dtypes (through the shapes jit
+        # keys on) and these statics alone, so jobs share them
+        base = (n_dev, len(self.on), tuple(lsch.names()),
+                tuple(rsch.names()), self.join_type,
+                tuple(sorted(rfill.items())), tuple(sflags), sentinel)
 
-        if broadcast:
-            # small build side: all_gather it, probe rows never move
-            # (CollectLeft analog, distributed_broadcast_join); output bound
-            # is per-device probe rows x fan-out factor
-            from ..parallel.distributed import distributed_broadcast_join
-
-            out_cap = _pow2(out_factor * (p_rows // n_dev))
-            attempts = 0
-            while True:
-                with self.xla_lock():
-                    run = self._runs.get(("bc", out_cap))
-                    if run is None:
-                        run = distributed_broadcast_join(
+        # per-device output bound: start at the EXPECTED per-device probe
+        # share x fan-out factor, not the worst-case receive bound — a
+        # too-small guess recompiles via the overflow-retry doubling, a
+        # too-large one allocates (and gathers into) multi-GB outputs
+        # every run (measured: q3's old 2x-shuffle-capacity bound put a
+        # 24M-row output gather on a 30k-row result)
+        out_cap = _pow2(out_factor * (p_rows // n_dev))
+        # per-device shuffle capacity: worst case every row of a side
+        # hashes to one bucket of one device's send buffer; factor 2
+        # covers skew, overflow re-runs at the true bound.  A small build
+        # side is all_gathered instead and the probe rows never move
+        # (CollectLeft analog, distributed_broadcast_join)
+        shuf_cap = None if broadcast \
+            else _pow2(2 * max(p_rows, b_rows) // n_dev)
+        attempts = 0
+        while True:
+            with self.xla_lock():
+                if broadcast:
+                    prog = _program(
+                        self, ("mesh_join_broadcast", out_cap) + base,
+                        lambda: distributed_broadcast_join(
                             mesh, len(self.on), list(lsch.names()),
                             list(rsch.names()), self.join_type, out_cap,
                             rfill, string_key_flags=sflags,
-                            null_key_sentinel=sentinel)
-                        self._runs[("bc", out_cap)] = run
-                with MESH_DISPATCH_LOCK:
-                    out_cols, out_mask, overflow = run((dp, dpm), (db, dbm))
-                if not bool(overflow):
-                    break
-                attempts += 1
-                if attempts > 3:
-                    raise CapacityError(
-                        f"mesh broadcast join overflowed its output capacity "
-                        f"({out_cap}) after retries")
-                out_cap *= 2
-                self.metrics().add("capacity_recompiles", 1)
-            self.metrics().add("broadcast_joins", 1)
-        else:
-            # per-device shuffle capacity: worst case every row of a side
-            # hashes to one bucket of one device's send buffer; factor 2
-            # covers skew, overflow re-runs at the true bound
-            shuf_cap = _pow2(2 * max(p_rows, b_rows) // n_dev)
-            # per-device output bound: start at the EXPECTED per-device probe
-            # share x fan-out factor, not the worst-case receive bound — a
-            # too-small guess recompiles via the overflow-retry doubling, a
-            # too-large one allocates (and gathers into) multi-GB outputs
-            # every run (measured: q3's old 2x-shuffle-capacity bound put a
-            # 24M-row output gather on a 30k-row result)
-            out_cap = _pow2(out_factor * (p_rows // n_dev))
-
-            attempts = 0
-            while True:
-                with self.xla_lock():
-                    run = self._runs.get(("part", shuf_cap, out_cap))
-                    if run is None:
-                        run = distributed_hash_join(
+                            null_key_sentinel=sentinel))
+                else:
+                    prog = _program(
+                        self,
+                        ("mesh_join_partitioned", shuf_cap, out_cap) + base,
+                        lambda: distributed_hash_join(
                             mesh, len(self.on), list(lsch.names()),
                             list(rsch.names()), self.join_type, shuf_cap,
                             out_cap, rfill, string_key_flags=sflags,
-                            null_key_sentinel=sentinel)
-                        self._runs[("part", shuf_cap, out_cap)] = run
-                with MESH_DISPATCH_LOCK:
-                    out_cols, out_mask, overflow = run((dp, dpm), (db, dbm))
-                if not bool(overflow):
-                    break
-                attempts += 1
-                if attempts > 3:
-                    raise CapacityError(
-                        "mesh join overflowed its shuffle/output capacity "
-                        f"(shuffle {shuf_cap}, out {out_cap}) after retries")
+                            null_key_sentinel=sentinel))
+            out_cols, out_mask, overflow = _dispatch(prog, dp, dpm, db, dbm)
+            if not overflow:
+                break
+            attempts += 1
+            if attempts > 3:
+                raise CapacityError(
+                    f"mesh {'broadcast ' if broadcast else ''}join "
+                    f"overflowed its shuffle/output capacity (shuffle "
+                    f"{shuf_cap}, out {out_cap}) after retries")
+            out_cap *= 2
+            if shuf_cap is not None:
                 shuf_cap *= 2
-                out_cap *= 2
-                self.metrics().add("capacity_recompiles", 1)
+            self.metrics().add("capacity_recompiles", 1)
+        if broadcast:
+            self.metrics().add("broadcast_joins", 1)
+        del dp, dpm, db, dbm
 
         dicts = dict(probe.dicts)
         if self.join_type in ("inner", "left"):
             dicts.update(build.dicts)
-        result = ColumnBatch(self._schema,
-                             {k: _unshard(v) for k, v in out_cols.items()},
-                             _unshard(out_mask), dicts)
+        out_cols, out_mask = _unshard((out_cols, out_mask))
+        result = ColumnBatch(self._schema, out_cols, out_mask, dicts)
         # deferred: the count becomes host-known for free when the shuffle
         # writer's packed fetch materializes this batch (an eager .num_rows
         # costs a scalar sync per task where remote_device() holds)

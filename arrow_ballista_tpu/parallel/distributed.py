@@ -16,14 +16,14 @@ transport per stage boundary.
 from __future__ import annotations
 
 import math
-import threading
-from typing import Dict, Sequence, Tuple
+from typing import Callable, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..obs.device import observed_jit
 from ..ops import kernels as K
 from .ici_shuffle import shuffle_rows
 from .mesh import PART_AXIS, mesh_axis_size
@@ -36,8 +36,36 @@ def _shuffle_capacity(rows_per_shard: int, n: int, factor: float) -> int:
     return max(1, math.ceil(rows_per_shard / n * factor))
 
 
-def _identity_filter(cols, mask):
+def _identity_filter(cols, mask, *aux):
     return cols, mask
+
+
+def _row_bytes(arrays) -> int:
+    """Bytes of one row across ``arrays`` (columns of equal length)."""
+    return sum(a.dtype.itemsize for a in arrays)
+
+
+class MeshProgram:
+    """One program over the device mesh: an ``observed_jit`` around a
+    ``shard_map`` (so it is named, counted and spanned like every other
+    program), the collective it is built around (``dense_reduce`` |
+    ``all_to_all`` | ``all_gather`` | ``none``) and the bytes one call
+    hands to that collective, summed over the devices, from shapes."""
+
+    __slots__ = ("jit", "collective", "_bytes")
+
+    def __init__(self, jit, collective: str, bytes_of: Callable[..., int]):
+        self.jit, self.collective, self._bytes = jit, collective, bytes_of
+
+    @property
+    def name(self) -> str:
+        return self.jit.name
+
+    def collective_bytes(self, *args) -> int:
+        return int(self._bytes(*args))
+
+    def __call__(self, *args):
+        return self.jit(*args)
 
 
 def distributed_filter_aggregate(
@@ -68,9 +96,10 @@ def distributed_filter_aggregate(
     """
     n = mesh_axis_size(mesh, axis)
     cap = _shuffle_capacity(partial_capacity, n, skew_factor)
+    sent = {}       # bytes a device hands to the all_to_all, set as it traces
 
-    def per_shard(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray):
-        cols, mask = filter_fn(cols, mask)
+    def per_shard(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray, *aux):
+        cols, mask = filter_fn(cols, mask, *aux)
         keys = [cols[k] for k in key_names]
         vals = [(cols[v], how) for v, how in agg_specs]
         pk, pv, pmask, ovf1 = K.grouped_aggregate(keys, vals, mask,
@@ -78,6 +107,8 @@ def distributed_filter_aggregate(
                                                   key_ranges=key_ranges)
         shuffled = {f"k{i}": a for i, a in enumerate(pk)}
         shuffled.update({f"v{i}": a for i, a in enumerate(pv)})
+        # n buckets of cap state rows, each with its mask byte
+        sent["bytes"] = n * cap * (_row_bytes(shuffled.values()) + 1)
         dest = K.bucket_of(pk, n)
         recv, rmask, ovf2 = shuffle_rows(shuffled, dest, pmask, axis, n, cap)
         rk = [recv[f"k{i}"] for i in range(len(pk))]
@@ -89,13 +120,10 @@ def distributed_filter_aggregate(
         overflow = lax.psum(flags.astype(jnp.int32), axis) > 0
         return fk, fv, fmask, overflow
 
-    row = P(axis)
-
-    def make_specs(cols, mask):
-        return ({name: row for name in cols}, row), \
-               ([row] * len(key_names), [row] * len(agg_specs), row, P())
-
-    return _make_runner(per_shard, mesh, make_specs)
+    return _make_runner(
+        "mesh.agg_exchange", f"k{len(key_names)}", per_shard, mesh,
+        _agg_specs_of(axis, len(key_names), len(agg_specs), P(axis)),
+        "all_to_all", lambda *args: n * sent.get("bytes", 0))
 
 
 def distributed_dense_aggregate(
@@ -127,12 +155,18 @@ def distributed_dense_aggregate(
     front in ascending fused-key order, matching the sort path's order).
     """
 
-    def per_shard(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray):
-        cols, mask = filter_fn(cols, mask)
+    n = mesh_axis_size(mesh, axis)
+    sent = {}       # bytes a device hands to the reduce, set as it traces
+
+    def per_shard(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray, *aux):
+        cols, mask = filter_fn(cols, mask, *aux)
         keys = [cols[k] for k in key_names]
         vals = [(cols[v], how) for v, how in agg_specs]
         dense_vals, exists_cnt, bad = K.dense_group_states(
             keys, vals, mask, key_ranges, domain)
+        # [domain] slots of every aggregate and of the exists count
+        sent["bytes"] = sum(v.size * v.dtype.itemsize
+                            for v in (*dense_vals, exists_cnt))
         merged = []
         for v, (_, how) in zip(dense_vals, agg_specs):
             if how in ("sum", "count"):
@@ -148,14 +182,10 @@ def distributed_dense_aggregate(
             domain)
         return fk, fv, fmask, ovf | bad
 
-    row = P(axis)
-    rep = P()
-
-    def make_specs(cols, mask):
-        return ({name: row for name in cols}, row), \
-               ([rep] * len(key_names), [rep] * len(agg_specs), rep, rep)
-
-    return _make_runner(per_shard, mesh, make_specs)
+    return _make_runner(
+        "mesh.agg_dense", f"k{len(key_names)}", per_shard, mesh,
+        _agg_specs_of(axis, len(key_names), len(agg_specs), P()),
+        "dense_reduce", lambda *args: n * sent.get("bytes", 0))
 
 
 def distributed_partial_aggregate(
@@ -177,8 +207,8 @@ def distributed_partial_aggregate(
     Returns ``run(cols, mask) -> (keys, vals, mask, overflow)`` where each
     output is the concatenation of every device's ``capacity`` state rows.
     """
-    def per_shard(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray):
-        cols, mask = derive_fn(cols, mask)
+    def per_shard(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray, *aux):
+        cols, mask = derive_fn(cols, mask, *aux)
         keys = [cols[k] for k in key_names]
         vals = [(cols[v], how) for v, how in agg_specs]
         pk, pv, pmask, ovf = K.grouped_aggregate(keys, vals, mask, capacity,
@@ -186,82 +216,50 @@ def distributed_partial_aggregate(
         overflow = lax.psum(K.overflow_flag(ovf).astype(jnp.int32), axis) > 0
         return pk, pv, pmask, overflow
 
+    # the states leave through the file shuffle: no collective but the flag
+    return _make_runner(
+        "mesh.agg_partial", f"k{len(key_names)}", per_shard, mesh,
+        _agg_specs_of(axis, len(key_names), len(agg_specs), P(axis)),
+        "none", lambda *args: 0)
+
+
+def _agg_specs_of(axis: str, n_keys: int, n_aggs: int, out):
+    """``make_specs`` of the aggregate programs: columns and mask sharded by
+    rows, whatever follows them (the expressions' lookup tables) replicated;
+    keys, states and mask leave as ``out``, the overflow flag replicated."""
     row = P(axis)
 
-    def make_specs(cols, mask):
-        return ({name: row for name in cols}, row), \
-               ([row] * len(key_names), [row] * len(agg_specs), row, P())
+    def make_specs(cols, mask, *aux):
+        return ({name: row for name in cols}, row, *(P(),) * len(aux)), \
+               ([out] * n_keys, [out] * n_aggs, out, P())
 
-    return _make_runner(per_shard, mesh, make_specs)
-
-
-def _sig_of(cols, mask):
-    return (tuple((k, v.shape, str(v.dtype)) for k, v in sorted(cols.items())),
-            mask.shape)
+    return make_specs
 
 
-def _compile_once(cache: Dict, lock: threading.Lock, sig, build, args):
-    """Run ``build()(*args)`` exactly once per signature across threads.
+def _make_runner(sig: str, variant: str, per_shard, mesh, make_specs,
+                 collective: str, bytes_of) -> MeshProgram:
+    """``per_shard`` over ``mesh`` as one ``observed_jit`` program named
+    ``program_name(sig, variant)``.  ``make_specs(*args) -> (in_specs,
+    out_specs)`` reads only the arguments' structure, so it runs as the
+    program traces.  jit keys the executable on the arguments' shapes;
+    callers dispatch under ``MESH_DISPATCH_LOCK``, so two tasks never
+    compile one signature at once."""
 
-    jax.jit compiles lazily at the FIRST call; concurrent same-stage tasks
-    (MeshTaskJoinExec spreads one runner over N partition tasks) would
-    otherwise both trace+compile the same minutes-long TPU program.  The
-    global lock covers only the cache lookup/registration — the owner
-    compiles OFF the lock (waiters for that signature block on its event;
-    callers of already-compiled signatures proceed immediately)."""
-    with lock:
-        entry = cache.get(sig)
-        owner = entry is None
-        if owner:
-            entry = [None, threading.Event()]
-            cache[sig] = entry
-    if owner:
-        try:
-            fn = build()
-            out = fn(*args)  # lazy trace+compile happens here
-        except BaseException:
-            with lock:
-                cache.pop(sig, None)
-            entry[1].set()
-            raise
-        entry[0] = fn
-        entry[1].set()
-        return out
-    entry[1].wait()
-    fn = entry[0]
-    if fn is None:
-        # the owner failed; retry as a fresh owner
-        return _compile_once(cache, lock, sig, build, args)
-    return fn(*args)
+    def program(*args):
+        in_specs, out_specs = make_specs(*args)
+        # ballista: allow=deprecated-jax-api — jax.shard_map is the installed jax's API
+        return jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs)(*args)
+
+    return MeshProgram(observed_jit(sig, program, variant=variant),
+                       collective, bytes_of)
 
 
-def _make_runner(per_shard, mesh, make_specs):
-    """Per-signature compile-once runner shared by every distributed
-    factory.  ``args`` is a flat sequence of (cols, mask) pairs;
-    ``make_specs(*args) -> (in_specs, out_specs)``."""
-
-    cache: Dict[Tuple, object] = {}
-    lock = threading.Lock()
-
-    def call(*args):
-        sig = tuple(_sig_of(args[i], args[i + 1])
-                    for i in range(0, len(args), 2))
-
-        def build():
-            in_specs, out_specs = make_specs(*args)
-            # ballista: allow=deprecated-jax-api — jax.shard_map is the installed jax's API
-            return jax.jit(jax.shard_map(per_shard, mesh=mesh,
-                                         in_specs=in_specs,
-                                         out_specs=out_specs))
-
-        return _compile_once(cache, lock, sig, build, args)
-
-    return call
-
-
-def _make_join_runner(per_shard, mesh, probe_names, build_names, join_type,
-                      axis):
-    """Runner for the two join variants (see _compile_once)."""
+def _make_join_runner(sig: str, per_shard, mesh, probe_names, build_names,
+                      join_type, axis, collective: str,
+                      bytes_of) -> MeshProgram:
+    """Runner for the two join variants: ``run(pcols, pmask, bcols,
+    bmask)``."""
     row = P(axis)
 
     def make_specs(pcols, pmask, bcols, bmask):
@@ -271,14 +269,8 @@ def _make_join_runner(per_shard, mesh, probe_names, build_names, join_type,
         out_specs = ({m: row for m in out_names}, row, P())
         return in_specs, out_specs
 
-    call = _make_runner(per_shard, mesh, make_specs)
-
-    def run(probe, build):
-        pcols, pmask = probe
-        bcols, bmask = build
-        return call(pcols, pmask, bcols, bmask)
-
-    return run
+    return _make_runner(sig, join_type, per_shard, mesh, make_specs,
+                        collective, bytes_of)
 
 
 def _probe_emit(join_type, key_names, sflags, null_key_sentinel, probe_names,
@@ -351,9 +343,10 @@ def distributed_broadcast_join(
     the build side costs ``n_devices x build_rows`` HBM, so the planner
     gates this on build-side size (MESH_BROADCAST_ROWS).
 
-    Returns ``run((pcols, pmask), (bcols, bmask))`` like
+    Returns ``run(pcols, pmask, bcols, bmask)`` like
     ``distributed_hash_join``; outputs stay probe-sharded.
     """
+    n = mesh_axis_size(mesh, axis)
     key_names = [f"__jk{i}" for i in range(n_keys)]
     sflags = list(string_key_flags) or [False] * n_keys
 
@@ -368,8 +361,13 @@ def distributed_broadcast_join(
         overflow = lax.psum(ovf_j.astype(jnp.int32), axis) > 0
         return out_cols, out_mask, overflow
 
-    return _make_join_runner(per_shard, mesh, probe_names, build_names,
-                             join_type, axis)
+    def gathered(pcols, pmask, bcols, bmask):
+        # every device receives the whole build side
+        return n * bmask.shape[0] * (_row_bytes(bcols.values()) + 1)
+
+    return _make_join_runner("mesh.join_broadcast", per_shard, mesh,
+                             probe_names, build_names, join_type, axis,
+                             "all_gather", gathered)
 
 
 def distributed_hash_join(
@@ -395,7 +393,7 @@ def distributed_hash_join(
     pass-through or stable string hashes, ops/expressions.compile_key) plus
     payload columns.  ``join_type``: inner | left | semi | anti.
 
-    Returns ``run((pcols, pmask), (bcols, bmask)) -> (out_cols, out_mask,
+    Returns ``run(pcols, pmask, bcols, bmask) -> (out_cols, out_mask,
     overflow)`` with outputs sharded over the mesh, ``out_capacity`` rows
     per device (inner/left add probe capacity for unmatched-row append).
     """
@@ -430,8 +428,15 @@ def distributed_hash_join(
             (ovf_exchange | ovf_j).astype(jnp.int32), axis) > 0
         return out_cols, out_mask, overflow
 
-    return _make_join_runner(per_shard, mesh, probe_names, build_names,
-                             join_type, axis)
+    def sent(pcols, pmask, bcols, bmask):
+        # every device sends n buckets of shuffle_capacity rows a side
+        rows = 0 if n == 1 else n * n * shuffle_capacity
+        return rows * (_row_bytes(pcols.values())
+                       + _row_bytes(bcols.values()) + 2)
+
+    return _make_join_runner("mesh.join_partitioned", per_shard, mesh,
+                             probe_names, build_names, join_type, axis,
+                             "all_to_all", sent)
 
 
 def distributed_grouped_aggregate(

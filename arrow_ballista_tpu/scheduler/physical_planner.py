@@ -251,11 +251,14 @@ class PhysicalPlanner:
         # of a file-shuffle stage pair.  Hybrid mode keeps the stage pair
         # (tasks spread over executors, file shuffle across hosts) and
         # meshes only the per-task partial — the multi-HOST composition.
-        # Adaptive: small exchanges stay on the file path (measured faster
-        # there — BENCH_r04 q3 SF1 3.6 s file vs 6.4 s mesh; the mesh's
-        # no-materialization advantage only wins at scale, SF10 q3 46 s
-        # mesh vs 51 s file), gated on the same row estimates the join
-        # broadcast decision already trusts.
+        # Adaptive: exchanges under MESH_MIN_ROWS estimated rows stay on the
+        # file path, gated on the same row estimates the join broadcast
+        # decision already trusts.  On the chip the gate has one reading on
+        # each side (PERF.md section 6, PR 28): over it, SF10 q1 (15.0M
+        # estimated rows) takes 0.33 s over four chips against 0.77 s on
+        # one; under it, with the gate forced open at SF1, warm q3 read
+        # 14.9 s over the mesh against 13.9 s over files (PR 24's smoke).
+        # Where between the two it should lie is not measured.
         if self.config.get(MESH_SHUFFLE) and (
                 self.config.get(MESH_HYBRID)  # explicit multi-host mode
                 or self._mesh_worthwhile(self._estimate_rows(node.input))):
@@ -532,11 +535,15 @@ class PhysicalPlanner:
         walk(plan)
 
     def _mesh_worthwhile(self, est_rows: int) -> bool:
-        """Adaptive per-exchange transport choice (the VERDICT r4 ask: pick
-        mesh vs file from the scheduler's size knowledge, the same family
-        of estimates ``maybe_coalesce`` exploits post-resolve).  0 disables
-        the gate (always mesh) — tests and operators forcing the mesh path
-        set ``ballista.shuffle.mesh.min_rows=0``."""
+        """Adaptive per-exchange transport choice: mesh or file from the
+        scheduler's size knowledge, the same family of estimates
+        ``maybe_coalesce`` exploits post-resolve.  The default floor (8M
+        estimated rows) is what the benchmark's mesh cell runs at: SF10
+        lineitem under a filter estimates 15.0M and passes, SF1's 1.5M does
+        not (PERF.md section 6, PR 28; where between them the floor should
+        lie is not measured).  0 disables the gate (always mesh) — tests
+        and operators forcing the mesh path set
+        ``ballista.shuffle.mesh.min_rows=0``."""
         floor = self.config.get(MESH_MIN_ROWS)
         return floor <= 0 or est_rows >= floor
 

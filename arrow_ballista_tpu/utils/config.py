@@ -28,7 +28,7 @@ COLLECT_STATISTICS = "ballista.collect_statistics"
 MESH_SHUFFLE = "ballista.shuffle.mesh"  # use ICI all-to-all when executors co-located on a mesh
 MESH_HYBRID = "ballista.shuffle.mesh.hybrid"  # mesh WITHIN a host, file shuffle ACROSS hosts
 MESH_BROADCAST_ROWS = "ballista.shuffle.mesh.broadcast_rows"  # build side <= this -> all_gather broadcast join
-MESH_MIN_ROWS = "ballista.shuffle.mesh.min_rows"  # adaptive: fuse on mesh only when exchange >= this
+MESH_MIN_ROWS = "ballista.shuffle.mesh.min_rows"  # adaptive: fuse on mesh only when the exchange's estimated rows >= this
 TASK_SLOTS = "ballista.executor.task_slots"
 BROADCAST_THRESHOLD = "ballista.join.broadcast_threshold"  # rows; build sides smaller skip the shuffle
 JOB_TIMEOUT_S = "ballista.job.timeout.seconds"  # client-side wait_for_job deadline
@@ -229,10 +229,12 @@ _ENTRIES: Dict[str, ConfigEntry] = {
                     "(CollectLeft analog)"),
         ConfigEntry(MESH_MIN_ROWS, 8_000_000, int,
                     "adaptive transport: mesh-fuse an exchange only when "
-                    "its estimated input rows reach this (small exchanges "
-                    "measured faster on the materialized file path; the "
-                    "mesh's no-materialization advantage grows with size); "
-                    "0 forces mesh for every eligible exchange"),
+                    "its estimated input rows reach this (on four v5e "
+                    "chips SF10 q1, 15.0M estimated rows, ran 0.33 s over "
+                    "the mesh against 0.77 s on one chip; below the floor "
+                    "the one chip reading is SF1 q3 forced onto the mesh, "
+                    "14.9 s against 13.9 s over files; between them not "
+                    "measured); 0 forces mesh for every eligible exchange"),
         ConfigEntry(TASK_SLOTS, 4, int, "concurrent task slots per executor"),
         ConfigEntry(BROADCAST_THRESHOLD, 4_000_000, int,
                     "broadcast join build sides with fewer estimated rows "

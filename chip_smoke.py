@@ -451,28 +451,29 @@ def child_mesh(args) -> None:
     import jax
 
     from arrow_ballista_tpu.client.context import BallistaContext
-    from arrow_ballista_tpu.parallel import distributed
+    from arrow_ballista_tpu.obs.tracing import RING
+    from arrow_ballista_tpu.ops import mesh_exec
     from arrow_ballista_tpu.utils.config import BallistaConfig
     from benchmarks.tpch import register_tables
 
-    # where the mesh programs' inputs live: read off the arguments of every
-    # program the mesh operators build, as they hand them over
+    # where the mesh programs' inputs live: read off every batch the mesh
+    # operators place over the devices, as they hand it on
     placements = {}
-    compile_once = distributed._compile_once
+    shard_rows = mesh_exec._shard_rows
 
-    def watching(cache, lock, sig, build, args_):
-        for leaf in jax.tree_util.tree_leaves(args_):
-            if hasattr(leaf, "addressable_shards") and leaf.ndim:
-                rec = placements.setdefault(
-                    (tuple(leaf.shape), str(leaf.dtype)), {})
-                for s in leaf.addressable_shards:
-                    # a mask counts its live rows, a column its slots
-                    n = int(s.data.sum()) if leaf.dtype == bool \
-                        else int(s.data.shape[0])
-                    rec[s.device.id] = max(rec.get(s.device.id, 0), n)
-        return compile_once(cache, lock, sig, build, args_)
+    def watching(cols, mask, mesh, n_dev):
+        out = shard_rows(cols, mask, mesh, n_dev)
+        for leaf in jax.tree_util.tree_leaves(out[:2]):
+            rec = placements.setdefault(
+                (tuple(leaf.shape), str(leaf.dtype)), {})
+            for s in leaf.addressable_shards:
+                # a mask counts its live rows, a column its slots
+                n = int(s.data.sum()) if leaf.dtype == bool \
+                    else int(s.data.shape[0])
+                rec[s.device.id] = max(rec.get(s.device.id, 0), n)
+        return out
 
-    distributed._compile_once = watching
+    mesh_exec._shard_rows = watching
 
     ddir = data_dir(args)
     oracles = _oracles(ddir, MESH_QUERIES)
@@ -491,13 +492,21 @@ def child_mesh(args) -> None:
                               for d, rows in rec.items() if rows})
             live = [rec for (_, dt), rec in placements.items()
                     if dt == "bool"]
+            # the programs by the names they carry into a device trace
+            programs = sorted({s.attrs["program"] for s in RING.snapshot()
+                               if s.name == "mesh_program"})
             emit({"phase": "mesh", "mesh_program_inputs": len(placements),
+                  "mesh_programs": programs,
                   "devices_holding_rows": holders,
                   "live_rows_per_device_of_largest_mask": max(
                       live, key=lambda r: sum(r.values()), default={})})
             if len(holders) < 4:
                 raise SystemExit(f"mesh inputs live on devices {holders} "
                                  "only; four must hold rows")
+            if not programs or not all(p.startswith("mesh_")
+                                       for p in programs):
+                raise SystemExit(f"mesh programs are named {programs}; "
+                                 "program_name's mesh_* names are expected")
     emit({"phase": "mesh", "ok": True, "device": info})
 
 

@@ -212,35 +212,71 @@ def test_fused_stage_q6_with_donation(one_chip, tpu_branches):
     assert "fusion" in c.as_text()
 
 
-def test_mesh_exchange_all_to_all_on_four_devices(topo, tpu_branches,
-                                                  monkeypatch):
+def test_mesh_exchange_all_to_all_on_four_devices(topo, tpu_branches):
     """The exchange across chips: the grouped aggregate's key repartition,
-    built by the program's own runner (parallel/distributed.py) over a mesh
-    of the four described devices.  Arguments are shapes with shardings;
-    the compiled text must hold an all-to-all."""
+    the program's own (parallel/distributed.py, the function its
+    ``observed_jit`` wraps) over a mesh of the four described devices.
+    Arguments are shapes with shardings; the compiled text must hold an
+    all-to-all."""
     from arrow_ballista_tpu.parallel import distributed
 
     mesh = Mesh(np.asarray(topo.devices), ("part",))
     rows = NamedSharding(mesh, P("part"))
     n = 4 * (1 << 18)
 
-    def lower_only(cache, lock, sig, build, args):
-        t0 = time.perf_counter()
-        compiled = build().lower(*args).compile()
-        print(f"\n[tpu-compile] mesh grouped aggregate: "
-              f"{time.perf_counter() - t0:.1f}s")
-        return compiled
-
-    monkeypatch.setattr(distributed, "_compile_once", lower_only)
     run = distributed.distributed_grouped_aggregate(
         mesh, ["l_orderkey"], [("l_quantity", "sum"), ("__ones", "sum")],
         partial_capacity=1 << 16, final_capacity=1 << 16, axis="part")
+    assert run.name == "mesh_agg_exchange__k1"
     cols = {"l_orderkey": sds((n,), jnp.int64, rows),
             "l_quantity": sds((n,), jnp.int64, rows),
             "__ones": sds((n,), jnp.int64, rows)}
-    compiled = run(cols, sds((n,), jnp.bool_, rows))
+    compiled = compile_for_chip("mesh grouped aggregate",
+                                run.jit.__wrapped__, cols,
+                                sds((n,), jnp.bool_, rows))
     text = compiled.as_text()
     assert "all-to-all" in text, "no all-to-all in the mesh program"
     mem = compiled.memory_analysis()
     print(f"[tpu-compile] mesh program bytes per device: "
           f"args {mem.argument_size_in_bytes}, temp {mem.temp_size_in_bytes}")
+
+
+def test_mesh_dense_reduce_q1_shape_on_four_devices(topo, tpu_branches):
+    """q1's mesh aggregate at SF10's shard (2^24 slots a device, the
+    benchmark's ``sf10_mesh4_scanagg``): two dictionary keys, int64 sums and
+    counts into 12 dense slots, merged by all-reduce and no all-to-all; what
+    it asks of a chip beside the 4.9 GB scan cache must leave room in 16 GB."""
+    from arrow_ballista_tpu.parallel import distributed
+
+    mesh = Mesh(np.asarray(topo.devices), ("part",))
+    rows = NamedSharding(mesh, P("part"))
+    n = 4 * (1 << 24)
+    key_ranges = ((-1, 2), (-1, 1))
+    aggs = [("qty", "sum"), ("price", "sum"), ("disc_price", "sum"),
+            ("charge", "sum"), ("qty", "count"), ("disc", "sum"),
+            ("ones", "count")]
+
+    def derive(cols, mask):
+        out = dict(cols)
+        out["disc_price"] = cols["price"] * (100 - cols["disc"])
+        out["charge"] = out["disc_price"] * (100 + cols["tax"])
+        out["ones"] = jnp.ones(mask.shape, jnp.int64)
+        return out, mask
+
+    run = distributed.distributed_dense_aggregate(
+        mesh, derive, ["flag", "status"], aggs, key_ranges,
+        K.dense_domain(key_ranges))
+    assert run.name == "mesh_agg_dense__k2"
+    cols = {name: sds((n,), jnp.int64, rows)
+            for name in ("qty", "price", "disc", "tax")}
+    cols.update({name: sds((n,), jnp.int32, rows)
+                 for name in ("flag", "status")})
+    compiled = compile_for_chip("mesh dense aggregate, q1 at SF10",
+                                run.jit.__wrapped__, cols,
+                                sds((n,), jnp.bool_, rows))
+    text = compiled.as_text()
+    assert "all-reduce" in text and "all-to-all" not in text
+    mem = compiled.memory_analysis()
+    print(f"[tpu-compile] q1 mesh program bytes per device: "
+          f"args {mem.argument_size_in_bytes}, temp {mem.temp_size_in_bytes}")
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
