@@ -11,9 +11,12 @@ compile one fused program per stage.
 Key techniques:
 - grouping is sort-based (lexsort -> boundary flags -> segment reductions),
   exact for any key combination, no hash tables in HBM required;
-- joins sort the build side by a 64-bit mixed key, probe via searchsorted,
-  expand variable fan-out through a cumulative-offset inversion, then verify
-  *real* key equality so hash collisions never corrupt results;
+- joins sort the build side by a 64-bit mixed key; a probe batch finds every
+  row's run of equal hashes in it once (``probe_ranges``: one sort of
+  probe and build together, no search), and
+  everything after it (``expand_pairs``: variable fan-out through a
+  cumulative-offset inversion) takes that range as an operand; *real* key
+  equality is then verified so hash collisions never corrupt results;
 - calendar decomposition (EXTRACT) uses the civil-from-days algorithm in
   pure integer arithmetic.
 """
@@ -783,28 +786,91 @@ def _min_ident(dtype):
 
 
 # --------------------------------------------------------------------------
-# join (sorted build + searchsorted probe + offset-inversion expansion)
+# join (sorted build + one range lookup a probe batch + offset-inversion
+# expansion)
 # --------------------------------------------------------------------------
+
+#: hash given to dead build rows: they sort behind every live row
+DEAD_HASH = 0xFFFFFFFFFFFFFFFF
 
 
 def build_side_sort(build_keys: List[jnp.ndarray], build_mask: jnp.ndarray):
-    """Sort the build side by mixed 64-bit key; dead rows get I64_MAX-as-uint.
+    """Sort the build side by mixed 64-bit key; dead rows get DEAD_HASH.
 
-    Returns (hash_sorted: uint64, order: int32 permutation, n_build).
+    Returns (hash_sorted: uint64, order: permutation, n_build).
     """
     h = hash64(build_keys)
-    h = jnp.where(build_mask, h, jnp.uint64(0xFFFFFFFFFFFFFFFF))
+    h = jnp.where(build_mask, h, jnp.uint64(DEAD_HASH))
     order = jnp.argsort(h)
     return h[order], order, jnp.sum(build_mask)
 
 
-def probe_join(
+def probe_ranges(
     probe_hash: jnp.ndarray,
     probe_mask: jnp.ndarray,
     build_hash_sorted: jnp.ndarray,
-    out_capacity: int,
 ):
-    """Match probe rows against the sorted build hashes.
+    """The join's one range lookup: the run ``[lo, lo + counts)`` of equal
+    hashes in the sorted build, per probe row.
+
+    Returns (lo: int32, counts: int32 masked by ``probe_mask``, total): the
+    first index with a hash >= the row's, and how many are equal to it.  A
+    hash the build lacks has an empty run; a probe hash equal to DEAD_HASH
+    meets the dead rows, whose liveness the caller checks pair by pair.
+
+    Read off one sort of probe and build hashes together, with no search
+    and no gather, on every backend: the TPU sorts far faster than it
+    gathers.  A binary search is log2(n) dependent gathers of an emulated
+    64-bit word per probe row (two of them read 841 ns a row against 2^18
+    build slots), this 15 ns a row (PERF.md section 6, PR 29).
+
+    Equal hashes end up in one run, in no particular order inside it (the
+    unstable sort compares two words, not three, and compiles in a third of
+    the time).  With the running count of build rows along the sorted
+    order, a run's probe rows have ``lo`` = the build rows before the run
+    and ``counts`` = the build rows inside it; both are carried along the
+    run from its first and last place and scattered back to row order.
+    """
+    n_p = probe_hash.shape[0]
+    merged = jnp.concatenate([probe_hash, build_hash_sorted])
+    hashes, src = jax.lax.sort(
+        (merged, jnp.arange(merged.shape[0], dtype=jnp.int32)),
+        num_keys=1, is_stable=False)
+    is_build = src >= n_p
+    upto = _shift_scan(is_build.astype(jnp.int32), jnp.add, 0)
+    first = jnp.concatenate([jnp.ones(1, bool), hashes[1:] != hashes[:-1]])
+    last = jnp.concatenate([first[1:], jnp.ones(1, bool)])
+    before = _shift_scan(jnp.where(first, upto - is_build, -1),
+                         jnp.maximum, -1)
+    through = _shift_scan(jnp.where(last, upto, _I32_MAX),
+                          jnp.minimum, _I32_MAX, reverse=True)
+    dest = jnp.where(is_build, n_p, src)  # build rows fall off the end
+    lo = jnp.zeros(n_p, jnp.int32).at[dest].set(before, mode="drop")
+    counts = jnp.zeros(n_p, jnp.int32).at[dest].set(through - before,
+                                                     mode="drop")
+    counts = jnp.where(probe_mask, counts, 0)
+    return lo, counts, jnp.sum(counts)
+
+
+def _shift_scan(x: jnp.ndarray, op, identity, reverse: bool = False):
+    """Inclusive scan of ``op`` along ``x`` by doubling shifts: log2(n)
+    elementwise passes.  Over a whole batch ``jnp.cumsum`` compiles for
+    5-25 s a shape for the TPU and ``lax.cummin`` for a minute; this
+    compiles in a second and the passes cost microseconds each (PERF.md
+    section 6, PR 29)."""
+    n = x.shape[0]
+    shift = 1
+    while shift < n:
+        pad = jnp.full(shift, identity, x.dtype)
+        x = op(x, jnp.concatenate([x[shift:], pad]) if reverse
+               else jnp.concatenate([pad, x[:-shift]]))
+        shift *= 2
+    return x
+
+
+def expand_pairs(lo: jnp.ndarray, counts: jnp.ndarray, n_build: int,
+                 out_capacity: int):
+    """Expand the ranges of ``probe_ranges`` into candidate pairs.
 
     Returns (probe_idx, build_pos, pair_valid, total_pairs):
     - ``probe_idx[j]``: which probe row pair j belongs to,
@@ -813,21 +879,18 @@ def probe_join(
     - ``total_pairs``: dynamic count (<= out_capacity or overflow).
     Callers MUST verify real key equality afterwards (hash collisions).
     """
-    lo = jnp.searchsorted(build_hash_sorted, probe_hash, side="left")
-    hi = jnp.searchsorted(build_hash_sorted, probe_hash, side="right")
-    counts = jnp.where(probe_mask, hi - lo, 0)
-    offsets = jnp.cumsum(counts)  # inclusive
+    offsets = _shift_scan(counts, jnp.add, 0)  # inclusive
     total = offsets[-1]
     starts = offsets - counts
 
     j = jnp.arange(out_capacity)
     # probe row for output slot j: first i with offsets[i] > j
     probe_idx = jnp.searchsorted(offsets, j, side="right")
-    probe_idx = jnp.clip(probe_idx, 0, probe_hash.shape[0] - 1)
+    probe_idx = jnp.clip(probe_idx, 0, lo.shape[0] - 1)
     k = j - starts[probe_idx]
     build_pos = lo[probe_idx] + k
     pair_valid = (j < total) & (k >= 0) & (k < counts[probe_idx])
-    build_pos = jnp.clip(build_pos, 0, build_hash_sorted.shape[0] - 1)
+    build_pos = jnp.clip(build_pos, 0, n_build - 1)
     return probe_idx, build_pos, pair_valid, total
 
 

@@ -531,7 +531,7 @@ class MeshJoinExec(ExecutionPlan):
 
     Replaces JoinExec(partitioned) <- Repartition(hash) x2 when the mesh
     path is enabled: both sides all_to_all by key bucket, then a per-device
-    sorted-build/searchsorted-probe join — ONE XLA program where the
+    sorted-build/range-lookup join — ONE XLA program where the
     reference materializes two shuffles and a reduce stage (exchange rules
     planner.rs:133-152; SURVEY.md §2.5 TP row).  Results are identical to
     the file-shuffle JoinExec path — verified by tests/test_mesh_exec.py.

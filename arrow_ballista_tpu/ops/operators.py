@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import expr as E
-from ..models.batch import ColumnBatch, concat_batches, remote_device
+from ..models.batch import ColumnBatch, concat_batches
 from ..models.schema import BOOL, DataType, Field, INT64, Schema
 from ..utils.config import AGG_CAPACITY, JOIN_MAX_CAPACITY
 from ..utils.errors import CapacityError, ExecutionError, InternalError
@@ -1060,11 +1060,22 @@ class HashAggregateExec(ExecutionPlan):
 
 
 @observed_jit("join.window_mask")
-def _window_mask(mask, lo, hi):
-    """Probe-window liveness: live AND row index in [lo, hi).  One compiled
-    program serves every window of every chunked join at this capacity."""
+def _window_mask(mask, counts, lo, hi):
+    """One probe window of a chunked join: liveness and candidate counts of
+    the rows with index in [lo, hi), zero outside.  One compiled program
+    serves every window of every chunked join at this capacity."""
     idx = jnp.arange(mask.shape[0], dtype=jnp.int32)
-    return mask & (idx >= lo) & (idx < hi)
+    inside = (idx >= lo) & (idx < hi)
+    return mask & inside, jnp.where(inside, counts, 0)
+
+
+@observed_jit("join.window_counts", static_argnums=(1, 2))
+def _window_counts(counts, chunk_rows, n_windows):
+    """Candidate pairs per probe window, from the join's one range lookup:
+    ONE program + ONE host transfer for every window (a per-window scalar
+    sync would cost its fixed latency each)."""
+    wid = jnp.arange(counts.shape[0], dtype=jnp.int32) // jnp.int32(chunk_rows)
+    return jax.ops.segment_sum(counts, wid, num_segments=n_windows)
 
 
 _mask_or = observed_jit("join.mask_or", lambda a, b: a | b)
@@ -1074,8 +1085,18 @@ _mask_and = observed_jit("join.mask_and", lambda a, b: a & b)
 
 
 class JoinExec(ExecutionPlan):
-    """Equi-join: sorted-build + searchsorted probe + static-capacity pair
-    expansion (ops/kernels.py).  Probe = left child, build = right child.
+    """Equi-join: sorted build, one range lookup per probe batch, then a
+    static-capacity pair expansion (ops/kernels.py).  Probe = left child,
+    build = right child.
+
+    Three programs: ``join.prep`` hashes and sorts the build; ``join.count``
+    is the range lookup — the one pass over the build's sorted hashes a
+    probe batch makes — and returns the candidate total with every probe
+    row's ``lo`` and ``counts``; ``join.probe`` takes those two arrays as
+    operands (it never sees the sorted hashes), expands the pairs and emits
+    by join type.  The chunked path's windows and their sizes come from the
+    same ``counts``.  Metrics ``range_lookups`` and ``probe_rows_searched``
+    count the lookups.
 
     ``dist``: 'partitioned' (both children hash-partitioned on keys — the
     planner inserts shuffles) or 'broadcast' (build side read fully by every
@@ -1244,12 +1265,15 @@ class JoinExec(ExecutionPlan):
             bh_sorted, border, _ = K.build_side_sort(bk, bmask)
             return bh_sorted, border
 
-        def join_fn(pcols, pmask, bcols, bmask, bh_sorted, border,
+        def join_fn(pcols, pmask, bcols, bmask, border, lo, counts,
                     laux, raux, faux, out_cap):
+            # lo/counts: the range lookup count_fn made for this probe
+            # batch (a chunked join's window passes its rows' counts, zero
+            # elsewhere) — no search of the build's hashes happens here
             pk = [c.fn(pcols, laux) for c in lkeys]
             bk = [c.fn(bcols, raux) for c in rkeys]
-            ph = K.hash64(pk)
-            pi, bp, pair_valid, total = K.probe_join(ph, pmask, bh_sorted, out_cap)
+            pi, bp, pair_valid, _ = K.expand_pairs(
+                lo, counts, bmask.shape[0], out_cap)
             bidx = border[bp]
             # verify real key equality (hash collisions) + build liveness;
             # string keys are value-hashes: exclude the NULL sentinel so
@@ -1273,7 +1297,7 @@ class JoinExec(ExecutionPlan):
             if jt in ("semi", "anti"):
                 hit = K.segment_any(ok, pi, pmask.shape[0])
                 new_mask = pmask & (hit if jt == "semi" else ~hit)
-                return pcols, new_mask, total
+                return pcols, new_mask
 
             out_cols = {n: pcols[n][pi] for n in lnames}
             out_cols.update({n: bcols[n][bidx] for n in rnames})
@@ -1318,48 +1342,39 @@ class JoinExec(ExecutionPlan):
                 # partition; a stable host sort on pi reconstructs the
                 # exact single-build emission order).  Device-resident
                 # unless the spill path fetches it.
-                return out_cols, out_mask, total, pi.astype(jnp.int32)
-            return out_cols, out_mask, total
+                return out_cols, out_mask, pi.astype(jnp.int32)
+            return out_cols, out_mask
 
         def count_fn(pcols, pmask, bh_sorted, laux):
-            # candidate-pair count only: the same hi-lo arithmetic the
-            # join performs, none of the gathers — sizes the output
-            # buffers to reality instead of out_factor x probe capacity
-            # (a 1M-row probe batch with 30k matches would otherwise
-            # gather every output column into 2M-row buffers)
+            # the join's range lookup, once per probe batch: each probe
+            # row's run [lo, lo + counts) of equal hashes in the sorted
+            # build.  The total sizes the output buffers to reality instead
+            # of out_factor x probe capacity (a 1M-row probe batch with 30k
+            # matches would otherwise gather every output column into
+            # 2M-row buffers); lo and counts stay on the device as
+            # join_fn's operands
             pk = [c.fn(pcols, laux) for c in lkeys]
-            ph = K.hash64(pk)
-            lo = jnp.searchsorted(bh_sorted, ph, side="left")
-            hi = jnp.searchsorted(bh_sorted, ph, side="right")
-            return jnp.sum(jnp.where(pmask, hi - lo, 0))
-
-        def wcount_fn(pcols, pmask, bh_sorted, laux, chunk_rows, n_windows):
-            # per-window candidate counts for the budget-chunked probe
-            # loop: ONE program + ONE host transfer for every window
-            # (a per-window scalar sync would cost its fixed latency each
-            # where remote_device() holds)
-            pk = [c.fn(pcols, laux) for c in lkeys]
-            ph = K.hash64(pk)
-            lo = jnp.searchsorted(bh_sorted, ph, side="left")
-            hi = jnp.searchsorted(bh_sorted, ph, side="right")
-            per_row = jnp.where(pmask, hi - lo, 0)
-            wid = (jnp.arange(pmask.shape[0], dtype=jnp.int32)
-                   // jnp.int32(chunk_rows))
-            return jax.ops.segment_sum(per_row, wid,
-                                       num_segments=n_windows)
+            lo, counts, total = K.probe_ranges(K.hash64(pk), pmask,
+                                               bh_sorted)
+            return total, lo, counts
 
         return (lcomp, rcomp, fcomp,
-                observed_jit("join.probe", join_fn, static_argnums=(9,)),
+                observed_jit("join.probe", join_fn, static_argnums=(10,)),
                 observed_jit("join.count", count_fn),
-                observed_jit("join.prep", prep_fn),
-                observed_jit("join.wcount", wcount_fn,
-                             static_argnums=(4, 5)))
+                observed_jit("join.prep", prep_fn))
+
+    def _lookup(self, cfn, probe, bh_sorted, laux):
+        """Dispatch the range lookup of one probe batch against one sorted
+        build: (candidate total, lo, counts), all on the device."""
+        self.metrics().add("range_lookups", 1)
+        self.metrics().add("probe_rows_searched", probe.capacity)
+        return cfn(probe.columns, probe.mask, bh_sorted, laux)
 
     def _out_row_bytes(self) -> int:
         return self._schema.row_byte_width()
 
     def _join_device(self, ctx, probe, build, lsch, rsch):
-        lcomp, rcomp, fcomp, jfn, cfn, pfn, _ = self._compiled
+        lcomp, rcomp, fcomp, jfn, cfn, pfn = self._compiled
 
         laux = lcomp.aux_arrays(probe.dicts)
         raux = rcomp.aux_arrays(build.dicts)
@@ -1375,8 +1390,7 @@ class JoinExec(ExecutionPlan):
                 if pc is not None and pc[0] == ctx.job_id and pc[1] is build:
                     prep = pc[2]
             if prep is None:
-                bh_sorted, border = pfn(build.columns, build.mask, raux)
-                prep = (bh_sorted, border)
+                prep = pfn(build.columns, build.mask, raux)
                 if self.dist == "broadcast":
                     # install under xla_lock and only while the build cache
                     # for this job is still alive: a concurrent
@@ -1388,49 +1402,39 @@ class JoinExec(ExecutionPlan):
                         if bc is not None and bc[0] == ctx.job_id:
                             self._prep_cache = (ctx.job_id, build, prep)
             bh_sorted, border = prep
-            # count pass -> exact candidate total -> power-of-two capacity
-            # bucket (static shapes stay static per bucket — the
+            # range lookup -> exact candidate total -> power-of-two
+            # capacity bucket (static shapes stay static per bucket — the
             # XLA-friendly answer to data-dependent join fan-out,
             # SURVEY.md §7 hard parts).  Floored at probe.capacity/4 so
             # same-shaped batches with modest counts share ONE compiled
             # bucket instead of compiling per data-dependent power of two
             # (compiles cost minutes on TPU); clamped to the ceiling so
             # pow2 rounding can never allocate above the configured cap.
+            # The lookup is the join's own first step, not a sizing pass
+            # beside it: the expansion below starts from its lo/counts.
             ceiling = ctx.config.get(JOIN_MAX_CAPACITY)
-            # capacity-bucket hint: same-shape sibling tasks skip the count
-            # pass (a full extra hash+searchsorted sweep) once one task
-            # discovered the bucket — CPU only, where the post-join
-            # int(total) check verifies exactness and retries; the remote
-            # path keeps the count pass as its only safety (the scalar
-            # sync there was judged to cost more than the count saves)
-            hint_state = getattr(self, "_out_cap_hint", None)
-            hint = None
-            if hint_state is not None and hint_state[0] == ctx.job_id:
-                hint = hint_state[1].get(probe.capacity)
-            if hint is not None and not remote_device():
-                out_cap = hint
+            total_dev, lo, counts = self._lookup(cfn, probe, bh_sorted,
+                                                 laux)
+            total_est = _scalar(total_dev)
+            if total_est > ceiling:
+                raise CapacityError(
+                    f"join produced {total_est} candidate pairs, above "
+                    f"the {ceiling}-row ceiling; likely an accidental "
+                    f"near-cross join — check join keys, or raise "
+                    f"{JOIN_MAX_CAPACITY}")
+            # two capacity buckets per probe shape: selective joins
+            # (the common case after semi/HAVING reductions) share the
+            # LOW bucket instead of gathering cap//4-row buffers for a
+            # handful of matches; everything else shares cap//4
+            low_floor = max(64, probe.capacity // 64)
+            if total_est <= low_floor:
+                out_cap = low_floor
             else:
-                total_est = _scalar(cfn(probe.columns, probe.mask,
-                                        bh_sorted, laux))
-                if total_est > ceiling:
-                    raise CapacityError(
-                        f"join produced {total_est} candidate pairs, above "
-                        f"the {ceiling}-row ceiling; likely an accidental "
-                        f"near-cross join — check join keys, or raise "
-                        f"{JOIN_MAX_CAPACITY}")
-                # two capacity buckets per probe shape: selective joins
-                # (the common case after semi/HAVING reductions) share the
-                # LOW bucket instead of gathering cap//4-row buffers for a
-                # handful of matches; everything else shares cap//4
-                low_floor = max(64, probe.capacity // 64)
-                if total_est <= low_floor:
-                    out_cap = low_floor
-                else:
-                    out_cap = max(1 << max(0, total_est - 1).bit_length(),
-                                  probe.capacity // 4)
-                if out_cap > ceiling:
-                    # ballista: allow=trace-key-stability — above-ceiling exact-size fallback: compiles once at the true match count instead of a doubled pow2 bucket that would blow the capacity ceiling; rare by construction (needs a near-cross join past JOIN_MAX_CAPACITY)
-                    out_cap = max(total_est, 64)
+                out_cap = max(1 << max(0, total_est - 1).bit_length(),
+                              probe.capacity // 4)
+            if out_cap > ceiling:
+                # ballista: allow=trace-key-stability — above-ceiling exact-size fallback: compiles once at the true match count instead of a doubled pow2 bucket that would blow the capacity ceiling; rare by construction (needs a near-cross join past JOIN_MAX_CAPACITY)
+                out_cap = max(total_est, 64)
             # memory control (VERDICT r4 #6): when the expansion working set
             # would exceed the per-task budget, run the probe loop in
             # bounded windows against the (already prepped) build instead of
@@ -1449,54 +1453,18 @@ class JoinExec(ExecutionPlan):
                     and probe.capacity >= 2048
                     and out_cap * self._out_row_bytes() > budget):
                 return self._join_chunked(
-                    ctx, probe, build, bh_sorted, border,
+                    ctx, probe, build, border, lo, counts,
                     laux, raux, faux, budget, ceiling, out_cap)
-            # inner joins return a 4th element (pi, for the spilled
-            # path's merge) — every in-memory caller slices it off
-            out_cols, out_mask, total = jfn(
+            # inner joins return a 3rd element (pi, for the spilled
+            # path's merge) — every in-memory caller slices it off.
+            # out_cap >= total_est, and the expansion's own total is the
+            # sum of the very counts array total_est summed, so the pairs
+            # always fit: there is no overflow to check after the join and
+            # no retry at a larger capacity.
+            out_cols, out_mask = jfn(
                 probe.columns, probe.mask, build.columns, build.mask,
-                bh_sorted, border, laux, raux, faux, out_cap
-            )[:3]
-            # out_cap >= total_est by construction, and the join's own count
-            # uses the same hi-lo arithmetic as the count pass, so this
-            # retry can only fire if something drifts between the two
-            # compiled programs.  On remote-attached devices the eager
-            # int(total) check would cost a scalar sync per task for
-            # a never-taken branch — skipped there (count and join run the
-            # same arithmetic on the same inputs; a disagreement would be an
-            # XLA miscompile, which no host-side retry rescues anyway).
-            if not remote_device() and _scalar(total) > out_cap:
-                need = 1 << (int(total) - 1).bit_length()
-                if need > ceiling:
-                    raise CapacityError(
-                        f"join produced {int(total)} candidate pairs, above "
-                        f"the {ceiling}-row ceiling; raise {JOIN_MAX_CAPACITY}")
-                if (budget and self.join_type in ("inner", "semi", "anti")
-                        and probe.capacity >= 2048
-                        and need * self._out_row_bytes() > budget):
-                    # a hinted (or drifted) undersize whose true expansion
-                    # busts the budget re-routes through the windowed path
-                    # — the retry must not allocate above the budget the
-                    # windowing exists to enforce
-                    return self._join_chunked(
-                        ctx, probe, build, bh_sorted, border,
-                        laux, raux, faux, budget, ceiling, need)
-                self.metrics().add("capacity_recompiles", 1)
-                out_cols, out_mask, total = jfn(
-                    probe.columns, probe.mask, build.columns, build.mask,
-                    bh_sorted, border, laux, raux, faux, need
-                )[:3]
-                out_cap = need
-            if not remote_device() and out_cap == max(64, probe.capacity // 64):
-                # latch ONLY the selective low bucket: that is where the
-                # count-skip pays (tiny outputs, full extra sweep saved)
-                # and where a wrong hint costs one cheap retry; latching
-                # larger buckets would inflate every later sibling's
-                # gathers.  Job-scoped: hints never leak across jobs.
-                hint_state = getattr(self, "_out_cap_hint", None)
-                if hint_state is None or hint_state[0] != ctx.job_id:
-                    self._out_cap_hint = hint_state = (ctx.job_id, {})
-                hint_state[1][probe.capacity] = out_cap
+                border, lo, counts, laux, raux, faux, out_cap
+            )[:2]
 
         dicts = dict(probe.dicts)
         if self.join_type in ("inner", "left", "full"):
@@ -1549,7 +1517,7 @@ class JoinExec(ExecutionPlan):
                             & (self._SPILL_PARTS - 1)).astype(jnp.int32)
 
                 self._spill_pfn = observed_jit("join.spill_part", part_fn)
-        lcomp, rcomp, fcomp, jfn, cfn, pfn, _ = self._compiled
+        lcomp, rcomp, fcomp, jfn, cfn, pfn = self._compiled
         nparts = self._SPILL_PARTS
         spiller = Spiller(ctx.work_dir, ctx.job_id, tag="join")
         runs: List[list] = [[] for _ in range(nparts)]
@@ -1594,10 +1562,12 @@ class JoinExec(ExecutionPlan):
                             if fcomp is not None else {})
                     bh_sorted, border = pfn(build_p.columns, build_p.mask,
                                             raux)
-                    # exact per-partition candidate count sizes the
-                    # output; the cross-join guard sees the partition SUM
-                    total_est = int(cfn(probe.columns, probe.mask,
-                                        bh_sorted, laux))
+                    # one range lookup per rehydrated partition: its exact
+                    # candidate count sizes the output; the cross-join
+                    # guard sees the partition SUM
+                    total_dev, lo, counts = self._lookup(
+                        cfn, probe, bh_sorted, laux)
+                    total_est = _scalar(total_dev)
                     grand_total += total_est
                     if grand_total > ceiling:
                         raise CapacityError(
@@ -1609,7 +1579,7 @@ class JoinExec(ExecutionPlan):
                     out_cap = max(low_floor,
                                   1 << max(0, total_est - 1).bit_length())
                     res = jfn(probe.columns, probe.mask, build_p.columns,
-                              build_p.mask, bh_sorted, border, laux, raux,
+                              build_p.mask, border, lo, counts, laux, raux,
                               faux, out_cap)
                     if self.join_type in ("semi", "anti"):
                         new_mask = res[1]
@@ -1620,7 +1590,7 @@ class JoinExec(ExecutionPlan):
                         else:
                             mask_acc = _mask_and(mask_acc, new_mask)
                         continue
-                    out_cols, out_mask, _total, pi = res
+                    out_cols, out_mask, pi = res
                     pb = ColumnBatch(self._schema, dict(out_cols),
                                      out_mask,
                                      {**probe.dicts, **build_p.dicts})
@@ -1682,13 +1652,15 @@ class JoinExec(ExecutionPlan):
         self.metrics().add("output_rows", int(pi.size))
         return out
 
-    def _join_chunked(self, ctx, probe, build, bh_sorted, border,
+    def _join_chunked(self, ctx, probe, build, border, lo, counts,
                       laux, raux, faux, budget: int, ceiling: int,
                       planned_cap: int):
         """Bounded-footprint probe loop: the probe is windowed by row-range
         masks (static shapes preserved — no reslicing, so ONE compiled
         program serves every window) and each window's expansion buffer is
-        sized by its own count pass.  Exact for inner/semi/anti: a probe
+        sized by its own share of ``counts``, the one range lookup the
+        caller made for the whole probe: no window searches the build
+        again.  Exact for inner/semi/anti: a probe
         row's matches depend only on that row and the build side.
         Semi/anti windows OR their verdict masks into one output batch;
         inner windows each emit a bounded batch.
@@ -1697,7 +1669,7 @@ class JoinExec(ExecutionPlan):
         most of the matches still allocates its real match count — the
         overrun is bounded by that window's genuine output size (which must
         be materialized regardless), not by fan-out across the whole probe."""
-        lcomp, rcomp, fcomp, jfn, cfn, pfn, wcfn = self._compiled
+        jfn = self._compiled[3]
         cap = probe.capacity
         width = self._out_row_bytes()
         want = max(1, -(-planned_cap * width // budget))
@@ -1720,16 +1692,16 @@ class JoinExec(ExecutionPlan):
             dicts.update(build.dicts)
         # all window counts in ONE program + ONE host transfer (per-window
         # scalar syncs would cost their fixed latency each)
-        counts_dev = wcfn(probe.columns, probe.mask, bh_sorted, laux,
-                          chunk_rows, chunks)
+        counts_dev = _window_counts(counts, chunk_rows, chunks)
         with device_wait("scalar"):
             # ballista: allow=hot-path-purity — deliberate single batched transfer
             window_counts = np.asarray(counts_dev)
         grand_total = 0  # the cross-join guard must see the SUM of windows
         for i in range(chunks):
             ctx.check_cancelled()
-            pmask_c = _window_mask(probe.mask, i * chunk_rows,
-                                   min((i + 1) * chunk_rows, cap))
+            pmask_c, counts_c = _window_mask(
+                probe.mask, counts, i * chunk_rows,
+                min((i + 1) * chunk_rows, cap))
             total_c = int(window_counts[i])
             grand_total += total_c
             if grand_total > ceiling:
@@ -1743,20 +1715,10 @@ class JoinExec(ExecutionPlan):
             if out_cap > ceiling:
                 # ballista: allow=trace-key-stability — above-ceiling exact-size fallback, same trade as the unchunked probe: one exact-size compile beats blowing the window capacity ceiling; rare by construction
                 out_cap = max(total_c, 64)
-            out_cols, out_mask, total = jfn(
+            # out_cap >= total_c = sum(counts_c): the window's pairs fit
+            out_cols, out_mask = jfn(
                 probe.columns, pmask_c, build.columns, build.mask,
-                bh_sorted, border, laux, raux, faux, out_cap)[:3]
-            if not remote_device() and _scalar(total) > out_cap:
-                need = 1 << (int(total) - 1).bit_length()
-                if need > ceiling:
-                    raise CapacityError(
-                        f"join window produced {int(total)} candidate pairs, "
-                        f"above the {ceiling}-row ceiling; raise "
-                        f"{JOIN_MAX_CAPACITY}")
-                self.metrics().add("capacity_recompiles", 1)
-                out_cols, out_mask, total = jfn(
-                    probe.columns, pmask_c, build.columns, build.mask,
-                    bh_sorted, border, laux, raux, faux, need)[:3]
+                border, lo, counts_c, laux, raux, faux, out_cap)[:2]
             if self.join_type in ("semi", "anti"):
                 mask_acc = out_mask if mask_acc is None \
                     else _mask_or(mask_acc, out_mask)

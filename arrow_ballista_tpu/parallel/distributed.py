@@ -277,16 +277,17 @@ def _probe_emit(join_type, key_names, sflags, null_key_sentinel, probe_names,
                 build_names, build_fill, out_capacity,
                 p_cols, p_mask, b_cols, b_mask):
     """Local half of a hash join, shared by the partitioned and broadcast
-    variants: sorted-build + searchsorted-probe + collision re-verification,
-    then emit by join type.  Both sides are already device-local (either
-    shuffled to the bucket owner, or the build side all_gathered)."""
+    variants: sorted build, one range lookup, pair expansion and collision
+    re-verification, then emit by join type.  Both sides are already
+    device-local (either shuffled to the bucket owner, or the build side
+    all_gathered)."""
     rpk = [p_cols[k] for k in key_names]
     rbk = [b_cols[k] for k in key_names]
 
     bh_sorted, border, _ = K.build_side_sort(rbk, b_mask)
-    ph = K.hash64(rpk)
-    pi, bp, pair_valid, total = K.probe_join(ph, p_mask, bh_sorted,
-                                             out_capacity)
+    lo, counts, _ = K.probe_ranges(K.hash64(rpk), p_mask, bh_sorted)
+    pi, bp, pair_valid, total = K.expand_pairs(lo, counts, b_mask.shape[0],
+                                               out_capacity)
     bidx = border[bp]
     ok = pair_valid & b_mask[bidx]
     for i, (a, b) in enumerate(zip(rpk, rbk)):
@@ -384,7 +385,7 @@ def distributed_hash_join(
     axis: str = PART_AXIS,
 ):
     """Fused partitioned hash join over the ICI mesh: key-bucket all_to_all
-    of BOTH sides, then per-device sorted-build/searchsorted-probe join —
+    of BOTH sides, then per-device sorted-build/range-lookup join —
     one XLA program replacing the reference's two shuffle stage pairs +
     reduce tasks (reference planner.rs:133-152 inserts hash RepartitionExec
     under each join side; exchange inventory SURVEY.md §2.5).

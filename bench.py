@@ -639,7 +639,7 @@ def _worker(deadline: float) -> None:
     else:
         result["engine_mesh"] = {"skipped": "deadline"}
 
-    # --- kernel: join shape (sorted-build + searchsorted probe) ---------
+    # --- kernel: join shape (sorted build, range lookup, expansion) -----
     # evidences the device join path: the build argsort is the one program
     # family measured to compile slowly on this backend, so compile time is
     # reported separately from steady-state
@@ -656,8 +656,8 @@ def _worker(deadline: float) -> None:
         @jax.jit
         def join_step(pk, bk, pmask, bmask):
             bh_sorted, border, _ = K.build_side_sort([bk], bmask)
-            ph = K.hash64([pk])
-            pi, bp, pair_valid, total = K.probe_join(ph, pmask, bh_sorted, out_cap)
+            lo, counts, _ = K.probe_ranges(K.hash64([pk]), pmask, bh_sorted)
+            pi, bp, pair_valid, total = K.expand_pairs(lo, counts, bmask.shape[0], out_cap)
             bidx = border[bp]
             ok = pair_valid & bmask[bidx] & (pk[pi] == bk[bidx])
             return jnp.sum(ok), total

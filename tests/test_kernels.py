@@ -61,6 +61,99 @@ def test_grouped_aggregate_overflow_flag():
     assert bool(overflow)
 
 
+def _two_sided(bh_sorted, ph, pmask, out_cap):
+    """The join's lookup and expansion as they stood before probe_ranges
+    and expand_pairs: a two-sided search of the sorted hashes."""
+    lo = jnp.searchsorted(bh_sorted, ph, side="left")
+    hi = jnp.searchsorted(bh_sorted, ph, side="right")
+    counts = jnp.where(pmask, hi - lo, 0)
+    offsets = jnp.cumsum(counts)
+    total = offsets[-1]
+    starts = offsets - counts
+    j = jnp.arange(out_cap)
+    probe_idx = jnp.clip(jnp.searchsorted(offsets, j, side="right"),
+                         0, ph.shape[0] - 1)
+    k = j - starts[probe_idx]
+    pair_valid = (j < total) & (k >= 0) & (k < counts[probe_idx])
+    build_pos = jnp.clip(lo[probe_idx] + k, 0, bh_sorted.shape[0] - 1)
+    return lo, counts, (probe_idx, build_pos, pair_valid, total)
+
+
+def _range_case(name, rng):
+    """(build hashes, build liveness, probe hashes, probe mask)."""
+    dead = np.uint64(K.DEAD_HASH)
+    u64 = lambda n: rng.integers(0, 2**63, n, dtype=np.int64).astype(np.uint64)
+    if name == "runs_of_1_to_1000":
+        distinct = u64(40)
+        bh = np.repeat(distinct, rng.integers(1, 1001, 40))
+        rng.shuffle(bh)
+        live = rng.random(bh.size) < 0.9
+        ph = np.concatenate([rng.choice(distinct, 300), u64(300)])
+    elif name == "every_hash_equal":  # force_hash_collisions
+        bh, live = np.zeros(512, np.uint64), np.ones(512, bool)
+        ph = np.zeros(256, np.uint64)
+    elif name == "below_above_and_dead_sentinel":
+        bh = np.sort(u64(200)) + np.uint64(5)
+        live = np.ones(200, bool); live[::7] = False  # dead build rows
+        ph = np.array([0, bh.min() - np.uint64(1), bh.max() + np.uint64(1),
+                       dead - np.uint64(1), dead, bh[3], bh[199]], np.uint64)
+    elif name == "capacity_one":
+        bh, live = np.array([77], np.uint64), np.ones(1, bool)
+        ph = np.array([76, 77, 78, dead], np.uint64)
+    elif name == "all_dead_build":
+        bh, live = u64(64), np.zeros(64, bool)
+        ph = np.concatenate([bh[:8], np.array([0, dead], np.uint64)])
+    else:
+        raise AssertionError(name)
+    pmask = rng.random(ph.size) < 0.8  # masked probe rows in every case
+    pmask[-1] = True
+    return bh, live, ph, pmask
+
+
+@pytest.mark.parametrize("ties", ["as_sorted", "reversed", "shuffled"])
+@pytest.mark.parametrize("case", [
+    "runs_of_1_to_1000", "every_hash_equal", "below_above_and_dead_sentinel",
+    "capacity_one", "all_dead_build"])
+def test_probe_ranges_equals_two_sided_search(case, ties, rng, monkeypatch):
+    bh, live, ph, pmask = _range_case(case, rng)
+    # the case's hashes are the keys themselves; the lookup's one sort of
+    # both sides may leave equal hashes in any order: here in the CPU
+    # sort's, with every tie reversed (build rows first), and with the ties
+    # in a random order
+    monkeypatch.setattr(K, "hash64", lambda arrays: arrays[0])
+    if ties != "as_sorted":
+        sort = jax.lax.sort
+        rank = np.arange(ph.size + bh.size, dtype=np.int32)[::-1]
+        if ties == "shuffled":
+            rank = rng.permutation(rank)
+
+        def ties_broken_by_rank(operands, num_keys, is_stable):
+            keys, src = operands
+            keys, _, src = sort((keys, jnp.asarray(rank)[src], src),
+                                num_keys=2)
+            return keys, src
+
+        monkeypatch.setattr(jax.lax, "sort", ties_broken_by_rank)
+    bh_sorted, _, _ = K.build_side_sort([jnp.asarray(bh)], jnp.asarray(live))
+    want_sorted = np.sort(np.where(live, bh, np.uint64(K.DEAD_HASH)))
+    assert np.array_equal(np.asarray(bh_sorted), want_sorted)
+    ph_j, pmask_j = jnp.asarray(ph), jnp.asarray(pmask)
+    out_cap = 1 << 17
+    want_lo, want_counts, want_pairs = _two_sided(bh_sorted, ph_j, pmask_j,
+                                                  out_cap)
+    lo, counts, total = K.probe_ranges(ph_j, pmask_j, bh_sorted)
+    assert lo.dtype == jnp.int32 and counts.dtype == jnp.int32
+    assert np.array_equal(np.asarray(lo), np.asarray(want_lo))
+    assert np.array_equal(np.asarray(counts), np.asarray(want_counts))
+    assert int(total) == int(want_pairs[3]) <= out_cap
+    got = K.expand_pairs(lo, counts, bh_sorted.shape[0], out_cap)
+    valid = np.asarray(want_pairs[2])
+    assert np.array_equal(np.asarray(got[2]), valid)
+    for g, w in zip(got[:2], want_pairs[:2]):
+        assert np.array_equal(np.asarray(g)[valid], np.asarray(w)[valid])
+    assert int(got[3]) == int(want_pairs[3])
+
+
 def test_probe_join_expansion(rng):
     build_n, probe_n, cap = 40, 60, 64
     build_keys = rng.integers(0, 20, build_n).astype(np.int64)
@@ -73,7 +166,8 @@ def test_probe_join_expansion(rng):
     bh_sorted, order, _ = K.build_side_sort([jnp.asarray(bk)], jnp.asarray(bmask))
     ph = K.hash64([jnp.asarray(pk)])
     out_cap = 4 * cap
-    pi, bp, valid, total = K.probe_join(ph, jnp.asarray(pmask), bh_sorted, out_cap)
+    lo, counts, _ = K.probe_ranges(ph, jnp.asarray(pmask), bh_sorted)
+    pi, bp, valid, total = K.expand_pairs(lo, counts, cap, out_cap)
 
     # verify real equality after hash match
     build_key_sorted = jnp.asarray(bk)[order]

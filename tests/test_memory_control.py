@@ -83,6 +83,79 @@ def test_outer_joins_keep_single_pass(join_type):
         budgeted.sort_values(sort_cols).reset_index(drop=True))
 
 
+def _lookups(j):
+    m = j.metrics().to_dict()
+    return m.get("range_lookups", 0), m.get("probe_rows_searched", 0)
+
+
+@pytest.mark.parametrize("join_type",
+                         ["inner", "left", "full", "semi", "anti"])
+def test_one_range_lookup_per_probe_batch(join_type):
+    """The build's hashes are searched once per probe batch joined, over
+    the probe's capacity: 30 000 rows in one batch of 32 768 slots."""
+    j, _ = _join(join_type)
+    assert _lookups(j) == (1, 32768)
+
+
+@pytest.mark.parametrize("join_type", ["inner", "semi", "anti"])
+def test_chunked_join_searches_the_build_once(join_type):
+    """Every window takes its rows' ranges from the one lookup made for the
+    whole probe; none searches the build again."""
+    j, _ = _join(join_type, budget=200_000)
+    assert j.metrics().to_dict()["join_probe_chunks"] > 1
+    assert _lookups(j) == (1, 32768)
+
+
+@pytest.mark.parametrize("join_type", ["inner", "semi", "anti"])
+def test_spilled_join_searches_once_per_rehydrated_partition(join_type,
+                                                             tmp_path):
+    from arrow_ballista_tpu.memory.governor import MemoryGovernor
+
+    fact, dim = _tables()
+    j = JoinExec(MemoryScanExec(SCHEMA_F, fact, 1),
+                 MemoryScanExec(SCHEMA_D, dim, 1),
+                 [(E.Column("k"), E.Column("dk"))],
+                 join_type=join_type, dist="partitioned")
+    ctx = TaskContext(config=BallistaConfig(), job_id="jspill",
+                      work_dir=str(tmp_path),
+                      governor=MemoryGovernor(host_budget=64))
+    j.execute(0, ctx)
+    # one build batch, so one spill run per non-empty hash-range partition
+    parts = j.metrics().to_dict()["spill_runs"]
+    assert 1 < parts <= JoinExec._SPILL_PARTS
+    assert _lookups(j) == (parts, parts * 32768)
+
+
+@pytest.mark.parametrize("join_type",
+                         ["inner", "left", "full", "semi", "anti"])
+def test_join_probe_takes_no_sorted_hash_operand(join_type):
+    """``join.probe`` starts from the lookup's lo/counts: no uint64 array
+    (the sorted hashes are the join's only ones) is among its operands."""
+    import jax
+
+    fact, dim = _tables()
+    left = MemoryScanExec(SCHEMA_F, fact, 1)
+    right = MemoryScanExec(SCHEMA_D, dim, 1)
+    dist = "partitioned" if join_type == "full" else "broadcast"
+    j = JoinExec(left, right, [(E.Column("k"), E.Column("dk"))],
+                 join_type=join_type, dist=dist)
+    ctx = TaskContext(config=BallistaConfig(), job_id="jops")
+    j._ensure_compiled(ctx, SCHEMA_F, SCHEMA_D)
+    compiled = list(j._compiled)
+    probe_program, seen = compiled[3], []
+    assert probe_program.name == "join_probe"
+
+    def spy(*args):
+        seen.append([str(leaf.dtype) for leaf in jax.tree.leaves(args[:-1])])
+        return probe_program(*args)
+
+    compiled[3] = spy
+    j._compiled = tuple(compiled)
+    j.execute(0, ctx)
+    assert len(seen) == 1 and "uint64" not in seen[0]
+    assert seen[0].count("int32") == 2  # lo, counts
+
+
 def test_budget_resolution():
     assert resolve_task_budget(BallistaConfig({MEM_TASK_BUDGET: "0"})) == 0
     assert resolve_task_budget(BallistaConfig({MEM_TASK_BUDGET: "1048576"})) == 1 << 20

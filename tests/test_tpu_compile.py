@@ -128,13 +128,13 @@ def test_join_build_sort_and_probe_q3_shapes(one_chip):
 
     def join(bk, bmask, pk, pmask):
         bh_sorted, border, _ = K.build_side_sort([bk], bmask)
-        pi, bp, valid, total = K.probe_join(K.hash64([pk]), pmask,
-                                            bh_sorted, out_cap)
+        lo, counts, _ = K.probe_ranges(K.hash64([pk]), pmask, bh_sorted)
+        pi, bp, valid, total = K.expand_pairs(lo, counts, build_cap, out_cap)
         bidx = border[bp]
         return valid & bmask[bidx] & (pk[pi] == bk[bidx]), total
 
     compile_for_chip(
-        "build_side_sort + probe_join", join,
+        "build_side_sort + probe_ranges + expand_pairs", join,
         sds((build_cap,), jnp.int64, one_chip),
         sds((build_cap,), jnp.bool_, one_chip),
         sds((BATCH,), jnp.int64, one_chip),
