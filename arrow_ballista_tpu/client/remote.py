@@ -238,6 +238,10 @@ class RemoteCluster:
             return None
         if status.get("cached"):
             return self._fetch_cached(job_id)
+        # result collection rides the same chunked protocol as
+        # executor-to-executor shuffle
+        from ..net.dataplane import fetch_partition
+
         schema = serde.schema_from_obj(status["schema"])
         batches: List[ColumnBatch] = []
         with span("fetch", "client", job_id=job_id) as sp:
@@ -248,7 +252,8 @@ class RemoteCluster:
                     if not loc.num_rows:
                         continue
                     nbytes += loc.num_bytes
-                    batches.extend(self._fetch(loc, schema))
+                    batches.extend(
+                        fetch_partition(loc, schema, self.config)[0])
             sp.set(bytes=nbytes)
         return batches
 
@@ -413,36 +418,3 @@ class RemoteCluster:
             batches.extend(read_ipc_buffers(blobs, schema,
                                             capacity=self.config.batch_size))
         return batches
-
-    def _fetch(self, loc, schema) -> List[ColumnBatch]:
-        from ..net.dataplane import (
-            StreamUnsupported,
-            fetch_partition_batches,
-            fetch_partition_stream,
-        )
-        from ..utils.config import (
-            SHUFFLE_INTEGRITY,
-            SHUFFLE_WIRE_CHUNK_ROWS,
-            SHUFFLE_WIRE_COMPRESSION,
-            SHUFFLE_WIRE_STREAMING,
-        )
-
-        expected = int(loc.checksum) if (
-            bool(self.config.get(SHUFFLE_INTEGRITY))
-            and loc.checksum >= 0) else -1
-        # result collection rides the same compressed chunked protocol as
-        # executor-to-executor shuffle; grpc_port=0 (native data plane or
-        # pre-upgrade executor metadata) keeps the whole-file path
-        if bool(self.config.get(SHUFFLE_WIRE_STREAMING)) and loc.grpc_port > 0:
-            try:
-                batches, _ = fetch_partition_stream(
-                    loc.host, loc.grpc_port, loc.path, schema,
-                    self.config.batch_size, expected_checksum=expected,
-                    chunk_rows=int(self.config.get(SHUFFLE_WIRE_CHUNK_ROWS)),
-                    compression=str(self.config.get(SHUFFLE_WIRE_COMPRESSION)))
-                return batches
-            except StreamUnsupported:
-                pass
-        return fetch_partition_batches(loc.host, loc.port, loc.path, schema,
-                                       self.config.batch_size,
-                                       expected_checksum=expected)

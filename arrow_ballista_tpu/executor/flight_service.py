@@ -4,8 +4,8 @@ Parity: reference executors serve shuffle partitions to peers AND stock
 Arrow clients via Flight ``do_get(Ticket{FetchPartition})``
 (reference ballista/executor/src/flight_service.rs:82-120, two-slot
 streaming channel; handshake issues a bearer token, :136-157).  The
-engine's own peers prefer the native C++ sendfile plane (net/dataplane +
-native/dataplane.cpp) — this door exists so ANY Arrow-speaking client can
+engine's own peers use the chunked stream on the executor's RPC port
+(net/dataplane.py) — this door exists so ANY Arrow-speaking client can
 fetch a partition with no Ballista code: the shuffle files on disk are
 plain Arrow IPC in physical representation (models/ipc.py), streamed
 as-is.

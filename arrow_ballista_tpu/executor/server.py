@@ -192,31 +192,13 @@ class ExecutorServer:
         if external_host is None:
             external_host = host if host not in ("0.0.0.0", "::") \
                 else socketmod.gethostname()
-        # data plane: prefer the native (C++) server — shuffle bytes then
-        # move kernel->socket via sendfile with no GIL involvement
-        # (reference analog: the Flight service next to the gRPC port).
-        # One native server per process; extra in-proc executors fall back
-        # to the Python RPC handler.  Claimed-and-nulled under
-        # _teardown_lock in stop()/kill()
-        self._native_dp = None  # ballista: guarded-by=_teardown_lock
-        data_port = self.rpc.port
-        # shared-secret auth + bounded fan-in (reference issues bearer tokens
-        # at Flight handshake, flight_service.rs:136-157, and bounds fetch
-        # concurrency with a 50-permit semaphore, shuffle_reader.rs:123)
+        # shared-secret auth of partition fetches (reference issues bearer
+        # tokens at Flight handshake, flight_service.rs:136-157)
         self._dp_token = os.environ.get("BALLISTA_DATA_PLANE_TOKEN", "")
-        from .. import native as native_mod
-
-        lib = native_mod.dataplane()
-        if lib is not None:
-            p = lib.dp_start(self.work_dir.encode(), 0,
-                             self._dp_token.encode(), 64)
-            if p > 0:
-                self._native_dp = lib
-                data_port = p
-                log.info("native data plane on port %d", p)
+        # one port: control RPCs and the chunked partition stream
         self.metadata = ExecutorMetadata(
-            executor_id=executor_id, host=external_host, port=data_port,
-            grpc_port=self.rpc.port, task_slots=concurrent_tasks)
+            executor_id=executor_id, host=external_host, port=self.rpc.port,
+            task_slots=concurrent_tasks)
         self.executor = Executor(self.metadata, self.work_dir, config,
                                  concurrent_tasks=concurrent_tasks)
         self.retry_policy = RetryPolicy.from_config(config) \
@@ -254,7 +236,7 @@ class ExecutorServer:
         self._draining = False  # ballista: guarded-by=none
         # _teardown_lock serializes stop() vs kill(): chaos fault injection
         # kills from a pool thread while a fixture teardown stops — without
-        # it both pass the None-checks and double-stop obs_http/_native_dp
+        # it both pass the None-checks and double-stop obs_http
         self._teardown_lock = threading.Lock()
         self._killed = False
         # satellite: bounded/throttled retry loops.  One transition log when
@@ -280,7 +262,7 @@ class ExecutorServer:
 
         # optional standard Arrow Flight door (reference
         # flight_service.rs:82-120): any stock Arrow client can do_get a
-        # shuffle partition; peers keep using the native/RPC plane
+        # shuffle partition; peers keep using the RPC port
         self.flight = None
         if flight_port >= 0:
             from .flight_service import ExecutorFlightServer
@@ -326,7 +308,6 @@ class ExecutorServer:
         self.rpc.register("launch_multi_task", self._launch_multi_task)
         self.rpc.register("cancel_tasks", self._cancel_tasks)
         self.rpc.register("cancel_task", self._cancel_task)
-        self.rpc.register("fetch_partition", self._fetch_partition)
         self.rpc.register_stream("fetch_partition_stream",
                                  self._fetch_partition_stream)
         self.rpc.register("remove_job_data", self._remove_job_data)
@@ -466,10 +447,9 @@ class ExecutorServer:
                 self._stop.set()
                 return
             self._stop.set()
-            # claim the shared resources under the lock so a racing kill()
-            # cannot stop them a second time (or trip over the None)
+            # claim the shared resource under the lock so a racing kill()
+            # cannot stop it a second time (or trip over the None)
             obs_http, self.obs_http = self.obs_http, None
-            native_dp, self._native_dp = self._native_dp, None
         faults.unregister_kill_target(self.metadata.executor_id)
         if notify:
             try:
@@ -486,8 +466,6 @@ class ExecutorServer:
             self.flight.stop()
         if obs_http is not None:
             obs_http.stop()
-        if native_dp is not None:
-            native_dp.dp_stop()
         self._join_threads()
 
     def _join_threads(self) -> None:
@@ -519,7 +497,6 @@ class ExecutorServer:
             self._killed = True
             self._stop.set()
             obs_http, self.obs_http = self.obs_http, None
-            native_dp, self._native_dp = self._native_dp, None
         faults.unregister_kill_target(self.metadata.executor_id)
         log.warning("executor %s killed by fault injection",
                     self.metadata.executor_id)
@@ -528,8 +505,6 @@ class ExecutorServer:
             self.flight.stop()
         if obs_http is not None:
             obs_http.stop()
-        if native_dp is not None:
-            native_dp.dp_stop()
         # wait=False: this may run on a pool thread (the task that tripped
         # the failpoint); a joining shutdown would deadlock on itself
         self.executor.pool.shutdown(wait=False)
@@ -684,8 +659,7 @@ class ExecutorServer:
     def _launch_multi_task(self, payload: dict, _bin: bytes):
         from ..scheduler.netservice import ungroup_tasks
 
-        # MultiTaskDefinition shape (one plan + N task envelopes) or the
-        # legacy flat shape
+        # MultiTaskDefinition shape (one plan + N task envelopes)
         tasks = [self._decode_task(t) for t in ungroup_tasks(payload)]
         self._learn_routes(payload, tasks)
         for task in tasks:
@@ -796,22 +770,10 @@ class ExecutorServer:
         target = os.path.realpath(path)
         return os.path.commonpath([base, target]) == base
 
-    def _fetch_partition(self, payload: dict, _bin: bytes):
-        if self._dp_token and payload.get("token", "") != self._dp_token:
-            raise ExecutionError("data plane auth failed")
-        path = payload["path"]
-        if not self._is_under_work_dir(path):
-            raise ExecutionError(f"path {path!r} escapes the work dir")
-        if not os.path.exists(path):
-            raise ExecutionError(f"no such shuffle file: {path}")
-        with open(path, "rb") as f:
-            data = f.read()
-        return {"num_bytes": len(data)}, data
-
     def _fetch_partition_stream(self, payload: dict, _bin: bytes, send):
-        """Chunked shuffle fetch: same auth + path guard as the whole-file
-        protocol, then the framing is delegated to the shared data-plane
-        server half (net/dataplane.stream_partition)."""
+        """Chunked partition fetch: auth + work-dir path guard, then the
+        framing is delegated to the shared data-plane server half
+        (net/dataplane.stream_partition)."""
         from ..net.dataplane import stream_partition
 
         if self._dp_token and payload.get("token", "") != self._dp_token:

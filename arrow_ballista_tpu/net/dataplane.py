@@ -1,28 +1,24 @@
 """Shared data-plane fetch: partition bytes -> device batches.
 
-One implementation for both consumers (reference parity: BallistaClient::
+One implementation for every consumer (reference parity: BallistaClient::
 fetch_partition, core/src/client.rs:112-187, used by shuffle reads and
-result collection alike) — bounded retries with capped jittered
-exponential backoff (``net.retry.RetryPolicy``; client.rs:57-58 used a
-fixed linear backoff).  Carries the ``shuffle.fetch.recv`` failpoint:
-per-attempt (and, on the streaming path, per-chunk) raise/delay/drop plus
-deterministic payload corruption, so chaos tests can force the
-lineage-rollback path.
+result collection alike): shuffle reads, the client's result collection
+and the Flight SQL result path all call :func:`fetch_partition`.  Bounded
+retries with capped jittered exponential backoff (``net.retry.RetryPolicy``;
+client.rs:57-58 used a fixed linear backoff).  Carries the
+``shuffle.fetch.recv`` failpoint: per-attempt and per-chunk
+raise/delay/drop plus deterministic payload corruption, so chaos tests can
+force the lineage-rollback path.
 
-Two wire formats coexist:
-
-- **whole-file** (``fetch_partition``): one request, one binary response
-  holding the complete Arrow IPC file — served by both the native C++
-  data plane and the Python RPC server.  File-level CRC-32 verification.
-- **chunked stream** (``fetch_partition_stream``): the server re-frames
-  the partition as a sequence of self-contained Arrow IPC *stream*
-  segments of ``chunk_rows`` rows each (dictionary encoding preserved,
-  optional lz4/zstd buffer compression via ``IpcWriteOptions``), each
-  chunk carrying its own CRC-32.  The client decodes chunk *k* while
-  chunk *k+1* is still in flight, and a retry resumes at the first
-  unverified chunk (``start_chunk``) instead of re-pulling the file.
-  Chunk boundaries are deterministic (row offsets ``i * chunk_rows``) so
-  resumed streams splice exactly.
+One wire format, the chunked stream (RPC method ``fetch_partition_stream``
+on the executor's one port): the server re-frames the partition as a
+sequence of self-contained Arrow IPC *stream* segments of ``chunk_rows``
+rows each (dictionary encoding preserved, optional lz4/zstd buffer
+compression via ``IpcWriteOptions``), each chunk carrying its own CRC-32.
+The client decodes chunk *k* while chunk *k+1* is still in flight, and a
+retry resumes at the first unverified chunk (``start_chunk``) instead of
+re-pulling the file.  Chunk boundaries are deterministic (row offsets
+``i * chunk_rows``) so resumed streams splice exactly.
 
 The server half (:func:`stream_partition`) lives here too so the
 protocol's two ends stay in one file and tests can exercise them through
@@ -46,14 +42,13 @@ from .retry import RetryPolicy
 log = logging.getLogger(__name__)
 
 FETCH_RETRIES = 3
-RETRY_BACKOFF_S = 3.0
 
-# convert/upload workers for the streaming path: chunk k's
+# convert/upload workers: chunk k's
 # IPC-table -> device-batch conversion runs here while the socket reads
 # chunk k+1 (at most one in flight per stream, so ordering and resume
 # bookkeeping stay trivial).  Module-level + lazy: threads are shared by
 # every concurrent fetch in the process and never spawned for
-# non-streaming workloads.
+# workloads that fetch nothing over the network.
 _CONVERT_POOL = None
 _CONVERT_POOL_LOCK = threading.Lock()
 
@@ -69,15 +64,9 @@ def _convert_pool():
                     max_workers=4, thread_name_prefix="dp-convert")
     return _CONVERT_POOL
 
-#: codecs the streaming path may negotiate ("none" disables compression)
+#: codecs a fetch may negotiate ("none" disables compression)
 WIRE_CODECS = ("lz4", "zstd")
 DEFAULT_CHUNK_ROWS = 1 << 16
-
-
-class StreamUnsupported(Exception):
-    """The peer does not speak ``fetch_partition_stream`` (pre-upgrade
-    executor or native-only data plane); callers fall back to the
-    whole-file protocol."""
 
 
 class DataPlaneStats:
@@ -85,10 +74,10 @@ class DataPlaneStats:
 
     Folded into the executor's prometheus exposition
     (``shuffle_bytes_fetched_total{path=...}``,
-    ``shuffle_wire_compression_ratio`` — executor/metrics.py) and read by
-    the bench's transport A/B leg.  ``raw_bytes``/``wire_bytes`` compare
-    the on-disk partition size with what actually crossed the network, so
-    the compression ratio is measured, not assumed.
+    ``shuffle_wire_compression_ratio`` — executor/metrics.py).
+    ``raw_bytes``/``wire_bytes`` compare the on-disk partition size with
+    what actually crossed the network, so the compression ratio is
+    measured, not assumed.
     """
 
     PATHS = ("local_mmap", "local_copy", "remote")
@@ -168,96 +157,6 @@ def _sleep_for_retry(policy: RetryPolicy, attempt: int, err: Exception) -> None:
     time.sleep(policy.backoff_s(attempt))
 
 
-def fetch_partition_batches(host: str, port: int, path: str, schema: Schema,
-                            capacity: int,
-                            retries: int = FETCH_RETRIES,
-                            backoff_s: float = RETRY_BACKOFF_S,
-                            policy: Optional[RetryPolicy] = None,
-                            expected_checksum: int = -1,
-                            fault_ctx: Optional[dict] = None) -> List[ColumnBatch]:
-    """Fetch one shuffle/result file from an executor data plane and decode
-    it into device batches.  Raises the last error after ``retries``.
-
-    ``policy`` supplies connect/read deadlines and the backoff curve; when
-    absent, legacy defaults (linear-ish ``backoff_s`` base, 3s cap) apply.
-    ``expected_checksum`` >= 0 is the producer-recorded CRC-32 of the file:
-    the payload is verified BEFORE Arrow deserialization and a mismatch
-    raises ``IntegrityError`` — retried in-loop immediately, with no
-    backoff (a re-fetch heals transient wire corruption and the peer is
-    reachable); connection failures back off between attempts.  After
-    ``retries`` the caller escalates to ``FetchFailedError`` and lineage
-    recovery re-runs the producer.  An undecodable payload surfaces the
-    same way rather than as an opaque Arrow traceback.
-    ``fault_ctx`` adds caller-known match keys (producer stage/partition/
-    executor) to the ``shuffle.fetch.recv`` failpoint context, so a chaos
-    plan can pin a rule to ONE logical fetch rather than racing the hit
-    counter across concurrent fetches.
-    """
-    import pyarrow.ipc as ipc
-
-    from ..models.ipc import physical_table_to_batches
-    from ..utils.errors import IntegrityError
-
-    import os
-    import zlib
-
-    policy = policy or RetryPolicy(base_backoff_s=backoff_s,
-                                   max_backoff_s=backoff_s * retries,
-                                   read_timeout_s=60.0)
-    req = {"path": path}
-    token = os.environ.get("BALLISTA_DATA_PLANE_TOKEN", "")
-    if token:
-        req["token"] = token
-    err: Exception = RuntimeError("unreachable")
-    for attempt in range(retries):
-        try:
-            rule = faults.inject("shuffle.fetch.recv", host=host, port=port,
-                                 path=path, attempt=attempt,
-                                 **(fault_ctx or {}))
-            if rule is not None and rule.action == "drop":
-                raise ConnectionError(
-                    "failpoint shuffle.fetch.recv dropped the payload")
-            _, data = wire.call(host, port, "fetch_partition", req,
-                                timeout=policy.read_timeout_s,
-                                connect_timeout=policy.connect_timeout_s)
-            if rule is not None and rule.action == "corrupt":
-                data = faults.corrupt_bytes(data)
-            if expected_checksum >= 0:
-                got = zlib.crc32(data)
-                if got != expected_checksum:
-                    raise IntegrityError(
-                        "shuffle.fetch.recv",
-                        f"checksum mismatch: expected crc32 "
-                        f"{expected_checksum:#010x}, got {got:#010x} "
-                        f"({len(data)} bytes)",
-                        host=host, port=port, path=path,
-                        **(fault_ctx or {}))
-            try:
-                table = ipc.open_file(io.BytesIO(data)).read_all()
-            except Exception as decode_err:
-                # undecodable frame == corruption the checksum did not (or
-                # could not) catch; surface it as the same diagnosable,
-                # retryable integrity failure instead of an Arrow traceback
-                raise IntegrityError(
-                    "shuffle.fetch.recv",
-                    f"undecodable partition payload ({len(data)} bytes): "
-                    f"{decode_err}",
-                    host=host, port=port, path=path,
-                    **(fault_ctx or {})) from decode_err
-            STATS.record("remote", len(data))
-            return physical_table_to_batches(table, schema, capacity=capacity)
-        except Exception as e:  # noqa: BLE001 — caller maps to its classification
-            err = e
-            if attempt + 1 < retries:
-                _sleep_for_retry(policy, attempt, e)
-    raise err
-
-
-# --------------------------------------------------------------------------
-# chunked streaming protocol
-# --------------------------------------------------------------------------
-
-
 def stream_partition(path: str, payload: dict,
                      send: Callable[[dict, bytes], None],
                      default_chunk_rows: int = DEFAULT_CHUNK_ROWS) -> None:
@@ -329,32 +228,41 @@ def stream_partition(path: str, payload: dict,
         "codec": codec or "none"}}, b"")
 
 
-def fetch_partition_stream(host: str, port: int, path: str, schema: Schema,
-                           capacity: int,
-                           retries: int = FETCH_RETRIES,
-                           policy: Optional[RetryPolicy] = None,
-                           expected_checksum: int = -1,
-                           chunk_rows: int = DEFAULT_CHUNK_ROWS,
-                           compression: str = "lz4",
-                           fault_ctx: Optional[dict] = None,
-                           ) -> Tuple[List[ColumnBatch], Dict[str, int]]:
-    """Client half of the chunked protocol: fetch one partition as a
-    pipelined chunk stream, decoding each verified chunk immediately.
+def fetch_partition(loc, schema: Schema, config,
+                    fault_ctx: Optional[dict] = None,
+                    ) -> Tuple[List[ColumnBatch], Dict[str, int]]:
+    """Fetch one stored partition from the executor that owns it and
+    decode it into device batches: the one way a partition crosses the
+    network, for shuffle reads, result collection and Flight SQL alike.
 
-    Returns ``(batches, stats)`` where stats carries ``chunks`` /
-    ``raw_bytes`` / ``wire_bytes`` / ``resumed_chunks`` for the caller's
-    operator metrics.  Retry semantics:
+    ``loc`` is anything with ``host``, ``port``, ``path`` and ``checksum``
+    (a ``PartitionLocation``).  ``config`` is the session's
+    ``BallistaConfig``: batch capacity, ``ballista.shuffle.integrity.verify``
+    (whether the producer-recorded CRC-32 of the file is sent for the
+    server to check against its disk), the chunk size, the wire codec and
+    the ``ballista.rpc.*`` deadlines and backoff all come from it.
+    ``fault_ctx`` adds caller-known match keys (producer stage/partition/
+    executor) to the ``shuffle.fetch.recv`` failpoint context, so a chaos
+    plan can pin a rule to ONE logical fetch rather than racing the hit
+    counter across concurrent fetches.
+
+    The partition arrives as a pipelined chunk stream and each verified
+    chunk is decoded immediately.  Returns ``(batches, stats)`` where stats
+    carries ``chunks`` / ``raw_bytes`` / ``wire_bytes`` /
+    ``resumed_chunks`` / ``codec`` for the caller's operator metrics.
+    Raises the last error after ``FETCH_RETRIES`` attempts; the caller
+    maps it to its own classification (``FetchFailedError`` -> lineage
+    recovery for a shuffle read).  Retry semantics:
 
     - a corrupt chunk (CRC mismatch or undecodable) raises
       ``IntegrityError`` and re-fetches IMMEDIATELY from the first
-      unverified chunk — already-decoded chunks are kept;
+      unverified chunk: already-decoded chunks are kept;
     - connection failures back off (jittered) and also resume;
     - a server-reported ``IntegrityError`` (the on-disk file itself is
-      corrupt) is NOT retried — re-fetching cannot heal a bad disk file,
-      so it escalates straight to the caller's ``FetchFailedError`` ->
-      lineage rollback;
-    - an ``unknown method`` answer raises :class:`StreamUnsupported` so
-      the caller falls back to the whole-file protocol.
+      corrupt) is NOT retried: re-fetching cannot heal a bad disk file,
+      so it escalates straight to the caller;
+    - any other answer of the server (auth, path guard, missing file) is
+      not retried either: the server answered, and would answer the same.
     """
     import os
     import zlib
@@ -362,9 +270,17 @@ def fetch_partition_stream(host: str, port: int, path: str, schema: Schema,
     import pyarrow.ipc as ipc
 
     from ..models.ipc import physical_table_to_batches
+    from ..utils.config import (SHUFFLE_INTEGRITY, SHUFFLE_WIRE_CHUNK_ROWS,
+                                SHUFFLE_WIRE_COMPRESSION)
     from ..utils.errors import IntegrityError
 
-    policy = policy or RetryPolicy(read_timeout_s=60.0)
+    host, port, path = loc.host, int(loc.port), loc.path
+    capacity = config.batch_size
+    policy = RetryPolicy.from_config(config)
+    expected_checksum = int(loc.checksum) \
+        if config.get(SHUFFLE_INTEGRITY) else -1
+    chunk_rows = int(config.get(SHUFFLE_WIRE_CHUNK_ROWS))
+    compression = str(config.get(SHUFFLE_WIRE_COMPRESSION))
     token = os.environ.get("BALLISTA_DATA_PLANE_TOKEN", "")
     batches: List[ColumnBatch] = []
     state = {"next_chunk": 0, "wire_bytes": 0, "resumed": 0,
@@ -483,7 +399,7 @@ def fetch_partition_stream(host: str, port: int, path: str, schema: Schema,
             sock.close()
 
     err: Exception = RuntimeError("unreachable")
-    for attempt in range(retries):
+    for attempt in range(FETCH_RETRIES):
         try:
             _stream_once(attempt)
             stats = {"chunks": state["chunks"],
@@ -496,21 +412,18 @@ def fetch_partition_stream(host: str, port: int, path: str, schema: Schema,
                                 state["wire_bytes"], state["resumed"])
             return batches, stats
         except wire.RemoteError as e:
-            if "unknown method" in str(e):
-                raise StreamUnsupported(str(e)) from e
             if e.kind == "IntegrityError":
                 # the server verified the DISK file against the producer
                 # checksum and it failed: no re-fetch can heal that —
                 # escalate now so lineage re-runs the producer
-                from ..utils.errors import IntegrityError as IErr
-
-                raise IErr("shuffle.fetch.stream",
-                           f"producer file corrupt on disk: {e}",
-                           host=host, port=port, path=path,
-                           **(fault_ctx or {})) from e
+                raise IntegrityError(
+                    "shuffle.fetch.stream",
+                    f"producer file corrupt on disk: {e}",
+                    host=host, port=port, path=path,
+                    **(fault_ctx or {})) from e
             raise
         except Exception as e:  # noqa: BLE001 — caller maps to its classification
             err = e
-            if attempt + 1 < retries:
+            if attempt + 1 < FETCH_RETRIES:
                 _sleep_for_retry(policy, attempt, e)
     raise err

@@ -60,8 +60,9 @@ class ShuffleWritePartition:
 @dataclasses.dataclass
 class PartitionLocation:
     """Where a map output lives (reference ballista.proto:211-221).
-    ``host``/``port`` address the owning executor's data plane for remote
-    fetch (the reference embeds ExecutorMetadata the same way)."""
+    ``host``/``port`` address the owning executor's one RPC port, which
+    serves the chunked fetch (the reference embeds ExecutorMetadata the
+    same way)."""
 
     executor_id: str
     map_partition: int
@@ -72,11 +73,7 @@ class PartitionLocation:
     host: str = ""
     port: int = 0
     checksum: int = -1  # producer-recorded CRC-32; -1 = unknown, skip verify
-    # control-plane (Python RPC) port of the owning executor: ``port`` may
-    # address the native whole-file data plane, so streaming fetches dial
-    # here instead.  0 = producer predates streaming, whole-file only.
-    grpc_port: int = 0
-    # on-disk representation; "" = legacy/unknown (treated as arrow_file).
+    # on-disk representation; "" = unknown (treated as arrow_file).
     # Lets a consumer reject a same-host mmap of a format it can't read
     # if the disk layout ever changes.
     format: str = ""
@@ -393,47 +390,22 @@ class ShuffleReaderExec(ExecutionPlan):
         return out
 
     def _fetch_remote(self, loc: PartitionLocation, ctx: TaskContext) -> List[ColumnBatch]:
-        from ..net.dataplane import (StreamUnsupported,
-                                     fetch_partition_batches,
-                                     fetch_partition_stream)
-        from ..net.retry import RetryPolicy
-        from ..utils.config import (SHUFFLE_INTEGRITY, SHUFFLE_WIRE_CHUNK_ROWS,
-                                    SHUFFLE_WIRE_COMPRESSION,
-                                    SHUFFLE_WIRE_STREAMING)
+        from ..net.dataplane import fetch_partition
 
-        policy = RetryPolicy.from_config(ctx.config)
-        expected = (loc.checksum
-                    if ctx.config.get(SHUFFLE_INTEGRITY) else -1)
-        fault_ctx = {"stage_id": self.stage_id,
-                     "map_partition": loc.map_partition,
-                     "executor_id": loc.executor_id}
         try:
-            if ctx.config.get(SHUFFLE_WIRE_STREAMING) and loc.grpc_port > 0:
-                try:
-                    batches, stats = fetch_partition_stream(
-                        loc.host, loc.grpc_port, loc.path,
-                        self._schema, ctx.config.batch_size,
-                        policy=policy, expected_checksum=expected,
-                        chunk_rows=int(ctx.config.get(SHUFFLE_WIRE_CHUNK_ROWS)),
-                        compression=str(ctx.config.get(SHUFFLE_WIRE_COMPRESSION)),
-                        fault_ctx=fault_ctx)
-                    self.metrics().add("remote_fetches", 1)
-                    self.metrics().add("fetch_chunks", stats["chunks"])
-                    self.metrics().add("wire_bytes", stats["wire_bytes"])
-                    self.metrics().add("raw_bytes", stats["raw_bytes"])
-                    return batches
-                except StreamUnsupported:
-                    pass  # pre-upgrade peer: fall through to whole-file
-            batches = fetch_partition_batches(
-                loc.host, loc.port, loc.path,
-                self._schema, ctx.config.batch_size,
-                policy=policy, expected_checksum=expected,
-                fault_ctx=fault_ctx)
-            self.metrics().add("remote_fetches", 1)
-            return batches
+            batches, stats = fetch_partition(
+                loc, self._schema, ctx.config,
+                fault_ctx={"stage_id": self.stage_id,
+                           "map_partition": loc.map_partition,
+                           "executor_id": loc.executor_id})
         except Exception as err:  # noqa: BLE001 — retries exhausted
             raise FetchFailedError(loc.executor_id, self.stage_id, loc.map_partition,
                                    f"remote fetch failed: {err}") from err
+        self.metrics().add("remote_fetches", 1)
+        self.metrics().add("fetch_chunks", stats["chunks"])
+        self.metrics().add("wire_bytes", stats["wire_bytes"])
+        self.metrics().add("raw_bytes", stats["raw_bytes"])
+        return batches
 
     def _label(self):
         return f"ShuffleReaderExec: stage={self.stage_id} partitions={self.partition_count}"

@@ -211,7 +211,7 @@ class ExecutionStage:
 
     def aggregate_metrics(self) -> Dict[str, float]:
         """Flattened '<op>.<metric>' -> total view of
-        ``operator_metrics`` (the REST stage view and bench profiler)."""
+        ``operator_metrics`` (the REST stage view)."""
         return {f"{op}.{k}": v
                 for op, mm in self.operator_metrics().items()
                 for k, v in mm.items()}
@@ -233,24 +233,18 @@ class ExecutionStage:
 
     def output_locations(self, addr_resolver=None) -> Dict[int, List[PartitionLocation]]:
         """output partition -> locations across all map tasks.
-        ``addr_resolver(executor_id) -> (host, port[, grpc_port])`` stamps
-        the data-plane address for remote fetch (None in purely local
-        deployments); the optional third element is the executor's control
-        port, where the chunked ``fetch_partition_stream`` protocol lives
-        (0 = whole-file fetch only, e.g. a pre-upgrade resolver)."""
+        ``addr_resolver(executor_id) -> (host, port)`` stamps the owning
+        executor's address for remote fetch (None in purely local
+        deployments)."""
         locs: Dict[int, List[PartitionLocation]] = {}
         for map_part, (executor_id, writes) in sorted(self.outputs.items()):
-            host, port, grpc_port = ("", 0, 0)
-            if addr_resolver is not None:
-                addr = addr_resolver(executor_id)
-                host, port = addr[0], addr[1]
-                grpc_port = addr[2] if len(addr) > 2 else 0
+            host, port = addr_resolver(executor_id) \
+                if addr_resolver is not None else ("", 0)
             for w in writes:
                 locs.setdefault(w.output_partition, []).append(
                     PartitionLocation(executor_id, map_part, w.output_partition,
                                       w.path, w.num_rows, w.num_bytes,
                                       host, port, checksum=w.checksum,
-                                      grpc_port=grpc_port,
                                       format="arrow_file"))
         return locs
 
@@ -397,7 +391,7 @@ class ExecutionGraph:
         self.stats = RuntimeStatsStore(job_id)
         # adaptive query execution (scheduler/aqe.py): per-job policy (the
         # scheduler overwrites it from the session config right after
-        # build), the flat rewrite log (bench/REST/serde), and the pending
+        # build), the flat rewrite log (REST/serde), and the pending
         # metric events the scheduler drains into its collector
         self.aqe = AqePolicy()
         self.aqe_log: List[dict] = []

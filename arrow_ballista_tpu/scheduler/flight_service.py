@@ -491,7 +491,7 @@ class BallistaFlightServer:
         from .. import serde
         from ..models.batch import ColumnBatch
         from ..models.ipc import read_ipc_files
-        from ..net.dataplane import fetch_partition_batches
+        from ..net.dataplane import fetch_partition
         from ..utils.errors import ExecutionError
 
         payload, _ = self.svc._execute_query({"sql": sql}, b"")
@@ -515,9 +515,8 @@ class BallistaFlightServer:
                 if os.path.exists(loc.path):
                     batches.extend(read_ipc_files([loc.path], schema))
                 else:
-                    batches.extend(fetch_partition_batches(
-                        loc.host, loc.port, loc.path, schema,
-                        self.svc.config.batch_size))
+                    batches.extend(fetch_partition(
+                        loc, schema, self.svc.config)[0])
         tables = [b.to_arrow().cast(target) for b in batches]
         return pa.concat_tables(tables) if tables \
             else target.empty_table()

@@ -20,10 +20,9 @@ from .. import faults, serde
 from ..catalog import CsvTable, MemoryTable, ParquetTable, SchemaCatalog
 from ..models.schema import Field, Schema
 from ..net.rpc import RpcServer
-from ..net import wire
 from ..net.retry import RetryPolicy, call_with_retry
 from ..utils.config import BallistaConfig
-from ..utils.errors import PlanningError
+from ..utils.errors import ExecutionError, PlanningError
 from .scheduler import SchedulerConfig, SchedulerServer, TaskLauncher, random_job_id
 from .types import ExecutorHeartbeat, ExecutorMetadata, TaskDescription
 
@@ -89,9 +88,9 @@ def group_tasks_by_plan(objs: List[dict]) -> List[dict]:
 
 
 def ungroup_tasks(payload: dict) -> List[dict]:
-    """Inverse of group_tasks_by_plan; accepts the legacy flat shape too."""
+    """Inverse of group_tasks_by_plan."""
     if "stages" not in payload:
-        return list(payload.get("tasks", []))
+        raise ExecutionError("task payload has no 'stages'")
     out = []
     for st in payload["stages"]:
         for env in st["tasks"]:
@@ -125,7 +124,7 @@ class NetTaskLauncher(TaskLauncher):
         meta = self.scheduler.cluster.get_executor(executor_id)
         if meta is None:
             raise PlanningError(f"unknown executor {executor_id}")
-        return meta.host, meta.grpc_port or meta.port
+        return meta.host, meta.port
 
     def launch_tasks(self, executor_id: str, tasks: List[TaskDescription]) -> None:
         objs = serialize_tasks_or_fail(self.scheduler, executor_id, tasks)
@@ -140,17 +139,8 @@ class NetTaskLauncher(TaskLauncher):
         if self.endpoint is not None:
             payload["scheduler"] = {"host": self.endpoint[0],
                                     "port": self.endpoint[1]}
-        try:
-            call_with_retry(host, port, "launch_multi_task", payload,
-                            policy=self.policy)
-        except wire.RemoteError as e:
-            if "'tasks'" not in str(e):
-                raise
-            # mixed-version rollout: an executor predating the grouped
-            # shape KeyErrors on payload['tasks'] — resend flat once
-            log.info("executor %s speaks the legacy launch shape", executor_id)
-            call_with_retry(host, port, "launch_multi_task", {"tasks": objs},
-                            policy=self.policy)
+        call_with_retry(host, port, "launch_multi_task", payload,
+                        policy=self.policy)
 
     def cancel_tasks(self, executor_id: str, job_id: str) -> None:
         if faults.dropped("scheduler.cancel.fanout",

@@ -320,7 +320,7 @@ class RestApi:
             hb = cluster._heartbeats.get(meta.executor_id)
             out.append({
                 "executor_id": meta.executor_id, "host": meta.host,
-                "port": meta.port, "grpc_port": meta.grpc_port,
+                "port": meta.port,
                 "task_slots": meta.task_slots,
                 "last_seen_s_ago": round(time.time() - hb.timestamp, 1) if hb else None,
                 "status": hb.status if hb else "unknown",

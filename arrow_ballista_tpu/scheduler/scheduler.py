@@ -1030,8 +1030,12 @@ class SchedulerServer:
 
     def _on_lease_lost(self, job_id: str, why: str) -> None:
         """Fencing kicked in: another shard owns the job now.  Drop every
-        local trace of it and reap our in-flight tasks — the adopter
-        relaunches them and records all further state."""
+        local trace of it; the adopter relaunches what was in flight and
+        records all further state.  No cancel goes to the executors: a
+        cancel names the job, not this shard's attempts, so it would kill
+        the tasks the adopter has already launched there, mark the job
+        cancelled for every later one and sweep its shuffle files.  Our
+        in-flight attempts run out as they do after a crashed owner."""
         with self._lease_lock:
             self._leases.pop(job_id, None)
         if self.jobs.get_status(job_id) is None:
@@ -1048,14 +1052,11 @@ class SchedulerServer:
         # marker before the job is dropped locally (the adopter's spans
         # continue the same trace_id via the checkpointed context)
         self.obs.on_stand_down(job_id, why)
-        graph = self.jobs.get_graph(job_id)
         self.jobs.remove_job(job_id)
         with self._meta_lock:
             self._queued_at_ms.pop(job_id, None)
             self._serving_info.pop(job_id, None)
         self.admission.release(job_id)
-        if graph is not None:
-            self._submit_work(self._cancel_running, graph)
 
     def recover_jobs(self) -> List[str]:
         """Adopt persisted unfinished jobs (reference try_acquire_job,
@@ -1144,6 +1145,12 @@ class SchedulerServer:
         while not self._stopped.wait(self.config.fleet_adopt_interval_s):
             try:
                 self.adopt_expired_jobs()
+                # the slots are shared: another shard (a fenced ex-owner
+                # absorbing its last statuses) can free them, and no event
+                # of ours says so.  An offer that came up empty is made
+                # again here, or an adopted job would wait for ever
+                if self.pending_task_count() > 0:
+                    self._event_loop.post(Offer())
             except Exception:  # noqa: BLE001 — scan again next interval
                 log.exception("lease adoption scan failed")
 
@@ -1684,12 +1691,8 @@ class SchedulerServer:
         events.clear()
 
     def _resolve_addr(self, executor_id: str):
-        # (host, data-plane port, control-plane port): the data plane may be
-        # the native whole-file server, so streaming fetches dial grpc_port
-        # (the Python RPC server, which speaks fetch_partition_stream)
         meta = self.cluster.get_executor(executor_id)
-        return (meta.host, meta.port, meta.grpc_port) \
-            if meta is not None else ("", 0, 0)
+        return (meta.host, meta.port) if meta is not None else ("", 0)
 
     # --- push scheduling -------------------------------------------------
     def _offer(self) -> None:

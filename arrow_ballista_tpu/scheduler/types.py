@@ -127,7 +127,6 @@ class ExecutorMetadata:
     executor_id: str
     host: str = "localhost"
     port: int = 0
-    grpc_port: int = 0
     task_slots: int = 1
 
 

@@ -189,9 +189,9 @@ def location_to_obj(l: PartitionLocation) -> dict:
 
 
 def location_from_obj(o: dict) -> PartitionLocation:
-    # tolerant across wire versions: unknown keys (from a NEWER peer) are
-    # dropped, missing keys (from an OLDER peer) take dataclass defaults —
-    # a rolling upgrade must not wedge on shuffle metadata
+    # unknown keys are dropped: a job's locations are persisted with its
+    # graph, and a state store written by another build of the scheduler
+    # must not wedge recovery on shuffle metadata
     import dataclasses as _dc
 
     known = {f.name for f in _dc.fields(PartitionLocation)}

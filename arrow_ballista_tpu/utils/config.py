@@ -106,7 +106,6 @@ SHUFFLE_INTEGRITY = "ballista.shuffle.integrity.verify"
 # path, streaming chunked remote fetch, and wire compression
 SHUFFLE_LOCAL_HOST_MATCH = "ballista.shuffle.local.host_match"
 SHUFFLE_MAX_CONCURRENT_FETCHES = "ballista.shuffle.max_concurrent_fetches"
-SHUFFLE_WIRE_STREAMING = "ballista.shuffle.wire.streaming"
 SHUFFLE_WIRE_CHUNK_ROWS = "ballista.shuffle.wire.chunk_rows"
 SHUFFLE_WIRE_COMPRESSION = "ballista.shuffle.wire.compression"
 # runtime statistics observatory (obs/stats.py + scheduler sampler)
@@ -497,13 +496,6 @@ _ENTRIES: Dict[str, ConfigEntry] = {
                     "fetches (the reference's 50-permit semaphore, "
                     "shuffle_reader.rs:123); fetches run on a shared "
                     "process-level pool rather than a per-task one"),
-        ConfigEntry(SHUFFLE_WIRE_STREAMING, True, _parse_bool,
-                    "chunked streaming remote fetch: shuffle partitions "
-                    "stream as framed Arrow IPC chunks (per-chunk CRC-32) "
-                    "so the reader decodes batches while later chunks are "
-                    "in flight, and a retry resumes from the last good "
-                    "chunk instead of re-pulling the whole file.  False = "
-                    "legacy whole-file fetch_partition blobs"),
         ConfigEntry(SHUFFLE_WIRE_CHUNK_ROWS, 1 << 16, int,
                     "rows per streamed shuffle chunk; chunk boundaries are "
                     "deterministic multiples of this so resume-from-chunk "

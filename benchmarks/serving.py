@@ -85,7 +85,7 @@ def ensure_data(scale: float = 0.01, data_dir: Optional[str] = None) -> str:
     overheads — the thing the caches attack — dominate the uncached leg."""
     data_dir = data_dir or os.path.join(REPO, ".bench_data",
                                         f"tpch-sf{scale:g}")
-    # two layouts exist: bench.py's <name>.parquet dirs and datagen's bare
+    # two layouts exist: <name>.parquet dirs and datagen's bare
     # <name> dirs — accept either, generate the latter when absent
     if not (os.path.exists(os.path.join(data_dir, "lineitem"))
             or os.path.exists(os.path.join(data_dir, "lineitem.parquet"))):

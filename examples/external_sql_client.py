@@ -4,9 +4,9 @@
 Demonstrates the scheduler's external SQL surface (the Arrow Flight SQL
 role of the reference, ballista/scheduler/src/flight_sql.rs:83-911): any
 client that can speak the framing below — open a session, prepare/execute
-SQL, poll status, fetch result partitions from executor data planes — can
+SQL, poll status, fetch result partitions from the executors — can
 run queries.  Only stdlib + pyarrow (for decoding the Arrow IPC result
-files) are used; nothing from arrow_ballista_tpu.
+chunks) are used; nothing from arrow_ballista_tpu.
 
 Usage:
     # start a cluster:
@@ -19,7 +19,9 @@ Usage:
 
 Wire protocol (net/wire.py): frame = u32 json_len | u64 bin_len | json | bin;
 request json = {"method": ..., "payload": {...}}; response json =
-{"ok": bool, "payload"|"error": ...}.
+{"ok": bool, "payload"|"error": ...}.  ``fetch_partition_stream`` answers
+one request with many frames: {"chunk", "rows", "crc", "chunks"} + the
+chunk's bytes, then {"eos": true, ...}.
 """
 import io
 import json
@@ -27,24 +29,53 @@ import socket
 import struct
 import sys
 import time
+import zlib
 
 HDR = struct.Struct("!IQ")
+
+
+def _send(sock, method, payload):
+    body = json.dumps({"method": method, "payload": payload or {}}).encode()
+    sock.sendall(HDR.pack(len(body), 0) + body)
+
+
+def _recv_frame(sock):
+    jlen, blen = HDR.unpack(_recv(sock, HDR.size))
+    obj = json.loads(_recv(sock, jlen))
+    binary = _recv(sock, blen) if blen else b""
+    if not obj.get("ok"):
+        raise RuntimeError(obj.get("error", "remote error"))
+    return obj.get("payload", {}), binary
 
 
 def call(host, port, method, payload=None, timeout=60.0):
     sock = socket.create_connection((host, port), timeout=timeout)
     sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
     try:
-        body = json.dumps({"method": method, "payload": payload or {}},
-                          separators=(",", ":")).encode()  # compact: the native data plane parses exact framing
-        sock.sendall(HDR.pack(len(body), 0) + body)
-        hdr = _recv(sock, HDR.size)
-        jlen, blen = HDR.unpack(hdr)
-        obj = json.loads(_recv(sock, jlen))
-        binary = _recv(sock, blen) if blen else b""
-        if not obj.get("ok"):
-            raise RuntimeError(obj.get("error", "remote error"))
-        return obj.get("payload", {}), binary
+        _send(sock, method, payload)
+        return _recv_frame(sock)
+    finally:
+        sock.close()
+
+
+def fetch_partition(host, port, path, timeout=60.0):
+    """One stored partition from the executor that owns it: one request,
+    then a stream of frames, each a self-contained Arrow IPC stream with
+    its CRC-32, until the frame that says ``eos``."""
+    import pyarrow.ipc as ipc
+
+    sock = socket.create_connection((host, port), timeout=timeout)
+    try:
+        _send(sock, "fetch_partition_stream",
+              {"path": path, "compression": "none"})
+        tables = []
+        while True:
+            frame, chunk = _recv_frame(sock)
+            if frame.get("eos"):
+                return tables
+            if zlib.crc32(chunk) != frame["crc"]:
+                raise RuntimeError(f"chunk {frame['chunk']} of {path} is corrupt")
+            tables.append(ipc.open_stream(io.BytesIO(chunk)).read_all())
     finally:
         sock.close()
 
@@ -78,17 +109,15 @@ def run_sql(host, port, session_id, sql):
         time.sleep(0.1)
 
     import pyarrow as pa
-    import pyarrow.ipc as ipc
 
     tables = []
     for part in sorted(status["locations"], key=int):
         for loc in status["locations"][part]:
             if not loc["num_rows"]:
                 continue
-            # fetch the partition file from the owning executor's data plane
-            _, data = call(loc["host"], loc["port"], "fetch_partition",
-                           {"path": loc["path"]})
-            tables.append(ipc.open_file(io.BytesIO(data)).read_all())
+            # fetch the partition from the executor that owns it
+            tables.extend(fetch_partition(loc["host"], loc["port"],
+                                          loc["path"]))
     if not tables:
         print("(empty result)")
         return

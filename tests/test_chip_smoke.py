@@ -1,4 +1,4 @@
-"""chip_smoke.py, bench.py and the compile cache: nothing quietly leaves
+"""chip_smoke.py and the compile cache: nothing quietly leaves
 the chip, and the caller places the cache.
 
 The smoke's real run is on the chip (``python chip_smoke.py`` through the
@@ -80,13 +80,6 @@ def test_compilation_cache_is_where_the_caller_puts_it(tmp_path, placed):
     want = str(tmp_path) if placed else os.path.join(REPO, ".xla_cache")
     assert out.stdout.strip().splitlines()[-1] == want
     assert os.listdir(want), "the compiled program was not written there"
-
-
-def test_bench_refuses_a_cpu_unless_asked_by_name():
-    out = _run(["bench.py"], 120)
-    assert out.returncode != 0
-    assert "platform 'cpu'" in out.stderr and "--platform cpu" in out.stderr
-    assert out.stdout.strip() == ""  # no number printed
 
 
 def test_importing_the_engine_starts_no_backend():

@@ -172,13 +172,18 @@ def test_heartbeat_reregisters_unknown_executor():
 
 
 # --------------------------------------------------------------------------
-# data-plane auth token (python fallback handler)
+# data-plane auth token
 # --------------------------------------------------------------------------
 
 
 def test_data_plane_token(tmp_path, monkeypatch):
     monkeypatch.setenv("BALLISTA_DATA_PLANE_TOKEN", "sekrit")
+    import numpy as np
+
     from arrow_ballista_tpu.executor.server import ExecutorServer
+    from arrow_ballista_tpu.models.batch import ColumnBatch
+    from arrow_ballista_tpu.models.ipc import write_ipc_file
+    from arrow_ballista_tpu.models.schema import Field, INT64, Schema
     from arrow_ballista_tpu.utils.errors import ExecutionError
 
     srv = ExecutorServer("127.0.0.1", 1, port=0, work_dir=str(tmp_path),
@@ -186,14 +191,48 @@ def test_data_plane_token(tmp_path, monkeypatch):
     try:
         p = tmp_path / "jobt" / "f.arrow"
         p.parent.mkdir(parents=True)
-        p.write_bytes(b"data")
+        schema = Schema([Field("v", INT64)])
+        write_ipc_file(ColumnBatch.from_numpy(
+            schema, {"v": np.arange(4, dtype=np.int64)}), str(p))
+        frames = []
+
+        def send(obj, chunk):
+            frames.append((obj, chunk))
+
         with pytest.raises(ExecutionError):
-            srv._fetch_partition({"path": str(p)}, b"")
+            srv._fetch_partition_stream({"path": str(p)}, b"", send)
         with pytest.raises(ExecutionError):
-            srv._fetch_partition({"path": str(p), "token": "wrong"}, b"")
-        payload, data = srv._fetch_partition(
-            {"path": str(p), "token": "sekrit"}, b"")
-        assert data == b"data"
+            srv._fetch_partition_stream(
+                {"path": str(p), "token": "wrong"}, b"", send)
+        assert frames == []
+        srv._fetch_partition_stream(
+            {"path": str(p), "token": "sekrit"}, b"", send)
+        assert frames[0][0]["payload"]["rows"] == 4 and frames[0][1]
+        assert frames[-1][0]["payload"]["eos"]
+    finally:
+        srv.stop(notify=False)
+
+
+# --------------------------------------------------------------------------
+# a launch payload is the grouped shape or an error: never guessed at
+# --------------------------------------------------------------------------
+
+
+def test_launch_without_stages_is_an_error_and_starts_no_task(tmp_path):
+    from arrow_ballista_tpu.executor.server import ExecutorServer
+    from arrow_ballista_tpu.net import wire
+
+    srv = ExecutorServer("127.0.0.1", 1, port=0, work_dir=str(tmp_path))
+    srv.rpc.start()
+    try:
+        flat = {"tasks": [{"task": {}, "plan": {}, "internal_id": 1,
+                           "scalars": {}}]}
+        with pytest.raises(wire.RemoteError, match="no 'stages'"):
+            wire.call("127.0.0.1", srv.rpc.port, "launch_multi_task", flat)
+        assert srv.executor.active_tasks() == 0
+        payload, _ = wire.call("127.0.0.1", srv.rpc.port,
+                               "launch_multi_task", {"stages": []})
+        assert payload == {"accepted": 0}
     finally:
         srv.stop(notify=False)
 
@@ -212,6 +251,7 @@ def test_concurrent_remote_fetch(tmp_path):
     from arrow_ballista_tpu.models.batch import ColumnBatch
     from arrow_ballista_tpu.models.ipc import write_ipc_file
     from arrow_ballista_tpu.models.schema import Field, INT64, Schema
+    from arrow_ballista_tpu.net.dataplane import stream_partition
     from arrow_ballista_tpu.net.rpc import RpcServer
     from arrow_ballista_tpu.ops.physical import TaskContext
     from arrow_ballista_tpu.ops.shuffle import PartitionLocation, ShuffleReaderExec
@@ -228,19 +268,19 @@ def test_concurrent_remote_fetch(tmp_path):
     inflight = {"now": 0, "max": 0}
     lock = threading.Lock()
 
-    def fetch(payload, _bin):
+    def fetch(payload, _bin, send):
         with lock:
             inflight["now"] += 1
             inflight["max"] = max(inflight["max"], inflight["now"])
         time.sleep(0.05)  # hold the slot so overlap is observable
-        with open(payload["path"], "rb") as f:
-            data = f.read()
-        with lock:
-            inflight["now"] -= 1
-        return {"num_bytes": len(data)}, data
+        try:
+            stream_partition(payload["path"], payload, send)
+        finally:
+            with lock:
+                inflight["now"] -= 1
 
     server = RpcServer("127.0.0.1", 0)
-    server.register("fetch_partition", fetch)
+    server.register_stream("fetch_partition_stream", fetch)
     server.start()
     try:
         locs = [PartitionLocation("exec-remote", i, 0, paths[i], num_rows=4,

@@ -104,14 +104,13 @@ SAMPLES = {
         PartitionLocation("exec-1", 0, 2, "/tmp/p2", checksum=0xCAFEF00D),
         PartitionLocation("exec-1", 1, 3, "/tmp/p3", num_rows=9,
                           num_bytes=512, host="10.0.0.3", port=50051,
-                          checksum=0x1234, grpc_port=50052,
-                          format="arrow_file"),
+                          checksum=0x1234, format="arrow_file"),
         LOCATION,
     ],
     ExecutorMetadata: [
         ExecutorMetadata("exec-1"),
         ExecutorMetadata("exec-2", host="10.0.0.9", port=7000,
-                         grpc_port=7001, task_slots=8),
+                         task_slots=8),
     ],
     ExecutorHeartbeat: [
         ExecutorHeartbeat("exec-1", timestamp=123.5),

@@ -1,7 +1,9 @@
 """Shuffle transport tests: zero-copy co-located mmap reads, the chunked
 streaming wire protocol (per-chunk CRC, resume-from-chunk, compression
-negotiation), the whole-file legacy path, and the retry-policy split
-between corrupt payloads (immediate re-fetch) and dead peers (backoff).
+negotiation) through a bare RPC server and through a real executor's
+handler, the retry-policy split between corrupt payloads (immediate
+re-fetch) and dead peers (backoff), and the one entry point every
+consumer reaches the network through.
 
 Everything asserts BIT-IDENTITY against a direct local read of the same
 partition file: a transport is only correct if no path can change a
@@ -19,7 +21,6 @@ from arrow_ballista_tpu.models.ipc import (crc32_file, read_ipc_files,
                                            write_ipc_rows)
 from arrow_ballista_tpu.models.schema import DataType, Field, Schema
 from arrow_ballista_tpu.net import dataplane as dp
-from arrow_ballista_tpu.net.retry import RetryPolicy
 from arrow_ballista_tpu.net.rpc import RpcServer
 from arrow_ballista_tpu.ops.physical import TaskContext
 from arrow_ballista_tpu.ops.shuffle import PartitionLocation, ShuffleReaderExec
@@ -68,15 +69,8 @@ def partition(tmp_path):
 
 @pytest.fixture()
 def stream_server(tmp_path):
-    """Bare RPC server speaking both fetch protocols over ``tmp_path``."""
+    """Bare RPC server speaking the fetch protocol over ``tmp_path``."""
     srv = RpcServer("127.0.0.1", 0)
-
-    def whole_file(payload, _bin):
-        with open(payload["path"], "rb") as f:
-            data = f.read()
-        return {"num_bytes": len(data)}, data
-
-    srv.register("fetch_partition", whole_file)
     srv.register_stream(
         "fetch_partition_stream",
         lambda p, b, send: dp.stream_partition(p["path"], p, send))
@@ -85,13 +79,29 @@ def stream_server(tmp_path):
     srv.stop()
 
 
-FAST = RetryPolicy(connect_timeout_s=2.0, read_timeout_s=20.0,
-                   base_backoff_s=0.01, max_backoff_s=0.02, jitter=0.0)
+# short deadlines and backoff, so a retried fetch costs milliseconds
+FAST = {"ballista.rpc.connect.timeout.seconds": "2.0",
+        "ballista.rpc.read.timeout.seconds": "20.0",
+        "ballista.rpc.retry.base.seconds": "0.01",
+        "ballista.rpc.retry.cap.seconds": "0.02",
+        "ballista.batch.size": "8192"}
+
+
+def _fetch(port, path, crc=-1, *, chunk_rows=None, compression=None):
+    """One fetch through the entry point every consumer calls."""
+    conf = dict(FAST)
+    if chunk_rows is not None:
+        conf["ballista.shuffle.wire.chunk_rows"] = str(chunk_rows)
+    if compression is not None:
+        conf["ballista.shuffle.wire.compression"] = compression
+    loc = PartitionLocation("producer-exec", 0, 0, path, host="127.0.0.1",
+                            port=port, checksum=crc)
+    return dp.fetch_partition(loc, SCHEMA, BallistaConfig(conf))
 
 
 # --------------------------------------------------------------------------
-# wire-format matrix: chunking x compression x legacy whole-file all decode
-# to the exact same logical table as a direct local read
+# wire-format matrix: chunking x compression all decode to the exact same
+# logical table as a direct local read
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("codec", ["lz4", "zstd", "none"])
@@ -100,10 +110,8 @@ def test_stream_matrix_bit_identical(partition, stream_server, codec,
                                      chunk_rows):
     path, nbytes, crc = partition
     baseline = _table_of(read_ipc_files([path], SCHEMA, capacity=8192))
-    batches, stats = dp.fetch_partition_stream(
-        "127.0.0.1", stream_server.port, path, SCHEMA, capacity=8192,
-        policy=FAST, expected_checksum=crc, chunk_rows=chunk_rows,
-        compression=codec)
+    batches, stats = _fetch(stream_server.port, path, crc,
+                            chunk_rows=chunk_rows, compression=codec)
     assert _table_of(batches).equals(baseline)
     assert stats["chunks"] == -(-N_ROWS // chunk_rows)
     assert stats["raw_bytes"] == nbytes
@@ -118,32 +126,96 @@ def test_stream_matrix_bit_identical(partition, stream_server, codec,
 def test_unknown_codec_degrades_to_uncompressed(partition, stream_server):
     path, nbytes, crc = partition
     baseline = _table_of(read_ipc_files([path], SCHEMA, capacity=8192))
-    batches, stats = dp.fetch_partition_stream(
-        "127.0.0.1", stream_server.port, path, SCHEMA, capacity=8192,
-        policy=FAST, expected_checksum=crc, compression="brotli-9000")
+    batches, stats = _fetch(stream_server.port, path, crc,
+                            compression="brotli-9000")
     assert stats["codec"] == "none"
     assert _table_of(batches).equals(baseline)
 
 
-def test_legacy_whole_file_bit_identical(partition, stream_server):
+# --------------------------------------------------------------------------
+# the same protocol through a real executor's handler: what it serves is the
+# file, and what it refuses (a path outside its work dir, a file that is not
+# there, a caller without the token) it refuses by name
+# --------------------------------------------------------------------------
+
+@pytest.fixture()
+def executor(tmp_path, monkeypatch):
+    """A real ExecutorServer listening on its one port (no scheduler: only
+    its RPC listener is started), with ``tmp_path`` as its work dir."""
+    from arrow_ballista_tpu.executor.server import ExecutorServer
+
+    monkeypatch.setenv("BALLISTA_DATA_PLANE_TOKEN", "sekrit")
+    srv = ExecutorServer("127.0.0.1", 1, port=0, work_dir=str(tmp_path))
+    srv.rpc.start()
+    assert srv.metadata.port == srv.rpc.port
+    yield srv
+    srv.stop(notify=False)
+
+
+def test_executor_serves_the_file_bit_identical(partition, executor):
+    path, nbytes, crc = partition
+    baseline = _table_of(read_ipc_files([path], SCHEMA, capacity=8192))
+    batches, stats = _fetch(executor.rpc.port, path, crc, chunk_rows=7_000)
+    assert _table_of(batches).equals(baseline)
+    assert stats["raw_bytes"] == nbytes and stats["chunks"] == 8
+
+
+@pytest.mark.parametrize("case", ["outside_work_dir", "missing_file"])
+def test_executor_refuses_by_name(partition, executor, tmp_path_factory,
+                                  case):
+    from arrow_ballista_tpu.net.wire import RemoteError
+
+    if case == "outside_work_dir":
+        path = str(tmp_path_factory.mktemp("elsewhere") / "data-0.arrow")
+        _write_partition(path, n=100)
+        want = "escapes the work dir"
+    else:
+        path = os.path.join(os.path.dirname(partition[0]), "data-9.arrow")
+        want = "no such shuffle file"
+    with pytest.raises(RemoteError, match=want) as err:
+        _fetch(executor.rpc.port, path)
+    assert path in str(err.value)
+
+
+def test_executor_wants_the_token(partition, executor, monkeypatch):
+    from arrow_ballista_tpu.net.wire import RemoteError
+
+    path, _, crc = partition
+    for token in ("wrong", ""):
+        monkeypatch.setenv("BALLISTA_DATA_PLANE_TOKEN", token)
+        with pytest.raises(RemoteError, match="auth failed"):
+            _fetch(executor.rpc.port, path, crc)
+    monkeypatch.setenv("BALLISTA_DATA_PLANE_TOKEN", "sekrit")
+    batches, _ = _fetch(executor.rpc.port, path, crc)
+    assert sum(b.num_rows for b in batches) == N_ROWS
+
+
+def test_executor_eight_concurrent_fetches_bit_identical(partition,
+                                                         executor):
+    from concurrent.futures import ThreadPoolExecutor
+
     path, _, crc = partition
     baseline = _table_of(read_ipc_files([path], SCHEMA, capacity=8192))
-    batches = dp.fetch_partition_batches(
-        "127.0.0.1", stream_server.port, path, SCHEMA, capacity=8192,
-        policy=FAST, expected_checksum=crc)
-    assert _table_of(batches).equals(baseline)
+    with ThreadPoolExecutor(8) as pool:
+        got = list(pool.map(
+            lambda _: _fetch(executor.rpc.port, path, crc, chunk_rows=7_000),
+            range(8)))
+    assert len(got) == 8
+    for batches, stats in got:
+        assert _table_of(batches).equals(baseline)
+        assert stats["resumed_chunks"] == 0
 
 
-def test_stream_unsupported_peer_raises(partition):
-    path, _, _ = partition
-    srv = RpcServer("127.0.0.1", 0)  # no stream handler registered
-    srv.start()
-    try:
-        with pytest.raises(dp.StreamUnsupported):
-            dp.fetch_partition_stream("127.0.0.1", srv.port, path, SCHEMA,
-                                      capacity=8192, policy=FAST, retries=1)
-    finally:
-        srv.stop()
+def test_stats_remote_bytes_grow_by_the_wire_bytes(partition, executor):
+    path, _, crc = partition
+    before = dp.STATS.snapshot()
+    _, stats = _fetch(executor.rpc.port, path, crc)
+    after = dp.STATS.snapshot()
+    assert stats["wire_bytes"] > 0
+    assert after["bytes_fetched"]["remote"] - \
+        before["bytes_fetched"]["remote"] == stats["wire_bytes"]
+    assert after["fetches"]["remote"] - before["fetches"]["remote"] == 1
+    assert after["wire_bytes"] - before["wire_bytes"] == stats["wire_bytes"]
 
 
 # --------------------------------------------------------------------------
@@ -160,9 +232,8 @@ def test_corrupt_chunk_resumes_without_refetching_verified_chunks(
         "site": "shuffle.fetch.recv", "action": "corrupt", "times": 1,
         "match": {"chunk": 3}}]})
     with faults.use_plan(plan):
-        batches, stats = dp.fetch_partition_stream(
-            "127.0.0.1", stream_server.port, path, SCHEMA, capacity=8192,
-            policy=FAST, expected_checksum=crc, chunk_rows=7_000)
+        batches, stats = _fetch(stream_server.port, path, crc,
+                                chunk_rows=7_000)
     assert plan.schedule() == (("shuffle.fetch.recv", 0, 1, "corrupt"),)
     assert _table_of(batches).equals(baseline)
     # the retry started at the corrupted chunk, keeping chunks 0-2
@@ -178,9 +249,8 @@ def test_dropped_chunk_resumes(partition, stream_server):
         "site": "shuffle.fetch.recv", "action": "drop", "times": 1,
         "match": {"chunk": 2}}]})
     with faults.use_plan(plan):
-        batches, stats = dp.fetch_partition_stream(
-            "127.0.0.1", stream_server.port, path, SCHEMA, capacity=8192,
-            policy=FAST, expected_checksum=crc, chunk_rows=7_000)
+        batches, stats = _fetch(stream_server.port, path, crc,
+                                chunk_rows=7_000)
     assert _table_of(batches).equals(baseline)
     assert stats["resumed_chunks"] == 2
 
@@ -197,13 +267,11 @@ def test_integrity_retries_immediately_connection_backs_off(
     sleeps = []
     monkeypatch.setattr(dp.time, "sleep", lambda s: sleeps.append(s))
 
-    # corrupt twice on the WHOLE-FILE path: two in-loop retries, no sleeps
+    # corrupt twice: two in-loop retries, no sleeps
     plan = faults.FaultPlan.from_obj({"rules": [{
         "site": "shuffle.fetch.recv", "action": "corrupt", "times": 2}]})
     with faults.use_plan(plan):
-        dp.fetch_partition_batches(
-            "127.0.0.1", stream_server.port, path, SCHEMA, capacity=8192,
-            policy=FAST, expected_checksum=crc)
+        _fetch(stream_server.port, path, crc)
     assert len(plan.events) == 2
     assert sleeps == [], "corrupt payloads must re-fetch without backoff"
 
@@ -211,9 +279,7 @@ def test_integrity_retries_immediately_connection_backs_off(
     plan = faults.FaultPlan.from_obj({"rules": [{
         "site": "shuffle.fetch.recv", "action": "drop", "times": 2}]})
     with faults.use_plan(plan):
-        dp.fetch_partition_batches(
-            "127.0.0.1", stream_server.port, path, SCHEMA, capacity=8192,
-            policy=FAST, expected_checksum=crc)
+        _fetch(stream_server.port, path, crc)
     assert len(sleeps) == 2, "connection failures must keep the backoff"
     assert all(s > 0 for s in sleeps)
 
@@ -236,9 +302,7 @@ def test_on_disk_corruption_fails_fast_without_refetch(tmp_path,
         "fetch_partition_stream",
         lambda p, b, send: (calls.append(1), orig(p["path"], p, send)))
     with pytest.raises(IntegrityError, match="corrupt"):
-        dp.fetch_partition_stream(
-            "127.0.0.1", stream_server.port, path, SCHEMA, capacity=8192,
-            policy=FAST, expected_checksum=crc)
+        _fetch(stream_server.port, path, crc)
     assert len(calls) == 1, "disk corruption must not be re-fetched"
 
 
@@ -246,14 +310,13 @@ def test_on_disk_corruption_fails_fast_without_refetch(tmp_path,
 # co-located mmap local path
 # --------------------------------------------------------------------------
 
-def _reader_for(path, crc, nbytes, *, host="node-a", port=1, grpc_port=0,
+def _reader_for(path, crc, nbytes, *, host="node-a", port=1,
                 conf=None, exec_host="node-a"):
     reader = ShuffleReaderExec(stage_id=1, schema=SCHEMA, partition_count=1,
                                locations={0: [PartitionLocation(
                                    "producer-exec", 0, 0, path,
                                    num_rows=N_ROWS, num_bytes=nbytes,
                                    host=host, port=port, checksum=crc,
-                                   grpc_port=grpc_port,
                                    format="arrow_file")]})
     ctx = TaskContext(config=BallistaConfig(conf or {}),
                       executor_id="consumer-exec", executor_host=exec_host)
@@ -281,10 +344,8 @@ def test_host_match_mmap_equals_wire_path(partition, stream_server):
     path, nbytes, crc = partition
     reader, ctx = _reader_for(path, crc, nbytes)
     via_mmap = _table_of(reader._execute(0, ctx))
-    via_wire, _ = dp.fetch_partition_stream(
-        "127.0.0.1", stream_server.port, path, SCHEMA,
-        capacity=ctx.config.batch_size, policy=FAST, expected_checksum=crc,
-        chunk_rows=7_000, compression="zstd")
+    via_wire, _ = _fetch(stream_server.port, path, crc, chunk_rows=7_000,
+                         compression="zstd")
     assert via_mmap.equals(_table_of(via_wire))
 
 
@@ -293,7 +354,6 @@ def test_host_mismatch_goes_remote(partition, stream_server):
     baseline = _table_of(read_ipc_files([path], SCHEMA, capacity=8192))
     reader, ctx = _reader_for(path, crc, nbytes, host="127.0.0.1",
                               port=stream_server.port,
-                              grpc_port=stream_server.port,
                               exec_host="node-a")
     got = _table_of(reader._execute(0, ctx))
     assert got.equals(baseline)
@@ -305,7 +365,7 @@ def test_host_match_disabled_goes_remote(partition, stream_server):
     path, nbytes, crc = partition
     reader, ctx = _reader_for(
         path, crc, nbytes, host="127.0.0.1", exec_host="127.0.0.1",
-        port=stream_server.port, grpc_port=stream_server.port,
+        port=stream_server.port,
         conf={"ballista.shuffle.local.host_match": "false"})
     reader._execute(0, ctx)
     assert reader.metrics().to_dict().get("remote_fetches") == 1
@@ -321,8 +381,7 @@ def test_stale_local_file_falls_back_to_remote(partition, stream_server,
     # wrong checksum recorded -> local CRC verify rejects the mmap
     reader, ctx = _reader_for(path, crc ^ 0x1, nbytes, host="127.0.0.1",
                               exec_host="127.0.0.1",
-                              port=stream_server.port,
-                              grpc_port=stream_server.port)
+                              port=stream_server.port)
     with pytest.raises(FetchFailedError):
         # remote verify also fails (the recorded CRC is simply wrong):
         # corruption is never silently accepted on ANY path
@@ -331,8 +390,7 @@ def test_stale_local_file_falls_back_to_remote(partition, stream_server,
     # -1 checksum) serves the real file
     reader, ctx = _reader_for(path, -1, nbytes + 1, host="127.0.0.1",
                               exec_host="127.0.0.1",
-                              port=stream_server.port,
-                              grpc_port=stream_server.port)
+                              port=stream_server.port)
     got = _table_of(reader._execute(0, ctx))
     assert got.equals(baseline)
     assert reader.metrics().to_dict().get("remote_fetches") == 1
@@ -372,8 +430,7 @@ def test_max_concurrent_fetches_config_bounds_fetches(tmp_path,
         paths.append((p, nbytes, crc))
     locs = [PartitionLocation("producer-exec", i, 0, p, num_rows=2_000,
                               num_bytes=nb, host="127.0.0.1",
-                              port=stream_server.port, checksum=c,
-                              grpc_port=stream_server.port)
+                              port=stream_server.port, checksum=c)
             for i, (p, nb, c) in enumerate(paths)]
     reader = ShuffleReaderExec(stage_id=1, schema=SCHEMA, partition_count=1,
                                locations={0: locs})
@@ -407,7 +464,7 @@ def test_max_concurrent_fetches_config_bounds_fetches(tmp_path,
 
 
 # --------------------------------------------------------------------------
-# serde: PartitionLocation wire tolerance across versions
+# serde: PartitionLocation round trip
 # --------------------------------------------------------------------------
 
 def test_location_serde_round_trip_and_tolerance():
@@ -415,20 +472,14 @@ def test_location_serde_round_trip_and_tolerance():
 
     loc = PartitionLocation("e1", 2, 3, "/w/j/1/2/data-3.arrow",
                             num_rows=10, num_bytes=999, host="node-a",
-                            port=50051, checksum=123, grpc_port=50052,
-                            format="arrow_file")
+                            port=50051, checksum=123, format="arrow_file")
     obj = serde.location_to_obj(loc)
-    assert obj["grpc_port"] == 50052 and obj["format"] == "arrow_file"
+    assert obj["port"] == 50051 and obj["format"] == "arrow_file"
     assert serde.location_from_obj(obj) == loc
-    # a NEWER peer's unknown field is dropped, not fatal
+    # a key this build does not know (a job persisted by another build of
+    # the scheduler) is dropped, not fatal
     obj["hypothetical_v9_field"] = {"x": 1}
     assert serde.location_from_obj(obj) == loc
-    # an OLDER peer's dict (pre-streaming) takes defaults
-    old = {"executor_id": "e1", "map_partition": 0, "output_partition": 1,
-           "path": "/p", "num_rows": 5, "num_bytes": 50, "host": "h",
-           "port": 7, "checksum": -1}
-    got = serde.location_from_obj(old)
-    assert got.grpc_port == 0 and got.format == ""
 
 
 # --------------------------------------------------------------------------
@@ -511,3 +562,109 @@ def test_cluster_host_match_uses_mmap_path_and_matches_remote(tmp_path):
     pd.testing.assert_frame_equal(on_df.reset_index(drop=True),
                                   off_df.reset_index(drop=True),
                                   check_dtype=False)
+
+
+# --------------------------------------------------------------------------
+# one entry point: the shuffle reader, the remote client's result collection
+# and the Flight SQL result path all reach the network through
+# net.dataplane.fetch_partition
+# --------------------------------------------------------------------------
+
+@pytest.fixture()
+def flight_cluster(tmp_path):
+    """Scheduler with its Flight SQL door + one executor, table ``t``."""
+    from arrow_ballista_tpu.catalog import MemoryTable
+    from arrow_ballista_tpu.executor.server import ExecutorServer
+    from arrow_ballista_tpu.scheduler.netservice import SchedulerNetService
+
+    conf = {"ballista.shuffle.partitions": "2"}
+    sched = SchedulerNetService("127.0.0.1", 0, config=BallistaConfig(conf),
+                                flight_port=0)
+    sched.start()
+    ex = ExecutorServer("127.0.0.1", sched.port, "127.0.0.1", 0,
+                        work_dir=str(tmp_path / "exec"), concurrent_tasks=2,
+                        executor_id="one-port-exec",
+                        config=BallistaConfig(conf))
+    ex.start()
+    rng = np.random.default_rng(41)
+    sched.catalog.register(MemoryTable("t", pa.table({
+        "g": pa.array(rng.integers(0, 50, 5_000).astype(np.int64)),
+        "v": pa.array(rng.integers(0, 100, 5_000).astype(np.int64))})))
+    yield sched
+    ex.stop(notify=False)
+    sched.stop()
+
+
+class _NoSharedDisk:
+    """``os`` as a scheduler sees it whose disk holds none of the
+    executors' files: every other name is the real module's."""
+
+    class path:
+        exists = staticmethod(lambda p: False)
+
+    def __getattr__(self, name):
+        return getattr(os, name)
+
+
+def _flight_sql(sched, sql):
+    import pyarrow.flight as fl
+
+    client = fl.connect(f"grpc://127.0.0.1:{sched.flight.port}")
+    try:
+        info = client.get_flight_info(
+            fl.FlightDescriptor.for_command(sql.encode()))
+        return client.do_get(info.endpoints[0].ticket).read_all()
+    finally:
+        client.close()
+
+
+def test_flight_sql_fetches_a_result_that_is_not_on_its_disk(
+        flight_cluster, monkeypatch):
+    from arrow_ballista_tpu.scheduler import flight_service
+
+    local = _flight_sql(flight_cluster, SQL)
+    before = dp.STATS.snapshot()
+    monkeypatch.setattr(flight_service, "os", _NoSharedDisk())
+    remote = _flight_sql(flight_cluster, SQL)
+    after = dp.STATS.snapshot()
+    assert after["fetches"]["remote"] > before["fetches"]["remote"]
+    assert after["chunks"] > before["chunks"]
+    assert remote.num_rows == 50 and remote.equals(local)
+
+
+@pytest.mark.parametrize("consumer", ["shuffle_reader", "remote_client",
+                                      "flight_sql"])
+def test_every_consumer_fetches_through_the_one_entry_point(
+        partition, stream_server, flight_cluster, monkeypatch, consumer):
+    calls = []
+    real = dp.fetch_partition
+
+    def counted(loc, schema, config, fault_ctx=None):
+        calls.append((loc.host, loc.port, loc.path))
+        return real(loc, schema, config, fault_ctx)
+
+    monkeypatch.setattr(dp, "fetch_partition", counted)
+    if consumer == "shuffle_reader":
+        path, nbytes, crc = partition
+        reader, ctx = _reader_for(path, crc, nbytes, host="127.0.0.1",
+                                  port=stream_server.port)
+        assert sum(b.num_rows for b in reader._execute(0, ctx)) == N_ROWS
+        assert calls == [("127.0.0.1", stream_server.port, path)]
+        return
+    executor_port = flight_cluster.server.cluster.get_executor(
+        "one-port-exec").port
+    if consumer == "remote_client":
+        from arrow_ballista_tpu.client.context import BallistaContext
+
+        c = BallistaContext.remote("127.0.0.1", flight_cluster.port)
+        try:
+            assert len(c.sql(SQL).to_pandas()) == 50
+        finally:
+            c.shutdown()
+    else:
+        from arrow_ballista_tpu.scheduler import flight_service
+
+        monkeypatch.setattr(flight_service, "os", _NoSharedDisk())
+        assert _flight_sql(flight_cluster, SQL).num_rows == 50
+    assert calls, f"{consumer} did not reach net.dataplane.fetch_partition"
+    assert {(h, p) for h, p, _ in calls} == {("127.0.0.1", executor_port)}
