@@ -327,6 +327,8 @@ class FusedStageExec(ExecutionPlan):
             domain = K.dense_domain(key_ranges)
             if domain is not None:
                 out_cap = min(out_cap, domain)
+                if K.i64_sum_path(domain + 1, big.capacity) == "contraction":
+                    self.metrics().add("mxu_grouped_sums", 1)
             # read host-side facts BEFORE the call: the donated column and
             # mask buffers are dead after it, so nothing below may touch
             # the input batch (donation-safety analyzer enforces this)

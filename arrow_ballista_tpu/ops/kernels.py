@@ -372,8 +372,8 @@ def _grouped_aggregate_on_order(
     # a 64-bit scatter measured 1-18M rows/s, and the first alternative
     # tried (sorted-run cumsum differences) turned out to COMPILE for 44 s
     # per shape on this backend, which per-job recompiles turned into a
-    # regression.  The limb programs compile in ~1-2 s and run at memory
-    # speed.
+    # regression.  The limb programs compile in ~1-2 s (what they take to
+    # run: the comment above i64_sum_path).
     i64_positions: List[int] = []
     i64_vals: List[jnp.ndarray] = []
     out_vals: List[Optional[jnp.ndarray]] = []
@@ -438,16 +438,27 @@ def _grouped_aggregate_on_order(
 # XLA's TPU scatter-add is the segment_sum lowering, and with x64 emulation
 # an int64 segment_sum measured 18M rows/s — and the realistic multi-
 # aggregate shape (8 int64 sums over one segment id vector, TPC-H q1's
-# stage) collapsed to 1M rows/s, which made the aggregate the engine's
-# dominant device cost.  int32 segment ops run ~200M rows/s and int32
-# one-hot matmuls ride the MXU at effectively memory speed, so int64
-# reductions decompose into exact 16-bit limbs:
+# stage) collapsed to 1M rows/s.  So int64 reductions decompose into exact
+# limbs (i64_sum_path says which way a call goes):
 #
-# - sums: limb rows x one-hot(segment) matmul per row-chunk (chunk bound
-#   keeps per-chunk limb sums inside int32), summed over the chunks and
-#   recombined in 32-bit arithmetic alone (_recombine_chunk_limbs) —
-#   measured ~1000x the segment_sum x8 shape; falls back to chunk-offset
-#   int32 segment_sums when the segment count makes one-hot tiles too large.
+# - sums into at most _MATMUL_SEG_LIMIT slots ("contraction"): per chunk of
+#   rows ONE contraction on the matrix unit, in a type the unit has — the
+#   eight 8-bit limbs of every distinct value vector and one row of ones
+#   (the rows per slot: every count, and dense_group_states' exists_cnt) as
+#   bfloat16 against the chunk's one-hot(segment) as bfloat16, accumulated
+#   in float32.  Products of integers under 2^8 and partial sums under 2^23
+#   are exact in float32 in any order.  The per-chunk sums are recombined in
+#   32-bit arithmetic alone (_recombine_chunk_limbs).  On one v5e, five
+#   sums and four counts of 2^23 rows into 290 slots (q1's shape): 8.6 ms,
+#   about 1 ns a row and some twenty times the HBM bound; the cost is the
+#   making of the limb rows, not the multiply-adds (290 slots or 13, bf16
+#   or int8 alike).  The form before it — 16-bit limbs in int32 through an
+#   int32 dot, counts as four more value vectors, rows per slot by an int32
+#   segment_sum — took 85-97 ms, 73 of them the segment_sum (PR 31's
+#   micro, PERF.md section 6).
+# - sums into more slots ("chunk_offset"): chunk-offset int32 segment_sums
+#   of 16-bit limbs, recombined the same way; past the sizes that holds,
+#   the plain int64 segment_sum ("scatter").
 # - min/max: lexicographic two-pass over (hi32, lo32-with-flipped-sign)
 #   int32 segment_min/max; identity values recombine to exactly the int64
 #   idents, so empty slots stay mergeable (mesh pmin/pmax).
@@ -462,11 +473,46 @@ def _tpu_backend() -> bool:
 
 
 _MATMUL_SEG_LIMIT = 1024  # one-hot matmul while chunk x segments tiles fit
-_SEG_CHUNK = 1 << 15      # max rows/chunk: 2^15 rows x 16-bit limbs < 2^31
+# max rows/chunk.  Chunk-offset path: 2^15 rows x 16-bit limbs < 2^31.
+# Contraction: 2^15 rows x 8-bit limbs < 2^23, exact in float32, and a pair
+# of limb sums put together, lo + (hi << 8) < 2^23 + 2^31, fits uint32
+_SEG_CHUNK = 1 << 15
 # chunk-offset path ceiling on C*(S+1): keeps the per-limb scratch buffer
 # <= 512 MB int32 AND far from the int32 id wrap at 2^31 (advisor r4:
 # wrapped ids silently dropped rows -> wrong aggregates with no error)
 _CHUNK_OFFSET_LIMIT = 1 << 27
+# chunks the contraction's scan takes an iteration: 11.3 ms unrolled by 1,
+# 9.2 by 2, 8.7 by 4, 8.4 by 8 at q1's shape (PR 31's micro)
+_SCAN_UNROLL = 4
+# chunks a call's rows may make: the 16-bit halves of 2^15 chunk sums add
+# up to less than 2^31 and every digit of the carry chain stays under 2^32
+_MAX_CHUNKS = 1 << 15
+
+
+def i64_sum_path(num_segments: int, n: int) -> str:
+    """The way the int64 sums (and counts) of ``n`` rows into
+    ``num_segments`` slots go, from what is static: ``"contraction"`` (the
+    matrix unit), ``"chunk_offset"`` (int32 limb segment_sums) or
+    ``"scatter"`` (plain segment ops: the CPU backend, and sizes past what
+    the 32-bit recombination or the chunk-offset ids hold).  The single
+    authority: the kernels branch on it and the operators count
+    ``mxu_grouped_sums`` by it."""
+    if not _tpu_backend():
+        return "scatter"
+    n_chunks = -(-n // _SEG_CHUNK)
+    if n_chunks > _MAX_CHUNKS:
+        # 2^30 rows in one call: past what the 32-bit recombination holds
+        return "scatter"
+    if num_segments <= _MATMUL_SEG_LIMIT:
+        return "contraction"
+    if n_chunks * (num_segments + 1) > _CHUNK_OFFSET_LIMIT:
+        # ids = seg + chunk_index*S1 wraps int32 past 2^31 — XLA would then
+        # silently DROP the wrapped rows — and the C*S1 scratch buffer per
+        # limb reaches multiple GB well before the wrap point: the plain
+        # int64 segment_sum (a slow 64-bit scatter, but exact) rather than
+        # ever risking silent wrong aggregates
+        return "scatter"
+    return "chunk_offset"
 
 
 def _i64_limbs(v: jnp.ndarray) -> List[jnp.ndarray]:
@@ -477,10 +523,21 @@ def _i64_limbs(v: jnp.ndarray) -> List[jnp.ndarray]:
             for i in range(4)]
 
 
+def _i64_limb_rows(vals: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    """int64[k] per value -> bfloat16[8 x values, k]: each value's eight
+    8-bit limbs (integers in [0, 255]: exact in bfloat16), low limb first —
+    the bytes of its two's complement, read off by a bitcast: no 64-bit
+    shift is left to the chip's emulation, and one op makes every row."""
+    b = jax.lax.bitcast_convert_type(jnp.stack(vals), jnp.uint8)  # [V, k, 8]
+    # by way of int32: 4 % quicker on the v5e than uint8 straight to bfloat16
+    rows = jnp.moveaxis(b, -1, 1).astype(jnp.int32).astype(jnp.bfloat16)
+    return rows.reshape(-1, rows.shape[-1])
+
+
 def _recombine_chunk_limbs(parts: jnp.ndarray) -> jnp.ndarray:
-    """parts: int32[C, 4, ...], per chunk the sums (each in [0, 2^31)) of
-    the four 16-bit limbs -> int64[...], the sum over the chunks recombined
-    exactly mod 2^64.
+    """parts: int32[C, 4, ...], per chunk the sums (each in [0, 2^31 +
+    2^23)) of the four 16-bit limbs -> int64[...], the sum over the chunks
+    recombined exactly mod 2^64.
 
     In 32-bit arithmetic alone, and the 64-bit value only assembled from
     its two words by a bitcast: the chip's emulated 64-bit addition is not
@@ -509,9 +566,60 @@ def _recombine_chunk_limbs(parts: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.bitcast_convert_type(words, jnp.int64)
 
 
-# chunks a call's rows may make: the 16-bit halves of 2^15 chunk sums add
-# up to less than 2^31 and every digit of the carry chain stays under 2^32
-_MAX_CHUNKS = 1 << 15
+def _recombine_chunk_limbs8(parts: jnp.ndarray) -> jnp.ndarray:
+    """parts: int32[C, 8, ...], per chunk the sums (each in [0, 2^23]: at
+    most _SEG_CHUNK rows of an 8-bit limb) of the eight 8-bit limbs ->
+    int64[...], as _recombine_chunk_limbs.  Each chunk's pair of limb sums
+    makes one 16-bit limb's sum, lo + (hi << 8) < 2^23 + 2^31 in uint32."""
+    p = parts.astype(jnp.uint32)
+    return _recombine_chunk_limbs(p[:, 0::2] + (p[:, 1::2] << jnp.uint32(8)))
+
+
+def _contracted_sums_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
+                         num_segments: int):
+    """The "contraction" of i64_sum_path: (sums: int64[num_segments] per
+    value, rows: int32[num_segments], the rows whose ``seg`` is the slot).
+    A value vector given twice (the same array) is contracted once."""
+    n = seg.shape[0]
+    S = num_segments
+    chunk = min(_SEG_CHUNK, n)
+    pad = (-n) % chunk
+    slot_of, distinct = {}, []
+    for v in vals:
+        if id(v) not in slot_of:
+            slot_of[id(v)] = len(distinct)
+            distinct.append(v)
+    if pad:
+        # padded rows: seg == S matches no one-hot column -> contribute 0
+        seg = jnp.concatenate([seg, jnp.full(pad, S, seg.dtype)])
+        distinct = [jnp.concatenate([v, jnp.zeros(pad, v.dtype)])
+                    for v in distinct]
+    iota_s = jnp.arange(S, dtype=jnp.int32)
+
+    # carry-free scan (stacked per-chunk partials, summed after): a
+    # zeros-initialized carry has no varying manual axes and trips
+    # shard_map's vma check when this runs inside a mesh program
+    def body(_, xs):
+        vc, sc = xs
+        rows = jnp.ones_like(sc, dtype=jnp.bfloat16)[None]
+        if vc:
+            rows = jnp.concatenate([_i64_limb_rows(vc), rows])
+        oh = (sc[:, None] == iota_s[None, :]).astype(jnp.bfloat16)
+        return None, jax.lax.dot_general(
+            rows, oh, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _, parts = jax.lax.scan(
+        body, None,
+        (tuple(v.reshape(-1, chunk) for v in distinct),
+         seg.reshape(-1, chunk)), unroll=_SCAN_UNROLL)
+    parts = parts.astype(jnp.int32)   # [chunks, 8 x values + 1, S]: exact
+    C, V = parts.shape[0], len(distinct)
+    rows = jnp.sum(parts[:, 8 * V], axis=0, dtype=jnp.int32)
+    # [chunks, values, limbs, S] -> [chunks, limbs, values, S]
+    sums = _recombine_chunk_limbs8(
+        parts[:, :8 * V].reshape(C, V, 8, S).transpose(0, 2, 1, 3))
+    return [sums[slot_of[id(v)]] for v in vals], rows
 
 
 def grouped_sums_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
@@ -520,55 +628,17 @@ def grouped_sums_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
     already be 0).  ``seg`` is int32 in [0, num_segments); rows may also
     carry seg == num_segments-1 as a dump slot — this computes all slots
     and the caller slices."""
-    if not _tpu_backend():
-        return [jax.ops.segment_sum(v, seg, num_segments=num_segments)
-                for v in vals]
     n = seg.shape[0]
     S = num_segments
-    if -(-n // _SEG_CHUNK) > _MAX_CHUNKS:
-        # 2^30 rows in one call: past what the 32-bit recombination holds
+    path = i64_sum_path(S, n)
+    if path == "scatter":
         return [jax.ops.segment_sum(v, seg, num_segments=S) for v in vals]
-    if S <= _MATMUL_SEG_LIMIT:
-        chunk = min(_SEG_CHUNK, n)
-        pad = (-n) % chunk
-        if pad:
-            # padded rows: seg == S matches no one-hot column -> contribute 0
-            seg = jnp.concatenate([seg, jnp.full(pad, S, seg.dtype)])
-        segc = seg.reshape(-1, chunk)
-        rows = []
-        for v in vals:
-            if pad:
-                v = jnp.concatenate([v, jnp.zeros(pad, v.dtype)])
-            rows.extend(_i64_limbs(v))
-        lhs = jnp.stack(rows).reshape(len(rows), -1, chunk).transpose(1, 0, 2)
-        iota_s = jnp.arange(S, dtype=jnp.int32)
-
-        # carry-free scan (stacked per-chunk partials, summed after): a
-        # zeros-initialized carry has no varying manual axes and trips
-        # shard_map's vma check when this runs inside a mesh program
-        def body(_, xs):
-            l, sc = xs
-            oh = (sc[:, None] == iota_s[None, :]).astype(jnp.int32)
-            return None, jax.lax.dot_general(l, oh, (((1,), (0,)), ((), ())))
-
-        _, parts = jax.lax.scan(body, None, (lhs, segc))
-        # [chunks, values x limbs, S] -> [chunks, limbs, values, S]
-        return list(_recombine_chunk_limbs(
-            parts.reshape(parts.shape[0], len(vals), 4, S).transpose(
-                0, 2, 1, 3)))
+    if path == "contraction":
+        return _contracted_sums_i64(vals, seg, S)[0]
     # large segment count: chunk-offset int32 segment_sums per limb (per
     # chunk x segment a limb sum stays < 2^31), recombined as above
     chunk = min(_SEG_CHUNK, n)
     S1 = S + 1  # one scratch slot for padded rows
-    n_chunks = -(-n // chunk)
-    if n_chunks * S1 > _CHUNK_OFFSET_LIMIT:
-        # ids = seg + chunk_index*S1 wraps int32 past 2^31 — XLA would then
-        # silently DROP the wrapped rows — and the C*S1 scratch buffer per
-        # limb reaches multiple GB well before the wrap point.  All inputs
-        # to this check are static shapes, so the guard costs nothing: fall
-        # back to the plain int64 segment_sum (a slow 64-bit scatter, but
-        # exact) rather than ever risking silent wrong aggregates.
-        return [jax.ops.segment_sum(v, seg, num_segments=S) for v in vals]
     pad = (-n) % chunk
     if pad:
         seg = jnp.concatenate([seg, jnp.full(pad, S, seg.dtype)])
@@ -583,6 +653,18 @@ def grouped_sums_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
                  .reshape(C, S1) for limb in _i64_limbs(v)]
         out.append(_recombine_chunk_limbs(jnp.stack(parts, axis=1))[:S])
     return out
+
+
+def grouped_sums_and_rows_i64(vals: List[jnp.ndarray], seg: jnp.ndarray,
+                              num_segments: int):
+    """``grouped_sums_i64`` and the rows per slot (int32[num_segments],
+    rows in the dump slot counted there): on the contraction path both come
+    out of the one contraction and the program holds no scatter."""
+    if i64_sum_path(num_segments, seg.shape[0]) == "contraction":
+        return _contracted_sums_i64(vals, seg, num_segments)
+    rows = jax.ops.segment_sum(jnp.ones(seg.shape, jnp.int32), seg,
+                               num_segments=num_segments)
+    return grouped_sums_i64(vals, seg, num_segments), rows
 
 
 # numpy scalars: a jnp constant here would start a backend at import, in
@@ -655,23 +737,23 @@ def dense_group_states(
     bad_rows = jnp.any(mask & ~in_range)
     seg = jnp.where(in_range, fused, domain).astype(jnp.int32)
 
-    exists_cnt = jax.ops.segment_sum(
-        jnp.where(in_range, 1, 0).astype(jnp.int32), seg,
-        num_segments=domain + 1)[:domain]
-
-    # int64 sums/counts batch through the limb path (one fused program for
-    # every aggregate — the TPU-fast formulation, see grouped_sums_i64)
+    # int64 sums batch through the limb path, and the rows per slot — every
+    # count and exists_cnt — come with them (one program for every
+    # aggregate: the TPU-fast formulation, see grouped_sums_and_rows_i64)
     i64_sums: List[Tuple[int, jnp.ndarray]] = []
+    counts: List[int] = []
+    masked: Dict[int, jnp.ndarray] = {}
     dense_vals: List[Optional[jnp.ndarray]] = []
     for arr, how in val_cols:
         if how == AGG_COUNT:
-            i64_sums.append((len(dense_vals),
-                             jnp.where(in_range, 1, 0).astype(jnp.int64)))
+            counts.append(len(dense_vals))
             dense_vals.append(None)
         elif how == AGG_SUM and arr.dtype == jnp.int64:
-            i64_sums.append((len(dense_vals),
-                             jnp.where(in_range, arr,
-                                       jnp.zeros((), arr.dtype))))
+            # one column summed twice is one masked vector, contracted once
+            if id(arr) not in masked:
+                masked[id(arr)] = jnp.where(in_range, arr,
+                                            jnp.zeros((), arr.dtype))
+            i64_sums.append((len(dense_vals), masked[id(arr)]))
             dense_vals.append(None)
         elif how == AGG_SUM:
             v = jax.ops.segment_sum(
@@ -693,10 +775,14 @@ def dense_group_states(
             dense_vals.append(v)
         else:
             raise ValueError(f"unknown agg {how}")
-    if i64_sums:
-        sums = grouped_sums_i64([v for _, v in i64_sums], seg, domain + 1)
-        for (pos, _), s in zip(i64_sums, sums):
-            dense_vals[pos] = s[:domain]
+    # rows outside the ranges sit in slot ``domain`` and are sliced away
+    sums, rows = grouped_sums_and_rows_i64([v for _, v in i64_sums], seg,
+                                           domain + 1)
+    exists_cnt = rows[:domain]
+    for (pos, _), s in zip(i64_sums, sums):
+        dense_vals[pos] = s[:domain]
+    for pos in counts:
+        dense_vals[pos] = exists_cnt.astype(jnp.int64)
     return dense_vals, exists_cnt, bad_rows
 
 

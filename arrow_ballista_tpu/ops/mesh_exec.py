@@ -328,7 +328,7 @@ class MeshAggregateExec(ExecutionPlan):
         from ..parallel.distributed import (distributed_dense_aggregate,
                                             distributed_filter_aggregate)
         from ..parallel.mesh import make_mesh
-        from .kernels import dense_domain
+        from .kernels import dense_domain, i64_sum_path
 
         assert partition == 0
         in_schema = self.input.schema
@@ -385,6 +385,8 @@ class MeshAggregateExec(ExecutionPlan):
                     "mesh dense aggregation saw keys outside their declared "
                     "ranges (dictionary/batch mismatch)")
             self.metrics().add("dense_reduce_collective", 1)
+            if i64_sum_path(domain + 1, padded // n_dev) == "contraction":
+                self.metrics().add("mxu_grouped_sums", 1)
         else:
             prog = _program(
                 self, ("mesh_agg_exchange", n_dev, key_ranges, partial_cap,

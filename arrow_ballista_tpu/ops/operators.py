@@ -957,6 +957,13 @@ class HashAggregateExec(ExecutionPlan):
             # dense domain bounds distinct groups exactly: don't allocate
             # (or device->host transfer) a 64k-row output for 12 groups
             out_cap = min(out_cap, domain)
+            # its sums, counts and rows per slot are one contraction on
+            # the matrix unit where the kernel says so.  (The sort path's
+            # capacities start at 1024 rows, a slot past what the
+            # contraction takes: only a dense domain reaches it from here.)
+            if not self._presorted() and K.i64_sum_path(
+                    domain + 1, big.capacity) == "contraction":
+                self.metrics().add("mxu_grouped_sums", 1)
         disorder = None
         with self.metrics().timer("agg_time"):
             aux = comp.aux_arrays(big.dicts)

@@ -11,7 +11,8 @@ the parent exit non-zero; nothing is caught and carried past.
 - ``data``: TPC-H at ``--scale`` (default 1) from ``--seed`` (default 0),
   all eight tables, written as parquet under ``.bench_data/``.  CPU only.
 - ``standalone`` (holds the chip): device facts, the budgets the program
-  resolved for itself, the platform's transfer constants, then
+  resolved for itself, the platform's transfer constants, a sweep of random
+  and edge operands through the int64 grouped-sum kernels against numpy, then
   ``BallistaContext.standalone`` over the parquet: q1, q6, q3, q18, each
   cold and then warm, every answer compared with a pandas oracle.
 - ``cluster``: scheduler daemon, executor daemon (holds the chip) and a
@@ -325,6 +326,89 @@ def _platform_constants() -> dict:
             "d2h_scalar_fixed_ms": d2h_scalar * 1e3}
 
 
+def _int64_sum_sweep(seed: int) -> dict:
+    """Random and edge operands through ``kernels.grouped_sums_i64`` on each
+    of its paths and through ``dense_group_states``, on this device, against
+    numpy's wrapping int64: the chip's emulated 64-bit arithmetic is what
+    the kernel avoids, and this is where a compiler that gets it wrong
+    shows (PR 28 met one).  Raises on the first difference."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from arrow_ballista_tpu.ops import kernels as K
+
+    rng = np.random.default_rng(seed)
+    i64 = np.iinfo(np.int64)
+
+    def operands(n):
+        full = rng.integers(i64.min, i64.max, n, dtype=np.int64,
+                            endpoint=True)
+        full[:4] = [i64.min, i64.max, i64.min, -1][:n]
+        price = rng.integers(90_000, 10_500_000, n, dtype=np.int64)
+        return [full, -rng.integers(1, 2**62, n, dtype=np.int64),
+                price * rng.integers(90, 101, n) * rng.integers(100, 109, n),
+                np.ones(n, np.int64), np.zeros(n, np.int64)]
+
+    def plain(vals, seg, S):
+        out = []
+        with np.errstate(over="ignore"):
+            for v in vals:
+                acc = np.zeros(S, np.int64)
+                np.add.at(acc, seg, v)
+                out.append(acc)
+        return out
+
+    paths = {}
+    shapes = [(1, 1000), (13, (1 << 15) + 7), (290, 1 << 20),
+              (290, (1 << 22) + 12345), (1024, 1 << 18),
+              (1025, 1 << 18), (50_000, 1 << 20)]
+    for S, n in shapes:
+        live = rng.random(n) < 0.9
+        seg = np.where(live, rng.integers(0, max(S - 1, 1), n),
+                       S - 1).astype(np.int32)
+        vals = [np.where(live, v, 0) for v in operands(n)]
+        path = K.i64_sum_path(S, n)
+        paths[path] = paths.get(path, 0) + 1
+        sums, rows = jax.jit(
+            lambda vals, seg, S=S: K.grouped_sums_and_rows_i64(vals, seg, S)
+        )([jnp.asarray(v) for v in vals], jnp.asarray(seg))
+        for i, (got, want) in enumerate(zip(sums, plain(vals, seg, S))):
+            if not np.array_equal(np.asarray(got), want):
+                bad = np.flatnonzero(np.asarray(got) != want)[:3]
+                raise SystemExit(
+                    f"grouped_sums_i64 ({path}) S={S} n={n} value {i}: slots "
+                    f"{bad.tolist()} read {np.asarray(got)[bad].tolist()}, "
+                    f"numpy {want[bad].tolist()}")
+        if not np.array_equal(np.asarray(rows), np.bincount(seg, minlength=S)):
+            raise SystemExit(f"rows per slot ({path}) S={S} n={n} differ")
+
+    # q1's shape of dense_group_states: 17 x 17 slots, sums and counts
+    n = (1 << 21) + 77
+    key_ranges = ((-1, 15), (-1, 15))
+    domain = K.dense_domain(key_ranges)
+    k0 = rng.integers(-1, 3, n).astype(np.int32)
+    k1 = rng.integers(-1, 2, n).astype(np.int32)
+    mask = rng.random(n) < 0.97
+    vals = operands(n)[:3]
+    hows = [K.AGG_SUM, K.AGG_COUNT, K.AGG_SUM, K.AGG_SUM, K.AGG_COUNT]
+    cols = [vals[0], vals[0], vals[1], vals[2], vals[2]]
+    dense, exists, bad = jax.jit(
+        lambda k0, k1, mask, *cols: K.dense_group_states(
+            [k0, k1], list(zip(cols, hows)), mask, key_ranges, domain)
+    )(k0, k1, mask, *cols)
+    slot = np.where(mask, (k0 + 1) * 17 + (k1 + 1), domain)
+    count = np.bincount(slot, minlength=domain + 1)[:domain]
+    if bool(bad) or not np.array_equal(np.asarray(exists), count):
+        raise SystemExit("dense_group_states: exists_cnt differs from numpy")
+    for got, col, how in zip(dense, cols, hows):
+        want = count if how == K.AGG_COUNT else \
+            plain([np.where(mask, col, 0)], slot, domain + 1)[0][:domain]
+        if not np.array_equal(np.asarray(got), want):
+            raise SystemExit(f"dense_group_states: a {how} differs from numpy")
+    return {"shapes": len(shapes) + 1, "paths": paths}
+
+
 def _run_queries(ctx, queries, oracles, runs, label: str) -> None:
     """Run each query ``runs`` times through ``ctx``, print seconds and the
     device accounting of each run, compare every answer with the oracle."""
@@ -393,6 +477,7 @@ def child_standalone(args) -> None:
         resolve_pool_budget,
         resolve_task_budget,
     )
+    from benchmarks.queries import QUERIES
     from benchmarks.tpch import register_tables
 
     config = BallistaConfig(dict(BASE_CONFIG))
@@ -407,6 +492,8 @@ def child_standalone(args) -> None:
               SCAN_CACHE_BYTES: table_cache.resolve_budget(
                   config.get(SCAN_CACHE_BYTES))}})
     emit({"phase": "standalone", "platform_constants": _platform_constants()})
+    emit({"phase": "standalone",
+          "int64_sum_sweep": _int64_sum_sweep(args.seed)})
     ddir = data_dir(args)
     oracles = _oracles(ddir, STANDALONE_QUERIES)
     ctx = BallistaContext.standalone(config, concurrent_tasks=4,
@@ -415,6 +502,19 @@ def child_standalone(args) -> None:
         register_tables(ctx, ddir)
         _run_queries(ctx, STANDALONE_QUERIES, oracles, ("cold", "warm"),
                      "standalone")
+        # q1's dense-domain aggregates reduce on the matrix unit, one
+        # contraction a kernel call; q6 has no keys and none
+        for q in (1, 6):
+            report = ctx.explain_analyze(QUERIES[q])
+            counted = {f"{stage['stage_id']}:{op['op']}":
+                       op["metrics"]["mxu_grouped_sums"]
+                       for stage in report["stages"]
+                       for op in stage["operator_tree"]
+                       if op["metrics"].get("mxu_grouped_sums")}
+            emit({"phase": "standalone", "query": f"q{q}",
+                  "mxu_grouped_sums": counted})
+            if info["platform"] == "tpu" and bool(counted) != (q == 1):
+                raise SystemExit(f"q{q}: mxu_grouped_sums {counted}")
     finally:
         ctx.shutdown()
     emit({"phase": "standalone", "ok": True, "device": info})

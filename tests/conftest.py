@@ -82,3 +82,17 @@ def pytest_runtest_protocol(item, nextitem):
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    """Code that asks which backend it runs on still sees the CPU here:
+    steer the kernels (``ops/kernels.py`` ``_tpu_backend``) onto their TPU
+    branches for one test."""
+    from arrow_ballista_tpu.ops import kernels as K
+
+    K._tpu_backend.cache_clear()
+    monkeypatch.setattr(K, "_tpu_backend", lambda: True)
+    yield
+    monkeypatch.undo()
+    K._tpu_backend.cache_clear()

@@ -336,6 +336,22 @@ def test_agg_chain_fused_matches_interpreted():
         assert _rows(got[p]) == _rows(interpreted[p])
 
 
+def test_fused_agg_counts_its_contractions(tpu_branches):
+    """A fused stage headed by an aggregate counts ``mxu_grouped_sums`` as
+    the aggregate alone does: one a kernel call on the TPU branch."""
+    ctx = _ctx()
+    scan = _scan(n=1000, partitions=2)
+    filt = O.FilterExec(scan, E.BinOp(">", E.Column("x"), E.Lit(101)))
+    agg = O.HashAggregateExec(     # a bool key: a dense domain of two
+        filt, [(E.BinOp(">", E.Column("y"), E.Lit(3)), "big")],
+        [O.AggSpec("sum", E.Column("x"), "sx"),
+         O.AggSpec("count", E.Column("x"), "n")], "partial")
+    fused = FusedStageExec([agg, filt])
+    for p in range(2):
+        fused.execute(p, ctx)
+    assert fused.metrics().to_dict()["mxu_grouped_sums"] == 2
+
+
 def test_runtime_fallback_latches_to_interpreted():
     # unique literals: a fresh fingerprint so the shared-program cache
     # cannot satisfy this chain (the broken _build below must be reached)

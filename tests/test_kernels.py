@@ -296,6 +296,165 @@ def test_i64_limb_reductions_match_plain_paths(monkeypatch):
 
 
 # --------------------------------------------------------------------------
+# int64 sums into a small domain: one contraction on the matrix unit
+# --------------------------------------------------------------------------
+
+def _plain_sums(vals, seg, S):
+    """numpy int64, wrapping mod 2^64, one row at a time."""
+    out = []
+    with np.errstate(over="ignore"):
+        for v in vals:
+            acc = np.zeros(S, np.int64)
+            np.add.at(acc, seg, v)
+            out.append(acc)
+    return out
+
+
+def _value_classes(rng, n, live):
+    """name -> int64[n], dead rows already 0 as the kernel's callers give
+    them."""
+    i64 = np.iinfo(np.int64)
+    full = rng.integers(i64.min, i64.max, n, dtype=np.int64, endpoint=True)
+    full[:3] = [i64.min, i64.max, i64.min][:n]      # sums wrap mod 2^64
+    out = {
+        "full_range": full,
+        "all_negative": -rng.integers(1, 2**62, n, dtype=np.int64),
+        "all_zero": np.zeros(n, np.int64),
+        "count_0_1": np.ones(n, np.int64),
+        "q1_magnitudes": rng.integers(0, 10**11, n, dtype=np.int64),
+    }
+    return {k: np.where(live, v, 0) for k, v in out.items()}
+
+
+@pytest.mark.parametrize("n", [1, 289, (1 << 15) - 1, (1 << 15) + 7, 1 << 18])
+@pytest.mark.parametrize("S", [1, 2, 26, 290, 1024])
+def test_contracted_sums_match_numpy(tpu_branches, S, n):
+    """Every value class through ONE contraction (a vector given twice is
+    contracted once), dead rows in the dump slot S-1: sums bit-identical to
+    numpy's wrapping int64, rows per slot equal to the plain count."""
+    assert K.i64_sum_path(S, n) == "contraction"
+    rng = np.random.default_rng(1000 * S + n % 997)
+    live = rng.random(n) < 0.9
+    seg = np.where(live, rng.integers(0, max(S - 1, 1), n), S - 1) \
+        .astype(np.int32)
+    classes = _value_classes(rng, n, live)
+    vals_np = list(classes.values())
+    vals = [jnp.asarray(v) for v in vals_np]
+    vals.append(vals[0])                    # the same array again
+    sums, rows = K.grouped_sums_and_rows_i64(vals, jnp.asarray(seg), S)
+    want = _plain_sums(vals_np + [vals_np[0]], seg, S)
+    for name, got, ref in zip(list(classes) + ["again"], sums, want):
+        assert got.dtype == jnp.int64 and got.shape == (S,)
+        assert np.array_equal(np.asarray(got), ref), name
+    assert rows.dtype == jnp.int32
+    assert np.array_equal(np.asarray(rows), np.bincount(seg, minlength=S))
+
+
+@pytest.mark.parametrize("case", ["pr28_limb_sums", "max_chunks_of_max_parts",
+                                  "one_chunk_of_zeros"])
+def test_recombine_8bit_limb_sums_in_32bit_arithmetic(case):
+    """The recombination alone, at the contraction's limb width."""
+    if case == "pr28_limb_sums":
+        # PR 28's 16-bit limb sums 0xc130694c, 0x1e8effec, 0, 0 as sums of
+        # 8-bit limbs: limb 2i + (limb 2i+1 << 8) is 16-bit limb i
+        l0, l1 = 0xC130694C, 0x1E8EFFEC
+        total8 = [l0 & 0xFF, l0 >> 8, l1 & 0xFF, l1 >> 8, 0, 0, 0, 0]
+        chunks = 4                  # every part under 2^23
+        parts = np.zeros((chunks, 8, 1), np.int64)
+        for i, t in enumerate(total8):
+            parts[:, i, 0] = t // chunks
+            parts[0, i, 0] += t % chunks
+        want = 0x1E8FC11C694C
+    elif case == "max_chunks_of_max_parts":
+        # as many chunks as a call may make, every limb sum at its ceiling
+        # (2^15 rows of 255), two columns that differ
+        top = 255 * K._SEG_CHUNK
+        parts = np.full((K._MAX_CHUNKS, 8, 2), top, np.int64)
+        parts[:, :, 1] = top - np.arange(8)[None, :]
+        want = None
+    else:
+        parts = np.zeros((1, 8, 1), np.int64)
+        want = 0
+    assert parts.max() < 1 << 23
+    got = np.asarray(K._recombine_chunk_limbs8(jnp.asarray(parts, jnp.int32)))
+    for col in range(parts.shape[2]):
+        exact = sum(int(parts[:, i, col].sum()) << (8 * i) for i in range(8))
+        exact &= (1 << 64) - 1
+        if want is not None:
+            assert exact == want
+        assert int(got[col]) & ((1 << 64) - 1) == exact
+
+
+@pytest.mark.parametrize("aggs", [
+    pytest.param("sums_and_counts", id="q1-int64-sums-and-counts"),
+    pytest.param("counts_only", id="counts-only"),
+    pytest.param("with_minmax_and_float", id="minmax-and-float-sum"),
+])
+def test_dense_group_states_rows_from_the_contraction(tpu_branches, aggs):
+    """``exists_cnt`` and every count equal the plain count on the same
+    inputs; with int64 sums and counts alone (q1) the traced program holds
+    no scatter at all."""
+    rng = np.random.default_rng(17)
+    n = (1 << 15) + 100
+    key_ranges = ((-1, 15), (-1, 15))            # q1's 17 x 17 slots
+    domain = K.dense_domain(key_ranges)
+    assert domain == 289
+    k0 = rng.integers(-1, 3, n).astype(np.int32)
+    k1 = rng.integers(-1, 2, n).astype(np.int32)
+    mask = rng.random(n) < 0.8
+    v = rng.integers(-2**62, 2**62, n, dtype=np.int64)
+    w = rng.integers(0, 10**11, n, dtype=np.int64)
+    f = rng.random(n)
+    val_cols = {
+        "sums_and_counts": [(v, K.AGG_SUM), (w, K.AGG_COUNT), (w, K.AGG_SUM),
+                            (v, K.AGG_COUNT), (v, K.AGG_SUM)],
+        "counts_only": [(v, K.AGG_COUNT)],
+        "with_minmax_and_float": [(v, K.AGG_MIN), (f, K.AGG_SUM),
+                                  (v, K.AGG_SUM), (w, K.AGG_COUNT),
+                                  (w, K.AGG_MAX)],
+    }[aggs]
+
+    def run(k0, k1, mask, *arrs):
+        return K.dense_group_states(
+            [k0, k1], [(a, how) for a, (_, how) in zip(arrs, val_cols)],
+            mask, key_ranges, domain)
+
+    args = (k0, k1, mask) + tuple(a for a, _ in val_cols)
+    dense_vals, exists_cnt, bad = jax.jit(run)(*args)
+    slot = (k0 + 1) * 17 + (k1 + 1)
+    plain = np.bincount(slot[mask], minlength=domain)
+    assert exists_cnt.dtype == jnp.int32 and not bool(bad)
+    assert np.array_equal(np.asarray(exists_cnt), plain)
+    for (a, how), got in zip(val_cols, dense_vals):
+        if how == K.AGG_COUNT:
+            assert got.dtype == jnp.int64
+            assert np.array_equal(np.asarray(got), plain)
+        elif how == K.AGG_SUM and a.dtype == np.int64:
+            ref = _plain_sums([np.where(mask, a, 0)],
+                              np.where(mask, slot, domain), domain + 1)[0]
+            assert np.array_equal(np.asarray(got), ref[:domain])
+    prims = set(_primitives(jax.make_jaxpr(run)(*args).jaxpr))
+    if aggs != "with_minmax_and_float":
+        assert not {p for p in prims if "scatter" in p}, prims
+    assert "dot_general" in prims
+
+
+@pytest.mark.parametrize("backend,S,n,path", [
+    ("cpu", 290, 1 << 20, "scatter"),
+    ("tpu", 1, 1, "contraction"),
+    ("tpu", 290, 1 << 23, "contraction"),
+    ("tpu", 1024, 1 << 20, "contraction"),
+    ("tpu", 1025, 1 << 20, "chunk_offset"),
+    ("tpu", 1 << 22, 1 << 20, "scatter"),       # chunk-offset ids would wrap
+    ("tpu", 290, (1 << 30) + 1, "scatter"),     # past the recombination
+])
+def test_i64_sum_path_is_the_one_authority(request, backend, S, n, path):
+    if backend == "tpu":
+        request.getfixturevalue("tpu_branches")
+    assert K.i64_sum_path(S, n) == path
+
+
+# --------------------------------------------------------------------------
 # keyless (global) aggregate: one masked reduction into one row
 # --------------------------------------------------------------------------
 

@@ -66,6 +66,23 @@ def test_mesh_matches_file_shuffle(table, q):
     pd.testing.assert_frame_equal(got, want, check_dtype=False)
 
 
+def test_mesh_dense_aggregate_counts_its_contraction(table, tpu_branches):
+    """A dense domain (one dictionary key) on the TPU branch: the mesh
+    program's sums and counts are one contraction a device, counted once a
+    dispatch; answers as over files."""
+    mesh_ctx, file_ctx = contexts(table)
+    sql = "select s, sum(v) as sv, count(*) as n from t group by s order by s"
+    report = mesh_ctx.explain_analyze(sql)
+    counted = [op["metrics"].get("mxu_grouped_sums", 0)
+               for stage in report["stages"]
+               for op in stage["operator_tree"]
+               if op["op"] == "MeshAggregateExec"]
+    assert counted == [1], report["text"]
+    pd.testing.assert_frame_equal(mesh_ctx.sql(sql).to_pandas(),
+                                  file_ctx.sql(sql).to_pandas(),
+                                  check_dtype=False)
+
+
 def test_mesh_standalone_cluster(table):
     config = BallistaConfig({"ballista.shuffle.partitions": "4",
                              "ballista.shuffle.mesh": "true",

@@ -148,6 +148,34 @@ def test_keyless_partial_is_one_row_of_capacity_one():
     assert b.capacity == 1 and b.num_rows == 0
 
 
+@pytest.mark.parametrize("case,backend,expected", [
+    ("dense_domain_sums_and_counts", "tpu", 2),
+    ("sort_path_sums", "tpu", 0),
+    ("keyless", "tpu", 0),
+    ("dense_domain_sums_and_counts", "cpu", 0),
+])
+def test_mxu_grouped_sums_counts_kernel_calls_on_the_matrix_unit(
+        request, case, backend, expected):
+    """One a kernel call whose int64 sums and counts take the contraction
+    (``kernels.i64_sum_path``): a dense domain on the TPU backend; the sort
+    path (its capacity of 1024 rows or more is past the contraction's
+    slots), a keyless reduction and the CPU backend never."""
+    if backend == "tpu":
+        request.getfixturevalue("tpu_branches")
+    df = lineitem_like()
+    aggs = [AggSpec("sum", E.Column("qty"), "s"), AggSpec("count", None, "c")]
+    keys = {"dense_domain_sums_and_counts": [(E.Column("flag"), "flag")],
+            "sort_path_sums": [(E.Column("k"), "k")],
+            "keyless": []}[case]
+    partial = HashAggregateExec(scan_of(df, 2), keys, aggs, "partial")
+    got = run_all(partial)
+    assert partial.metrics().to_dict().get("mxu_grouped_sums", 0) == expected
+    if keys:
+        name = keys[0][1]
+        assert got.groupby(name)["c"].sum().to_dict() \
+            == df.groupby(name).size().to_dict()
+
+
 @pytest.mark.parametrize("mode", ["single", "final"])
 def test_keyless_aggregate_over_empty_input_is_one_row(mode):
     """SQL: count = 0 and sum/min/max = NULL, from 'single' directly and

@@ -15,6 +15,7 @@ runs under several workers that each import this file, and only the worker
 that is given the file may load it.
 """
 import os
+import re
 import time
 
 import numpy as np
@@ -55,18 +56,6 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def tpu_branches(monkeypatch):
-    """Code that asks which backend it runs on still sees the CPU here:
-    steer the kernels onto their TPU branches, as tests/test_kernels.py
-    does."""
-    K._tpu_backend.cache_clear()
-    monkeypatch.setattr(K, "_tpu_backend", lambda: True)
-    yield
-    monkeypatch.undo()
-    K._tpu_backend.cache_clear()
-
-
 def sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -78,17 +67,78 @@ def compile_for_chip(name, fn, *args, **jit_kw):
     return compiled
 
 
-@pytest.mark.parametrize("segments", [
-    pytest.param(64, id="q1-dense-onehot-matmul"),
-    pytest.param(4 * K._MATMUL_SEG_LIMIT, id="chunk-offset"),
+def contraction_operands(text):
+    """(result type, operand types) of every convolution (a dot, to the
+    TPU's compiler) in a compiled program's text."""
+    types = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[", text, re.M))
+    return [(res, [types.get(a.strip().lstrip("%"), "?")
+                   for a in args.split(",")])
+            for res, args in re.findall(
+                r"= (\w+)\[[^\]]*\]\S* convolution\(([^)]*)\)", text)]
+
+
+def has_scatter(text):
+    """A scatter among the program's ops (names of functions and tests
+    ride the text's stack frames, so not a substring test)."""
+    return re.search(r"\bscatter\(", text) is not None
+
+
+def assert_exact_mxu_contraction(text):
+    """The kept form: operands in the matrix unit's own bfloat16 (the
+    one-hot may still be the compare's ``pred``, converted inside the
+    fusion), accumulated in float32; no emulated int32 dot."""
+    dots = contraction_operands(text)
+    assert dots, "no contraction in the program"
+    for res, operands in dots:
+        assert res == "f32", dots
+        assert set(operands) <= {"bf16", "pred"}, dots
+
+
+@pytest.mark.parametrize("segments,values", [
+    pytest.param(64, 8, id="dense-onehot-matmul"),
+    pytest.param(290, 9, id="q1-dense-290-slots-nine-values"),
+    pytest.param(4 * K._MATMUL_SEG_LIMIT, 8, id="chunk-offset"),
 ])
-def test_grouped_sums_i64_tpu_branch(one_chip, tpu_branches, segments):
-    """q1's aggregate: 8 int64 value vectors over one batch."""
-    vals = [sds((BATCH,), jnp.int64, one_chip) for _ in range(8)]
+def test_grouped_sums_i64_tpu_branch(one_chip, tpu_branches, segments,
+                                     values):
+    """q1's aggregate: int64 value vectors over one batch.  Up to
+    ``_MATMUL_SEG_LIMIT`` slots it is one exact contraction on the matrix
+    unit and holds no scatter; past it the chunk-offset segment_sums."""
+    vals = [sds((BATCH,), jnp.int64, one_chip) for _ in range(values)]
     seg = sds((BATCH,), jnp.int32, one_chip)
-    compile_for_chip(
+    text = compile_for_chip(
         f"grouped_sums_i64 S={segments}",
-        lambda vals, seg: K.grouped_sums_i64(vals, seg, segments), vals, seg)
+        lambda vals, seg: K.grouped_sums_and_rows_i64(vals, seg, segments),
+        vals, seg).as_text()
+    if K.i64_sum_path(segments, BATCH) == "contraction":
+        assert not has_scatter(text)
+        assert_exact_mxu_contraction(text)
+    else:
+        assert has_scatter(text) and " convolution(" not in text
+
+
+def test_dense_aggregate_q1_shape_is_one_contraction(one_chip, tpu_branches):
+    """q1's partial aggregate as ``sf10_scanagg`` runs it: one scan task's
+    2^23 slots, two dictionary keys rounded to 17 x 17 = 289 dense slots,
+    five int64 sums and four counts.  Sums, counts and the rows per slot are
+    one contraction in bfloat16; nothing in the program is a scatter or an
+    int32 dot."""
+    n = 1 << 23
+    key_ranges = ((-1, 15), (-1, 15))
+    hows = [K.AGG_SUM] * 4 + [K.AGG_COUNT] + [K.AGG_SUM] + [K.AGG_COUNT] * 3
+
+    def q1_partial(keys, vals, mask):
+        return K.grouped_aggregate(keys, list(zip(vals, hows)), mask,
+                                   K.dense_domain(key_ranges),
+                                   key_ranges=key_ranges)
+
+    text = compile_for_chip(
+        "dense grouped_aggregate, q1 at SF10, one task", q1_partial,
+        [sds((n,), jnp.int32, one_chip) for _ in range(2)],
+        [sds((n,), jnp.int64, one_chip) for _ in hows],
+        sds((n,), jnp.bool_, one_chip)).as_text()
+    assert not has_scatter(text)
+    assert_exact_mxu_contraction(text)
 
 
 @pytest.mark.parametrize("is_min", [True, False], ids=["min", "max"])
@@ -276,6 +326,10 @@ def test_mesh_dense_reduce_q1_shape_on_four_devices(topo, tpu_branches):
                                 sds((n,), jnp.bool_, rows))
     text = compiled.as_text()
     assert "all-reduce" in text and "all-to-all" not in text
+    # every aggregate is an int64 sum or a count: one exact contraction,
+    # the rows per slot among its rows
+    assert not has_scatter(text)
+    assert_exact_mxu_contraction(text)
     mem = compiled.memory_analysis()
     print(f"[tpu-compile] q1 mesh program bytes per device: "
           f"args {mem.argument_size_in_bytes}, temp {mem.temp_size_in_bytes}")
