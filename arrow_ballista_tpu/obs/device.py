@@ -87,6 +87,7 @@ _COUNTER_KEYS = (
     "h2d_time", "d2h_time",
     "program_cache_hits", "program_cache_misses",
     "mesh_reshard_bytes", "mesh_programs", "mesh_collective_bytes",
+    "mesh_unshard_bytes", "mesh_exchange_retries",
 )
 _PEAK_KEYS = ("device_live_peak_bytes", "host_rss_peak_bytes")
 
@@ -330,6 +331,21 @@ def record_mesh_program(collective_bytes: int) -> None:
     if _enabled:
         _record("mesh_programs", 1)
         _record("mesh_collective_bytes", int(collective_bytes))
+
+
+def record_mesh_unshard(nbytes: int) -> None:
+    """Bytes of a mesh program's outputs brought onto one device
+    (ops/mesh_exec.py ``_unshard``): devices to device, no host crossing,
+    so not ``d2h_bytes``."""
+    if _enabled:
+        _record("mesh_unshard_bytes", int(nbytes))
+
+
+def record_mesh_exchange_retry() -> None:
+    """One exchange program run again at the send-bucket capacity its
+    first run found it needed (ops/mesh_exec.py ``MeshAggregateExec``)."""
+    if _enabled:
+        _record("mesh_exchange_retries", 1)
 
 
 def record_program_cache(hit: bool) -> None:

@@ -27,7 +27,7 @@ from ..models.batch import ColumnBatch, concat_batches
 from ..models.schema import Field, Schema
 from ..obs import device as device_obs
 from ..obs.tracing import span
-from ..utils.config import AGG_CAPACITY, JOIN_OUTPUT_FACTOR, MESH_BROADCAST_ROWS
+from ..utils.config import JOIN_OUTPUT_FACTOR, MESH_BROADCAST_ROWS
 from ..utils.errors import CapacityError
 from .expressions import ExprCompiler
 from .operators import AggSpec, HashAggregateExec, null_check_of, valid_of
@@ -43,22 +43,45 @@ def _pow2(n: int) -> int:
     return max(64, 1 << max(0, int(n) - 1).bit_length())
 
 
-def _unshard(tree):
-    """Collapse a mesh program's outputs to ordinary single-device arrays.
+def _unshard(tree, keep: Optional[int] = None):
+    """Collapse a mesh program's outputs to ordinary arrays on the default
+    device.
 
     Downstream operators run eager single-device ops; feeding them sharded
     arrays makes every eager op an 8-device collective program, and
     concurrently dispatched collective programs deadlock XLA's CPU
     rendezvous (observed: 'Expected 8 threads to join ... only 6 arrived'
-    -> hard abort).  So the outputs take one host hop, all of them in one
-    fetch and one placement, accounted like any other (``device_wait``
-    ``d2h``, ``h2d``): small for group states, the size of the result for
-    a join."""
-    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
-    with device_obs.device_wait("d2h", nbytes):
-        host = jax.device_get(tree)
-    with device_obs.h2d(nbytes):
-        return jax.device_put(host)
+    -> hard abort).  Device to device: every shard is copied to the default
+    device and the copies are concatenated there in shard order (a
+    replicated output is the default device's own replica: no copy); no
+    byte crosses the host, whose bulk D2H runs at 0.2 GB/s on the v5e.
+    ``keep``: the slots at the front of every shard that can hold a live
+    row (an exchange's final states are compacted to the front of their
+    ``final_capacity``); the rest are not moved.  A ``mesh_unshard`` span
+    that ends when the arrays are in place (``rows``: those of the tree's
+    last leaf, the mask), counted in ``mesh_unshard_bytes``."""
+    dev = jax.devices()[0]
+
+    def one(x):
+        shards = sorted(x.addressable_shards,
+                        key=lambda s: (s.index[0].start or 0,
+                                       s.device != dev))
+        if x.is_fully_replicated:
+            return jax.device_put(shards[0].data, dev)
+        parts = [s.data if keep is None or keep >= s.data.shape[0]
+                 else s.data[:keep] for s in shards]
+        # ballista: allow=host-device-boundary — device to device, not a host crossing: counted as mesh_unshard_bytes
+        return jnp.concatenate([jax.device_put(p, dev) for p in parts])
+
+    with span("mesh_unshard", "device", via="device") as sp:
+        out = jax.tree_util.tree_map(one, tree)
+        leaves = jax.tree_util.tree_leaves(out)
+        nbytes = sum(x.nbytes for x in leaves)
+        sp.set(bytes=nbytes, rows=int(leaves[-1].shape[0]))
+        with device_obs.device_wait("ready"):
+            jax.block_until_ready(out)
+    device_obs.record_mesh_unshard(nbytes)
+    return out
 
 
 def _shard_rows(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray, mesh,
@@ -91,22 +114,49 @@ def _shard_rows(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray, mesh,
     return cols, mask, padded
 
 
-def _dispatch(prog, *args):
+def _dispatch(prog, *args, describe=None, **attrs):
     """One call of a mesh program (parallel/distributed.py ``MeshProgram``):
-    a ``mesh_program`` span from the dispatch, under the process-wide
-    dispatch lock, until the outputs are ready.  The overflow flag is the
-    program's last output, so fetching it (a ``device_wait``) waits for all
-    of them.  Returns the outputs with the flag as a host bool."""
+    a ``mesh_program`` span (``attrs`` beside its own) from the dispatch,
+    under the process-wide dispatch lock, until the outputs are ready.  The
+    program's last output is its overflow flag, or an exchange's ``stats``
+    whose first element is one, so fetching it (a ``device_wait``) waits for
+    all of them.  Returns the outputs with the last as a host array;
+    ``describe(last)`` gives the span what only that tells."""
     from ..parallel.mesh import MESH_DISPATCH_LOCK
 
     with span("mesh_program", "device", program=prog.name,
-              collective=prog.collective):
+              collective=prog.collective, **attrs) as sp:
         with MESH_DISPATCH_LOCK:
-            *out, overflow = prog(*args)
+            *out, last = prog(*args)
         with device_obs.device_wait("scalar"):
-            overflow = bool(overflow)
+            last = np.asarray(last)
+        if describe is not None:
+            sp.set(**describe(last))
     device_obs.record_mesh_program(prog.collective_bytes(*args))
-    return (*out, overflow)
+    return (*out, last)
+
+
+def _exchange_bounds(rows: int, n_dev: int, shuffle_cap: Optional[int] = None):
+    """``(partial, shuffle, final)`` capacities a device of an exchange
+    aggregate over shards of ``rows`` rows, none from configuration: a
+    shard has at most ``rows`` groups; a destination bucket of the send
+    buffer holds ``shuffle_cap`` states, twice a bucket's even share of them
+    unless a first run has said what it needs, and never more than the
+    shard could fill it with; a device can receive, so own, at most
+    ``n_dev`` full buckets.  The first and the last cannot be passed (the
+    kernel's overflow flag is statically absent for both); the second can,
+    by keys that hash unevenly, and the program then says by how much."""
+    from ..parallel.distributed import shuffle_capacity_of
+
+    shuffle_cap = shuffle_capacity_of(rows, n_dev) if shuffle_cap is None \
+        else max(1, min(rows, shuffle_cap))
+    return rows, shuffle_cap, n_dev * shuffle_cap
+
+
+# send-bucket capacities that first runs found they needed, by what the
+# program is shared under and the shard's rows: a later execution of the
+# same statement over the same rows starts there and pays no re-run
+_EXCHANGE_NEED: Dict[tuple, int] = {}
 
 
 # --- shared pieces of the two mesh aggregate operators ---------------------
@@ -204,14 +254,14 @@ def _agg_key_ranges(key_c, dicts):
 
 
 def _finish_states(schema, key_c, val_c, ks, vs, msk, big_dicts,
-                   hidden_specs=()):
+                   hidden_specs=(), keep=None):
     """Unshard fused-program outputs into one ordinary ColumnBatch, casting
     values to the operator's declared schema dtypes.  ``vs`` carries the
     main aggregate states followed by the hidden valid-count states
     (``hidden_specs`` order); all-NULL groups are restored to the output
-    sentinel here, after the exchange."""
+    sentinel here, after the exchange.  ``keep``: see ``_unshard``."""
     n_main = len(val_c)
-    ks, vs, msk = _unshard((list(ks), list(vs), msk))
+    ks, vs, msk = _unshard((list(ks), list(vs), msk), keep)
     out_cols: Dict[str, jnp.ndarray] = {}
     dicts: Dict[str, np.ndarray] = {}
     for (kc, name), arr in zip(key_c, ks):
@@ -359,14 +409,9 @@ class MeshAggregateExec(ExecutionPlan):
         # it before the program asks for its workspace beside it
         del big
 
-        cap = ctx.config.get(AGG_CAPACITY)
-        # partial states are bounded by the shard size; the final aggregate
-        # is NOT (hash skew can land every group on one device), so its
-        # bound must respond to the config knob
-        partial_cap = max(256, min(cap, padded // n_dev + 1))
-        final_cap = max(256, min(cap, padded + 1))
         key_ranges = _agg_key_ranges(key_c, dicts)
         domain = dense_domain(key_ranges)
+        keep = None
         # one program a plan shape, device count and static bound, shared
         # across jobs: a re-run of the query traces and compiles nothing
         if domain is not None:
@@ -388,29 +433,64 @@ class MeshAggregateExec(ExecutionPlan):
             if i64_sum_path(domain + 1, padded // n_dev) == "contraction":
                 self.metrics().add("mxu_grouped_sums", 1)
         else:
-            prog = _program(
-                self, ("mesh_agg_exchange", n_dev, key_ranges, partial_cap,
-                       final_cap) + base,
-                lambda: distributed_filter_aggregate(
+            fk, fv, fmask, keep = self._exchange(
+                n_dev, padded // n_dev, key_ranges, base,
+                lambda partial, shuffle, final: distributed_filter_aggregate(
                     mesh, _make_derive(key_c, val_c), key_names, agg_specs,
-                    partial_capacity=partial_cap, final_capacity=final_cap,
-                    key_ranges=key_ranges))
-            fk, fv, fmask, overflow = _dispatch(prog, cols, mask, aux)
-            if overflow:
-                raise CapacityError(
-                    f"mesh aggregation exceeded its group capacity "
-                    f"(partial {partial_cap}/device, final {final_cap}/device); "
-                    f"raise {AGG_CAPACITY}")
+                    partial_capacity=partial, final_capacity=final,
+                    key_ranges=key_ranges, shuffle_capacity=shuffle),
+                cols, mask, aux)
         del cols, mask
 
         result = _finish_states(self._schema, key_c, val_c, fk, fv, fmask,
-                                dicts, hidden_specs=hidden)
+                                dicts, hidden_specs=hidden, keep=keep)
         # deferred: the count becomes host-known for free when the shuffle
         # writer's packed fetch materializes this batch (an eager .num_rows
         # costs a scalar sync per task where remote_device() holds)
         deferred_rows(self.metrics(), "output_rows", result)
         self.metrics().add("mesh_devices", n_dev)
         return [result]
+
+    def _exchange(self, n_dev, rows, key_ranges, base, build, *args):
+        """The partial aggregate, ``all_to_all`` and final aggregate of
+        shards of ``rows`` rows as one program (``build(partial, shuffle,
+        final)``, called on ``args``) at ``_exchange_bounds``.  A send
+        bucket fuller than its bound leaves states unsent: the program
+        flags it and says what the fullest bucket needed, the flagged
+        outputs are dropped whole, and it runs once more at that need,
+        which the same rows cannot pass.  ``(keys, states, mask, keep)``:
+        ``keep`` slots at the front of every device's share hold all of its
+        groups."""
+        memo = None if base == (None,) else (n_dev, rows, key_ranges) + base
+        learned = _EXCHANGE_NEED.get(memo)
+        for retries in (0, 1):
+            bounds = partial_cap, shuffle_cap, final_cap = _exchange_bounds(
+                rows, n_dev, learned)
+            prog = _program(
+                self, ("mesh_agg_exchange", n_dev, key_ranges) + bounds + base,
+                lambda: build(*bounds))
+            fk, fv, fmask, stats = _dispatch(
+                prog, *args,
+                describe=lambda st: {"groups_out": int(st[3])},
+                partial_capacity=partial_cap, shuffle_capacity=shuffle_cap,
+                final_capacity=final_cap, retries=retries)
+            overflow, need, groups_max, _groups_out = (int(v) for v in stats)
+            self.metrics().add("exchange_collective", 1)
+            if not overflow:
+                return fk, fv, fmask, min(final_cap, _pow2(groups_max))
+            del fk, fv, fmask
+            if retries or need <= shuffle_cap:
+                break
+            learned = _pow2(need)
+            if memo is not None:
+                _EXCHANGE_NEED[memo] = learned
+            self.metrics().add("exchange_retries", 1)
+            device_obs.record_mesh_exchange_retry()
+        raise CapacityError(
+            f"mesh aggregation passed a bound that its input cannot pass "
+            f"(partial {partial_cap}, shuffle {shuffle_cap}, final "
+            f"{final_cap} a device over shards of {rows} rows; the fullest "
+            f"send bucket needed {need})")
 
     def _label(self):
         g = ", ".join(n for _, n in self.group_exprs)
@@ -487,8 +567,9 @@ class MeshPartialAggregateExec(ExecutionPlan):
                                              n_dev)
             del big
 
-            cap = ctx.config.get(AGG_CAPACITY)
-            per_dev_cap = max(64, min(cap, padded // n_dev + 1))
+            # a shard has no more groups than rows: the sort path's
+            # overflow flag is statically absent at this bound
+            per_dev_cap = padded // n_dev
             key_ranges = _agg_key_ranges(key_c, dicts)
             domain = dense_domain(key_ranges)
             if domain is not None:
@@ -505,8 +586,9 @@ class MeshPartialAggregateExec(ExecutionPlan):
             pk, pv, pmask, overflow = _dispatch(prog, cols, mask, aux)
             if overflow:
                 raise CapacityError(
-                    f"mesh partial aggregation exceeded {per_dev_cap} "
-                    f"groups/device; raise {AGG_CAPACITY}")
+                    f"mesh partial aggregation passed {per_dev_cap} groups a "
+                    f"device over shards of {padded // n_dev} rows: keys "
+                    f"outside their declared ranges")
             del cols, mask
 
         # all-NULL partial states become sentinels here, exactly like the

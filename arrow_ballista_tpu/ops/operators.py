@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from ..models import expr as E
 from ..models.batch import ColumnBatch, concat_batches
 from ..models.schema import BOOL, DataType, Field, INT64, Schema
-from ..utils.config import AGG_CAPACITY, JOIN_MAX_CAPACITY
+from ..utils.config import JOIN_MAX_CAPACITY
 from ..utils.errors import CapacityError, ExecutionError, InternalError
 from ..obs.device import device_wait, observed_jit
 from .expressions import Compiled, ExprCompiler
@@ -466,7 +466,6 @@ class HashAggregateExec(ExecutionPlan):
 
     def _execute(self, partition: int, ctx: TaskContext) -> List[ColumnBatch]:
         ctx.check_cancelled()
-        cfg_cap = ctx.config.get(AGG_CAPACITY)
         batches = self.input.execute(partition, ctx)
         in_schema = self.input.schema
 
@@ -484,16 +483,14 @@ class HashAggregateExec(ExecutionPlan):
             est = _state_bytes(batches, in_schema, self._schema)
             reservation = gov.try_reserve(est, site=f"agg:{self.mode}")
             if reservation is None:
-                return self._execute_spilled(ctx, cfg_cap, batches,
-                                             in_schema)
+                return self._execute_spilled(ctx, batches, in_schema)
         try:
-            return self._execute_inmem(partition, ctx, cfg_cap, batches,
-                                       in_schema)
+            return self._execute_inmem(partition, ctx, batches, in_schema)
         finally:
             if reservation is not None:
                 reservation.release()
 
-    def _execute_inmem(self, partition, ctx, cfg_cap, batches, in_schema):
+    def _execute_inmem(self, partition, ctx, batches, in_schema):
         big = concat_batches(in_schema, batches).shrink()
 
         if self.mode == "partial" and self.group_exprs \
@@ -514,7 +511,7 @@ class HashAggregateExec(ExecutionPlan):
         # concurrent first-calls of the shared jfn
         with self.xla_lock():
             self._ensure_compiled(ctx, in_schema)
-        out, disorder = self._execute_device(ctx, cfg_cap, big)
+        out, disorder = self._execute_device(ctx, big)
         if self.mode == "partial" and getattr(self, "clustered", None) \
                 is not None and self.clustered[0] is None:
             # presorted-only clustering: no early filter, but the disorder
@@ -538,8 +535,7 @@ class HashAggregateExec(ExecutionPlan):
                     with device_wait("scalar"):
                         bad = bool(disorder)
                 if bad:
-                    out = self._latch_sorted_fallback(ctx, in_schema,
-                                                      cfg_cap, big)
+                    out = self._latch_sorted_fallback(ctx, in_schema, big)
             return out
         if self.mode == "partial" and getattr(self, "clustered", None) \
                 is not None:
@@ -555,8 +551,7 @@ class HashAggregateExec(ExecutionPlan):
                                                      mismatch)
                         for b in out]
             if any(f is None for f in filtered):
-                out = self._latch_sorted_fallback(ctx, in_schema, cfg_cap,
-                                                  big)
+                out = self._latch_sorted_fallback(ctx, in_schema, big)
                 if getattr(self, "_stale_ranges", False):
                     return out
                 filtered = [self._apply_clustered_filter(ctx, b, None, None)
@@ -564,7 +559,7 @@ class HashAggregateExec(ExecutionPlan):
             out = filtered
         return out
 
-    def _execute_spilled(self, ctx, cfg_cap, batches, in_schema):
+    def _execute_spilled(self, ctx, batches, in_schema):
         """Reservation denied: bound the state to one input batch at a
         time.  Each batch is aggregated independently (its state is
         capped by the batch capacity — the engine's functional floor),
@@ -586,7 +581,7 @@ class HashAggregateExec(ExecutionPlan):
         try:
             for b in batches:
                 ctx.check_cancelled()
-                out, _ = self._execute_device(ctx, cfg_cap, b)
+                out, _ = self._execute_device(ctx, b)
                 for r in out:
                     spiller.write_batch(r)
             self.metrics().add("spill_runs", len(spiller.runs))
@@ -597,7 +592,7 @@ class HashAggregateExec(ExecutionPlan):
             mop = self._merge_op()
             with mop.xla_lock():
                 mop._ensure_compiled(ctx, self._schema)
-            out, _ = mop._execute_device(ctx, cfg_cap, merged)
+            out, _ = mop._execute_device(ctx, merged)
             if out[0]._num_rows is not None:
                 self.metrics().add("output_rows", out[0]._num_rows)
             else:
@@ -619,7 +614,7 @@ class HashAggregateExec(ExecutionPlan):
                     self.aggs, "final")
             return self._merge
 
-    def _latch_sorted_fallback(self, ctx, in_schema, cfg_cap, big):
+    def _latch_sorted_fallback(self, ctx, in_schema, big):
         """Row groups lied about ordering (runtime disorder detection):
         latch off the presorted grouping, recompile the sorted path, and
         re-run — correctness first.  _make_compiled returns the tuple, so
@@ -629,7 +624,7 @@ class HashAggregateExec(ExecutionPlan):
         with self.xla_lock():
             self._no_presort = True
             self._compiled = self._make_compiled(ctx, in_schema)
-        out, _ = self._execute_device(ctx, cfg_cap, big)
+        out, _ = self._execute_device(ctx, big)
         return out
 
     def _declared_range_mismatch(self, ctx, big, partition):
@@ -910,7 +905,7 @@ class HashAggregateExec(ExecutionPlan):
                 observed_jit("agg.grouped", agg_fn, static_argnums=(3, 4),
                              variant=self.program_variant()))
 
-    def _execute_device(self, ctx, cfg_cap, big):
+    def _execute_device(self, ctx, big):
         comp, group_c, agg_c, tracked, jfn = self._compiled
         # static key ranges enable the dense (sort-free) grouping path:
         # dictionary-coded strings have host-known code ranges, bools are

@@ -15,7 +15,6 @@ transport per stage boundary.
 """
 from __future__ import annotations
 
-import math
 from typing import Callable, Dict, Sequence, Tuple
 
 import jax
@@ -32,8 +31,11 @@ from .mesh import PART_AXIS, mesh_axis_size
 _MERGE = {"sum": "sum", "count": "sum", "min": "min", "max": "max"}
 
 
-def _shuffle_capacity(rows_per_shard: int, n: int, factor: float) -> int:
-    return max(1, math.ceil(rows_per_shard / n * factor))
+def shuffle_capacity_of(states: int, n: int) -> int:
+    """State rows a destination bucket of a device's send buffer holds
+    unless told otherwise: twice the even share of ``states`` over ``n``
+    buckets, and never more than ``states`` (all of them in one bucket)."""
+    return max(1, min(states, -(-2 * states // n)))
 
 
 def _identity_filter(cols, mask, *aux):
@@ -76,8 +78,8 @@ def distributed_filter_aggregate(
     partial_capacity: int,
     final_capacity: int,
     axis: str = PART_AXIS,
-    skew_factor: float = 2.0,
     key_ranges=None,
+    shuffle_capacity: int = None,
 ):
     """Fused scan-filter → partial agg → ICI shuffle → final agg step.
 
@@ -89,13 +91,26 @@ def distributed_filter_aggregate(
     selects the dense sort-free grouping path on both sides of the
     exchange — see kernels.grouped_aggregate.
 
-    Returns ``run(cols, mask) -> (out_keys, out_vals, out_mask, overflow)``
+    The three static bounds, per device: ``partial_capacity`` group states
+    out of the shard's rows (a shard of that many rows cannot overflow it),
+    ``shuffle_capacity`` states a destination bucket of the send buffer
+    (``shuffle_capacity_of(partial_capacity, n)`` unless given),
+    ``final_capacity`` groups owned after the exchange (``n *
+    shuffle_capacity``, all a device can receive, cannot overflow it).
+
+    Returns ``run(cols, mask) -> (out_keys, out_vals, out_mask, stats)``
     with outputs sharded over the mesh (device d owns the groups whose
-    key-hash bucket is d), each of shape ``[n * final_capacity]``.  This is
-    the full TPC-H q1 execution shape as ONE compiled multi-chip program.
+    key-hash bucket is d, compacted to the front of its ``final_capacity``
+    slots), each of shape ``[n * final_capacity]``, and ``stats``, a
+    replicated int32 ``[overflow, bucket_need, groups_max, groups_out]``:
+    whether any bound was passed (the result is then short of rows and must
+    not be used), the fullest send bucket's states on any device (the
+    ``shuffle_capacity`` a re-run needs), the most groups a device owns and
+    the groups in all.
     """
     n = mesh_axis_size(mesh, axis)
-    cap = _shuffle_capacity(partial_capacity, n, skew_factor)
+    cap = shuffle_capacity if shuffle_capacity is not None \
+        else shuffle_capacity_of(partial_capacity, n)
     sent = {}       # bytes a device hands to the all_to_all, set as it traces
 
     def per_shard(cols: Dict[str, jnp.ndarray], mask: jnp.ndarray, *aux):
@@ -110,15 +125,21 @@ def distributed_filter_aggregate(
         # n buckets of cap state rows, each with its mask byte
         sent["bytes"] = n * cap * (_row_bytes(shuffled.values()) + 1)
         dest = K.bucket_of(pk, n)
-        recv, rmask, ovf2 = shuffle_rows(shuffled, dest, pmask, axis, n, cap)
+        recv, rmask, ovf2, need = shuffle_rows(shuffled, dest, pmask, axis,
+                                               n, cap)
         rk = [recv[f"k{i}"] for i in range(len(pk))]
         rv = [(recv[f"v{i}"], _MERGE[agg_specs[i][1]]) for i in range(len(pv))]
         fk, fv, fmask, ovf3 = K.grouped_aggregate(rk, rv, rmask,
                                                   final_capacity,
                                                   key_ranges=key_ranges)
         flags = K.overflow_flag(ovf1) | ovf2[0] | K.overflow_flag(ovf3)
-        overflow = lax.psum(flags.astype(jnp.int32), axis) > 0
-        return fk, fv, fmask, overflow
+        groups = jnp.sum(fmask, dtype=jnp.int32)
+        stats = jnp.stack([
+            lax.psum(flags.astype(jnp.int32), axis),
+            lax.pmax(need[0], axis),
+            lax.pmax(groups, axis),
+            lax.psum(groups, axis)])
+        return fk, fv, fmask, stats
 
     return _make_runner(
         "mesh.agg_exchange", f"k{len(key_names)}", per_shard, mesh,
@@ -416,10 +437,10 @@ def distributed_hash_join(
             # ship rows to their key-hash bucket owner (both sides agree)
             pdest = K.bucket_of(pk, n)
             bdest = K.bucket_of(bk, n)
-            p_recv, p_rmask, ovf_p = shuffle_rows(pcols, pdest, pmask, axis,
-                                                  n, shuffle_capacity)
-            b_recv, b_rmask, ovf_b = shuffle_rows(bcols, bdest, bmask, axis,
-                                                  n, shuffle_capacity)
+            p_recv, p_rmask, ovf_p, _ = shuffle_rows(
+                pcols, pdest, pmask, axis, n, shuffle_capacity)
+            b_recv, b_rmask, ovf_b, _ = shuffle_rows(
+                bcols, bdest, bmask, axis, n, shuffle_capacity)
             ovf_exchange = ovf_p[0] | ovf_b[0]
         out_cols, out_mask, ovf_j = _probe_emit(
             join_type, key_names, sflags, null_key_sentinel, probe_names,
@@ -447,9 +468,9 @@ def distributed_grouped_aggregate(
     partial_capacity: int,
     final_capacity: int,
     axis: str = PART_AXIS,
-    skew_factor: float = 2.0,
+    shuffle_capacity: int = None,
 ):
     """Distributed GROUP BY without a fused filter stage."""
     return distributed_filter_aggregate(
         mesh, _identity_filter, key_names, agg_specs, partial_capacity,
-        final_capacity, axis=axis, skew_factor=skew_factor)
+        final_capacity, axis=axis, shuffle_capacity=shuffle_capacity)

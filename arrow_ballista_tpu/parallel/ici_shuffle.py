@@ -11,15 +11,16 @@ Static-shape discipline (XLA cannot all_to_all ragged rows):
 - each device ranks its live rows within their destination bucket and
   scatters them into a ``[n_dest, capacity]`` send buffer (MoE-style
   capacity-factor dispatch);
-- ``capacity = ceil(rows/n * factor)`` bounds skew; rows past capacity set
-  an ``overflow`` flag the host checks (same contract as the kernels'
-  grouped_aggregate overflow — the host re-runs with a bigger factor);
+- ``capacity`` rows a bucket bound skew; rows past it are not sent, set an
+  ``overflow`` flag and are counted in ``need`` (the fullest bucket's live
+  rows), so the host never takes a flagged result and re-runs at ``need``,
+  which cannot overflow again;
 - the all_to_all swaps the leading axis, so device d ends up with every
   source's bucket-d block; flattening gives rows+mask again.
 
 This file is pure device code usable inside `jax.shard_map`; host-side
-orchestration (choosing factor, re-running on overflow) lives in the
-executor's stage runner.
+orchestration (choosing the capacity, re-running on overflow) lives in the
+mesh operators (ops/mesh_exec.py).
 """
 from __future__ import annotations
 
@@ -36,11 +37,13 @@ def dispatch_to_buckets(
     mask: jnp.ndarray,
     num_dest: int,
     capacity: int,
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray]:
+) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Scatter rows into a ``[num_dest, capacity]`` send buffer per column.
 
-    Returns (send_cols, send_mask, overflow).  Rows whose within-bucket rank
-    exceeds ``capacity`` are dropped and flagged via ``overflow``.
+    Returns (send_cols, send_mask, overflow, need).  Rows whose
+    within-bucket rank exceeds ``capacity`` are left out and flagged via
+    ``overflow``; ``need`` (int32 scalar) is the fullest bucket's live rows,
+    the capacity at which nothing would have been left out.
     """
     dkey = jnp.where(mask, dest, num_dest).astype(jnp.int32)
     # sort-free ranking: one cumsum per destination (num_dest = mesh size,
@@ -66,8 +69,8 @@ def dispatch_to_buckets(
     mbuf = jnp.zeros((num_dest * capacity + 1,), dtype=jnp.bool_)
     mbuf = mbuf.at[flat].set(slot_ok, mode="drop")
     send_mask = mbuf[:-1].reshape(num_dest, capacity)
-    overflow = jnp.any(counts > capacity)
-    return send_cols, send_mask, overflow
+    need = jnp.max(counts)
+    return send_cols, send_mask, need > capacity, need
 
 
 def all_to_all_rows(
@@ -98,15 +101,16 @@ def shuffle_rows(
     axis: str,
     num_partitions: int,
     capacity: int,
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray]:
+) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Full on-pod shuffle for one stage boundary (inside shard_map).
 
     Each device sends row i to device ``dest[i]``; returns the rows this
     device received (``num_partitions * capacity`` of them, masked), plus
-    the local overflow flag as a shape-(1,) bool (rank ≥1 so it can cross
-    shard_map out_specs; callers psum/any it across the mesh).
+    the local overflow flag and the local ``need`` (dispatch_to_buckets),
+    each of shape (1,) (rank ≥1 so it can cross shard_map out_specs;
+    callers psum/pmax them across the mesh).
     """
-    send_cols, send_mask, overflow = dispatch_to_buckets(
+    send_cols, send_mask, overflow, need = dispatch_to_buckets(
         cols, dest, mask, num_partitions, capacity)
     recv_cols, recv_mask = all_to_all_rows(send_cols, send_mask, axis)
-    return recv_cols, recv_mask, overflow[None]
+    return recv_cols, recv_mask, overflow[None], need[None]

@@ -21,7 +21,6 @@ REPARTITION_JOINS = "ballista.repartition.joins"
 REPARTITION_AGGREGATIONS = "ballista.repartition.aggregations"
 PARQUET_PRUNING = "ballista.parquet.pruning"
 # TPU-native knobs
-AGG_CAPACITY = "ballista.agg.capacity"  # static max distinct groups per batch agg
 JOIN_OUTPUT_FACTOR = "ballista.join.output_factor"  # mesh joins: out_cap = factor * per-device probe share
 JOIN_MAX_CAPACITY = "ballista.join.max_capacity"  # ceiling for adaptive retry
 COLLECT_STATISTICS = "ballista.collect_statistics"
@@ -209,7 +208,6 @@ _ENTRIES: Dict[str, ConfigEntry] = {
                     "distributed planner always repartitions aggregations; "
                     "accepted and propagated but not yet consulted"),
         ConfigEntry(PARQUET_PRUNING, True, _parse_bool, "row-group pruning on parquet scans"),
-        ConfigEntry(AGG_CAPACITY, 1 << 16, int, "static max distinct groups per aggregation"),
         ConfigEntry(JOIN_OUTPUT_FACTOR, 2, int,
                     "mesh-join output capacity = factor * per-device probe "
                     "share (plain joins size outputs by a count pass)"),
@@ -219,9 +217,18 @@ _ENTRIES: Dict[str, ConfigEntry] = {
                     "reference-parity placeholder (config.rs:38): scans "
                     "always collect the statistics pruning needs; accepted "
                     "and propagated but not yet consulted"),
-        ConfigEntry(MESH_SHUFFLE, False, _parse_bool, "use ICI mesh all-to-all shuffle"),
+        ConfigEntry(MESH_SHUFFLE, False, _parse_bool,
+                    "use ICI mesh all-to-all shuffle; a mesh aggregate's "
+                    "bounds come from its input, not from a key: a device "
+                    "holds as many partial groups as its shard has rows, a "
+                    "send bucket twice its even share of them (a fuller "
+                    "one is detected and the program runs once more at "
+                    "what it needed), and owns what the buckets can "
+                    "deliver"),
         ConfigEntry(MESH_HYBRID, False, _parse_bool,
-                    "hybrid exchange: mesh-fused partials per host, file shuffle across hosts"),
+                    "hybrid exchange: mesh-fused partials per host (a "
+                    "device's group slots are its shard's rows), file "
+                    "shuffle across hosts"),
         ConfigEntry(MESH_BROADCAST_ROWS, 1 << 18, int,
                     "mesh joins all_gather the build side instead of "
                     "all_to_all-ing both sides when its live rows fit here "
@@ -732,10 +739,6 @@ class BallistaConfig:
     @property
     def batch_size(self) -> int:
         return self.get(BATCH_SIZE)
-
-    @property
-    def agg_capacity(self) -> int:
-        return self.get(AGG_CAPACITY)
 
     @property
     def join_output_factor(self) -> int:

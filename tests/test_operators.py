@@ -301,17 +301,22 @@ def test_string_sort_via_codes():
 
 
 def test_aggregate_adaptive_capacity():
-    """High-cardinality GROUP BY beyond ballista.agg.capacity must succeed
-    via power-of-two recompilation (the join path's bucketed-recompile
-    discipline applied to aggregation)."""
+    """A high-cardinality GROUP BY succeeds with no capacity to configure:
+    an aggregate's group slots are bounded by its input's rows (the key
+    ``ballista.agg.capacity`` that once bounded them is gone, and a session
+    that still sets it is told so)."""
     import numpy as np
     import pyarrow as pa
 
     from arrow_ballista_tpu.client.context import BallistaContext
     from arrow_ballista_tpu.utils.config import BallistaConfig
 
-    n = 5000  # distinct keys far above the configured capacity of 16
-    ctx = BallistaContext.local(BallistaConfig({"ballista.agg.capacity": "16"}))
+    from arrow_ballista_tpu.utils.errors import ConfigurationError
+
+    with pytest.raises(ConfigurationError, match="unknown configuration key"):
+        BallistaConfig({"ballista.agg.capacity": "16"})
+    n = 5000
+    ctx = BallistaContext.local(BallistaConfig())
     ctx.register_table("big", pa.table({
         "k": pa.array(np.arange(n, dtype=np.int64)),
         "v": pa.array(np.ones(n, dtype=np.int64)),
