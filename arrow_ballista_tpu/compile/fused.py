@@ -329,6 +329,9 @@ class FusedStageExec(ExecutionPlan):
                 out_cap = min(out_cap, domain)
                 if K.i64_sum_path(domain + 1, big.capacity) == "contraction":
                     self.metrics().add("mxu_grouped_sums", 1)
+            elif group_c:
+                # keys and no dense domain: the kernel's run scans
+                self.metrics().add("run_scan_aggregates", 1)
             # read host-side facts BEFORE the call: the donated column and
             # mask buffers are dead after it, so nothing below may touch
             # the input batch (donation-safety analyzer enforces this)

@@ -9,8 +9,10 @@ fan-out) go to fixed capacities with liveness masks, which is what lets XLA
 compile one fused program per stage.
 
 Key techniques:
-- grouping is sort-based (lexsort -> boundary flags -> segment reductions),
-  exact for any key combination, no hash tables in HBM required;
+- grouping is sort-based (one sort that carries the columns -> boundary
+  flags -> a segmented scan per aggregate -> a second sort that brings the
+  run ends to the front), exact for any key combination, no hash tables in
+  HBM required and no row moved by an index;
 - joins sort the build side by a 64-bit mixed key; a probe batch finds every
   row's run of equal hashes in it once (``probe_ranges``: one sort of
   probe and build together, no search), and
@@ -241,11 +243,10 @@ def grouped_aggregate_presorted(
     out_capacity: int,
 ):
     """Sort-FREE grouping for inputs already ordered by the single group
-    key (clustered scans: physical_planner._clustered_having_pushdown).
-    Compaction (two cumsums + scatter) replaces the argsort — on TPU this
-    is the difference between a seconds and a minutes compile
-    (grouped_aggregate docstring), and at SF10 it drops a per-task 1M-row
-    sort on CPU too.
+    key (clustered scans: physical_planner._clustered_having_pushdown):
+    ``grouped_aggregate``'s reduction without its first sort.  The rows are
+    reduced where they lie — the boundaries and the scans skip dead rows in
+    place (``_live_runs``), nothing is compacted and no column is gathered.
 
     Returns (out_keys, out_vals, out_mask, overflow, disorder): ``disorder``
     is True when live keys were NOT non-decreasing — the caller must then
@@ -253,13 +254,10 @@ def grouped_aggregate_presorted(
     would otherwise emit duplicate partial states, which merge fine at a
     final aggregate but break early-HAVING filters)."""
     assert len(key_cols) == 1, "presorted grouping is single-key"
-    order = compaction_order(mask)
-    mask_s = mask[order]
-    k = key_cols[0][order]
-    disorder = jnp.any(mask_s[1:] & mask_s[:-1] & (k[1:] < k[:-1]))
-    out_keys, out_vals, out_mask, overflow = _grouped_aggregate_on_order(
-        [k], [(v[order], how) for v, how in val_cols], mask_s,
-        out_capacity, mask.shape[0])
+    keys_f, boundary, (prev_live, prev_keys) = _live_runs(key_cols, mask)
+    disorder = jnp.any(mask & prev_live & (key_cols[0] < prev_keys[0]))
+    out_keys, out_vals, out_mask, overflow = _reduce_runs(
+        keys_f, boundary, val_cols, mask, out_capacity)
     return out_keys, out_vals, out_mask, overflow, disorder
 
 
@@ -283,7 +281,13 @@ def grouped_aggregate(
     on TPU, where the sort-based program's XLA compile takes minutes while
     the dense program compiles in seconds (measured: 163 s vs 3.8 s for the
     q1 shape on v5e) and runs ~2.5x faster.  Otherwise grouping is
-    sort-based (lexsort -> boundary flags -> segment reductions).
+    sort-based and moves no row by an index (the comment above
+    ``i64_sum_path``): ONE unstable sort on the group keys that carries
+    the mask and the value vectors along, a segmented scan per aggregate
+    over the runs of equal keys (``_reduce_runs``), and a second sort that
+    brings the run ends, in key order, to the front of the output.  Float
+    aggregates keep their ``jax.ops.segment_*`` lowering (and, for their
+    summation order, a stable first sort): no cell runs one on this path.
 
     CONTRACT: ``key_ranges`` bounds are a caller-guaranteed invariant — every
     live row's key must lie inside its declared range.  On the dense path,
@@ -301,12 +305,21 @@ def grouped_aggregate(
     if domain is not None:
         return _grouped_aggregate_dense(key_cols, val_cols, mask,
                                         out_capacity, key_ranges, domain)
-    n = mask.shape[0]
-    order = sort_order([(k, True) for k in key_cols], mask)
-    mask_s = mask[order]
-    return _grouped_aggregate_on_order(
-        [k[order] for k in key_cols],
-        [(v[order], how) for v, how in val_cols], mask_s, out_capacity, n)
+    # the comparator reads the key words and nothing else: liveness is not
+    # a sort key (dead rows land anywhere and the reduction skips them), and
+    # nothing needs stability — equal keys are one run and every integer
+    # reduction commutes — but a float sum's order of additions.  The mask
+    # and the value vectors ride along; a count reads the mask alone.
+    carried = [a for a, how in val_cols if how != AGG_COUNT]
+    nk = len(key_cols)
+    out = jax.lax.sort(
+        (*key_cols, mask, *carried), num_keys=nk,
+        is_stable=any(a.dtype.kind == "f" for a in carried))
+    keys_s, mask_s, carried_s = list(out[:nk]), out[nk], iter(out[nk + 1:])
+    vals_s = [(mask_s if how == AGG_COUNT else next(carried_s), how)
+              for _, how in val_cols]
+    keys_f, boundary, _ = _live_runs(keys_s, mask_s)
+    return _reduce_runs(keys_f, boundary, vals_s, mask_s, out_capacity)
 
 
 def _global_aggregate(
@@ -344,86 +357,211 @@ def _in_slot0(v: jnp.ndarray, capacity: int) -> jnp.ndarray:
     return jnp.pad(v[None], (0, capacity - 1))
 
 
-def _grouped_aggregate_on_order(
-    keys_s: List[jnp.ndarray],
+def _live_runs(keys: List[jnp.ndarray], mask: jnp.ndarray):
+    """The runs of rows whose LIVE rows are in group order (equal keys
+    adjacent among the live rows; dead rows anywhere between them).
+
+    Returns (keys_f, boundary, (prev_live, prev_keys)): ``keys_f`` per row
+    the keys of the last live row at or before it (so a run's last row holds
+    the run's key even where that row is dead), ``boundary`` the live rows
+    that start a run, ``prev_live`` / ``prev_keys`` the same carried keys
+    one row earlier (whether a live row lies before the row at all, and its
+    keys).  The carry is a scan by doubling shifts in which the nearer live
+    row wins: no compaction, no gather."""
+    n = mask.shape[0]
+    has, keys_f = mask, list(keys)
+    shift = 1
+    while shift < n:
+        keys_f = [jnp.where(has, k, jnp.concatenate([k[:shift], k[:-shift]]))
+                  for k in keys_f]
+        has = has | jnp.concatenate([jnp.zeros(shift, bool), has[:-shift]])
+        shift *= 2
+    prev_live = jnp.concatenate([jnp.zeros(1, bool), has[:-1]])
+    prev_keys = [jnp.concatenate([k[:1], k[:-1]]) for k in keys_f]
+    differs = ~prev_live
+    for k, pk in zip(keys, prev_keys):
+        differs = differs | (k != pk)
+    return keys_f, mask & differs, (prev_live, prev_keys)
+
+
+def _run_scan(states: Tuple[jnp.ndarray, ...], gid: jnp.ndarray, combine):
+    """Inclusive scan that restarts wherever ``gid`` (non-decreasing)
+    changes, by doubling shifts: ``combine(states, earlier)`` merges each
+    row's state with the state ``shift`` rows earlier, taken only while
+    both lie in one run, so that a run's last row ends up holding the whole
+    run's state.  (Doubling shifts, not ``jnp.cumsum`` / ``lax.cummin``:
+    ``_shift_scan``.)"""
+    n = gid.shape[0]
+    shift = 1
+    while shift < n:
+        same = jnp.concatenate([jnp.zeros(shift, bool),
+                                gid[shift:] == gid[:-shift]])
+        earlier = tuple(jnp.concatenate([x[:shift], x[:-shift]])
+                        for x in states)
+        states = tuple(jnp.where(same, m, x)
+                       for m, x in zip(combine(states, earlier), states))
+        shift *= 2
+    return states
+
+
+def _i64_words(a: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """int64 -> (high word int32, low word uint32) of its two's
+    complement."""
+    return (a >> 32).astype(jnp.int32), a.astype(jnp.uint32)
+
+
+def _i64_of_words(hi: jnp.ndarray, lo: jnp.ndarray) -> jnp.ndarray:
+    """The int64 of two 32-bit words, assembled by a bitcast (no emulated
+    64-bit arithmetic: ``_recombine_chunk_limbs``)."""
+    return jax.lax.bitcast_convert_type(
+        jnp.stack([lo.astype(jnp.uint32), hi.astype(jnp.uint32)], axis=-1),
+        jnp.int64)
+
+
+def _add_words(cur, earlier):
+    """(hi, lo) + (hi, lo) mod 2^64 in uint32 words with the carry taken
+    out by hand: the chip's emulated 64-bit addition is not to be trusted
+    (``_recombine_chunk_limbs``)."""
+    (hi, lo), (ehi, elo) = cur, earlier
+    low = lo + elo
+    return hi + ehi + (low < elo).astype(jnp.uint32), low
+
+
+def _words_less(a, b):
+    """a < b for int64s given as (hi int32, lo uint32)."""
+    (ahi, alo), (bhi, blo) = a, b
+    return (ahi < bhi) | ((ahi == bhi) & (alo < blo))
+
+
+def _min_words(cur, earlier):
+    take = _words_less(earlier, cur)
+    return tuple(jnp.where(take, e, c) for c, e in zip(cur, earlier))
+
+
+def _max_words(cur, earlier):
+    take = _words_less(cur, earlier)
+    return tuple(jnp.where(take, e, c) for c, e in zip(cur, earlier))
+
+
+def _front(x: jnp.ndarray, capacity: int) -> jnp.ndarray:
+    """The first ``capacity`` slots of ``x``, zero-padded if it is
+    shorter."""
+    n = x.shape[0]
+    return x[:capacity] if capacity <= n else jnp.pad(x, (0, capacity - n))
+
+
+_SEGMENT_OPS = {AGG_SUM: jax.ops.segment_sum, AGG_MIN: jax.ops.segment_min,
+                AGG_MAX: jax.ops.segment_max}
+
+
+def _identity(how: str, dtype):
+    """What a dead row holds under ``how``: the reduction's identity, which
+    is also what an empty output slot holds (0, and the INT64_MAX /
+    INT64_MIN ``grouped_minmax_i64`` gives an empty slot)."""
+    if how == AGG_MIN:
+        return _max_ident(dtype)
+    if how == AGG_MAX:
+        return _min_ident(dtype)
+    return jnp.zeros((), dtype)
+
+
+def _scanned_states(a: jnp.ndarray, how: str, mask: jnp.ndarray,
+                    gid: jnp.ndarray):
+    """One aggregate's segmented scan over the runs of ``gid``: (words,
+    identities, finish) — the state per row as 32-bit words (a run's last
+    row holds the group's), what an empty slot's words hold, and how the
+    words make the output column.  A count is an int32 sum of the mask, an
+    integer of up to 32 bits is reduced in its own type, an int64 as two
+    words: sums exactly mod 2^64 with the carry taken by hand, min/max by
+    comparing (high, low).  None for any other type (floats): the caller
+    keeps their ``jax.ops.segment_*`` lowering."""
+    if how == AGG_COUNT:
+        words = _run_scan((mask.astype(jnp.int32),), gid,
+                          lambda s, e: (s[0] + e[0],))
+        return words, (0,), lambda w: w[0].astype(jnp.int64)
+    ident = _identity(how, a.dtype)
+    pre = jnp.where(mask, a, ident)
+    if a.dtype == jnp.int64:
+        hi, lo = _i64_words(pre)
+        if how == AGG_SUM:
+            hi = hi.astype(jnp.uint32)
+        combine = {AGG_SUM: _add_words, AGG_MIN: _min_words,
+                   AGG_MAX: _max_words}[how]
+        return (_run_scan((hi, lo), gid, combine), _i64_words(ident),
+                lambda w: _i64_of_words(*w))
+    if a.dtype.kind in "iu" and a.dtype.itemsize <= 4:
+        op = {AGG_SUM: jnp.add, AGG_MIN: jnp.minimum,
+              AGG_MAX: jnp.maximum}[how]
+        return (_run_scan((pre,), gid, lambda s, e: (op(s[0], e[0]),)),
+                (ident,), lambda w: w[0])
+    return None
+
+
+def _reduce_runs(
+    keys_f: List[jnp.ndarray],
+    boundary: jnp.ndarray,
     val_cols: List[Tuple[jnp.ndarray, str]],
-    mask_s: jnp.ndarray,
+    mask: jnp.ndarray,
     out_capacity: int,
-    n: int,
 ):
-    """Grouping over rows ALREADY in group order (live rows contiguous,
-    equal keys adjacent): boundary flags -> segment reductions.  Shared by
-    the sort path (grouped_aggregate) and the clustered presorted path
-    (grouped_aggregate_presorted)."""
-    first = jnp.zeros(n, dtype=bool).at[0].set(True)
-    diff = jnp.zeros(n, dtype=bool)
-    for k in keys_s:
-        diff = diff | (k != jnp.roll(k, 1))
-    boundary = mask_s & (first | diff)
+    """Reduce the runs ``_live_runs`` found: shared by the sort path
+    (grouped_aggregate) and the clustered presorted path
+    (grouped_aggregate_presorted); ``val_cols`` as those get them (a
+    count's vector is not read).
 
-    seg = jnp.cumsum(boundary) - 1  # group index per sorted row (-1 before first)
-    num_groups = jnp.sum(boundary)
-    # dead or out-of-capacity rows -> dump segment
-    seg_ok = mask_s & (seg >= 0) & (seg < out_capacity)
-    seg_ids = jnp.where(seg_ok, seg, out_capacity).astype(jnp.int32)
+    Per aggregate one segmented scan (``_scanned_states``) over the values
+    with dead rows at the reduction's identity, so that a run's last row
+    holds the group's state.  Then ONE sort keyed on the group index at a
+    run's last row, and on a value past every index elsewhere, carries the
+    keys and the states to the front, in ascending key order: the only data
+    movement.  Slots past the groups hold zero keys and the reductions'
+    identities, groups past ``out_capacity`` are dropped and flagged.
+    Nothing as long as the input is scattered or gathered, except by a
+    float aggregate, which keeps its ``jax.ops.segment_*`` lowering into
+    the group's slot."""
+    n = mask.shape[0]
+    # group index per row: -1 before the first live row
+    gid = _shift_scan(boundary.astype(jnp.int32), jnp.add, 0) - 1
+    num_groups = gid[n - 1] + 1
+    run_end = jnp.concatenate([gid[1:] != gid[:-1], jnp.ones(1, bool)]) \
+        & (gid >= 0)
 
-    # int64 sums/counts batch through the limb path (grouped_sums_i64 —
-    # segment order does not matter there): on TPU an int64 segment_sum is
-    # a 64-bit scatter measured 1-18M rows/s, and the first alternative
-    # tried (sorted-run cumsum differences) turned out to COMPILE for 44 s
-    # per shape on this backend, which per-job recompiles turned into a
-    # regression.  The limb programs compile in ~1-2 s (what they take to
-    # run: the comment above i64_sum_path).
-    i64_positions: List[int] = []
-    i64_vals: List[jnp.ndarray] = []
+    # per aggregate its scan, or (a float) its segment reduction straight
+    # into out_vals
+    scans = []
     out_vals: List[Optional[jnp.ndarray]] = []
     for a, how in val_cols:
-        if how == AGG_COUNT or (how == AGG_SUM and a.dtype == jnp.int64):
-            if how == AGG_COUNT:
-                pre = jnp.where(seg_ok, 1, 0).astype(jnp.int64)
-            else:
-                pre = jnp.where(seg_ok, a, jnp.zeros((), a.dtype))
-            i64_positions.append(len(out_vals))
-            i64_vals.append(pre)
+        if how not in (AGG_SUM, AGG_COUNT, AGG_MIN, AGG_MAX):
+            raise ValueError(f"unknown agg {how}")
+        scans.append(_scanned_states(a, how, mask, gid))
+        if scans[-1] is not None:
             out_vals.append(None)
             continue
-        elif how == AGG_SUM:
-            v = jax.ops.segment_sum(jnp.where(seg_ok, a, jnp.zeros((), a.dtype)), seg_ids,
-                                    num_segments=out_capacity + 1)[:out_capacity]
-        elif how == AGG_MIN:
-            if a.dtype == jnp.int64:
-                v = grouped_minmax_i64(a, seg_ok, seg_ids, out_capacity + 1,
-                                       is_min=True)[:out_capacity]
-            else:
-                ident = _max_ident(a.dtype)
-                v = jax.ops.segment_min(jnp.where(seg_ok, a, ident), seg_ids,
-                                        num_segments=out_capacity + 1)[:out_capacity]
-        elif how == AGG_MAX:
-            if a.dtype == jnp.int64:
-                v = grouped_minmax_i64(a, seg_ok, seg_ids, out_capacity + 1,
-                                       is_min=False)[:out_capacity]
-            else:
-                ident = _min_ident(a.dtype)
-                v = jax.ops.segment_max(jnp.where(seg_ok, a, ident), seg_ids,
-                                        num_segments=out_capacity + 1)[:out_capacity]
-        else:
-            raise ValueError(f"unknown agg {how}")
-        out_vals.append(v)
-    if i64_vals:
-        sums = grouped_sums_i64(i64_vals, seg_ids, out_capacity + 1)
-        for pos, s in zip(i64_positions, sums):
-            out_vals[pos] = s[:out_capacity]
+        seg_ids = jnp.where(mask & (gid < out_capacity), gid, out_capacity)
+        out_vals.append(_SEGMENT_OPS[how](
+            jnp.where(mask, a, _identity(how, a.dtype)), seg_ids,
+            num_segments=out_capacity + 1)[:out_capacity])
 
-    out_keys = []
-    for k in keys_s:
-        # scatter each group's first (boundary) row into its slot; non-boundary
-        # rows aim at the dump index and are dropped
-        ok = jnp.zeros(out_capacity, dtype=k.dtype).at[
-            jnp.where(boundary & seg_ok, seg, out_capacity)
-        ].set(k, mode="drop")
-        out_keys.append(ok)
+    words = [w for st in scans if st is not None for w in st[0]]
+    moved = jax.lax.sort(
+        (jnp.where(run_end, gid, _I32_MAX), *keys_f, *words),
+        num_keys=1, is_stable=False)[1:]
+    out_mask = jnp.arange(out_capacity) < jnp.minimum(num_groups,
+                                                      out_capacity)
 
-    out_mask = jnp.arange(out_capacity) < jnp.minimum(num_groups, out_capacity)
+    def slots(x, empty):
+        return jnp.where(out_mask, _front(x, out_capacity),
+                         jnp.asarray(empty).astype(x.dtype))
+
+    at = len(keys_f)
+    out_keys = [slots(k, 0) for k in moved[:at]]
+    for pos, st in enumerate(scans):
+        if st is not None:
+            _, idents, finish = st
+            out_vals[pos] = finish(
+                [slots(w, e) for w, e in zip(moved[at:], idents)])
+            at += len(idents)
+
     # out_capacity >= n makes overflow statically impossible: report None so
     # the host skips the flag check — a scalar device->host sync costs its
     # fixed latency once per task
@@ -438,8 +576,29 @@ def _grouped_aggregate_on_order(
 # XLA's TPU scatter-add is the segment_sum lowering, and with x64 emulation
 # an int64 segment_sum measured 18M rows/s — and the realistic multi-
 # aggregate shape (8 int64 sums over one segment id vector, TPC-H q1's
-# stage) collapsed to 1M rows/s.  So int64 reductions decompose into exact
-# limbs (i64_sum_path says which way a call goes):
+# stage) collapsed to 1M rows/s.  Two families of kernels avoid it.
+#
+# GROUPS FOUND BY SORTING (grouped_aggregate with keys and no dense domain,
+# grouped_aggregate_presorted): no segment ids at all.  The chip sorts a
+# slot some twenty times faster than it scatters or gathers one, so the
+# sort carries the columns, every reduction is a segmented scan by doubling
+# shifts that leaves a group's state on its run's last row (_run_scan; an
+# int64 as two 32-bit words, sums with the carry taken by hand), and a
+# second sort brings the run ends to the front (_reduce_runs).  On one v5e
+# (PR 33's micro, PERF.md section 6; one int64 key, one int64 sum), at
+# 15.0M slots: the first sort 68 ms, the carry of the live keys 19 ms, the
+# group index 5 ms, the sum 20 ms, the second sort 72 ms, the whole call
+# 170 ms (11 ns a slot), and 411 ms at 30.0M slots; presorted at 2^20
+# slots 3.4 ms.  The form before it (lexsort, a gather of every column by
+# the permutation, then segment ids into the int64 paths below and an int64
+# .at[].set of the keys) took 4.4 s and 10.5 s at those sizes — the three
+# gathers 0.66 and 2.9 s, the int64 segment_sum 1.9 and 3.9 s (125 ns a
+# slot), the key scatter 1.8 and 3.5 s, its lexsort 0.08 and 0.19 s — and
+# 243 ms presorted at 2^20.  Float aggregates alone keep jax.ops.segment_*.
+#
+# GROUPS THAT ARE SLOTS (the dense path: dense_group_states, whose fused key
+# IS the segment id) decompose int64 reductions into exact limbs, and
+# i64_sum_path says which way a call's sums go:
 #
 # - sums into at most _MATMUL_SEG_LIMIT slots ("contraction"): per chunk of
 #   rows ONE contraction on the matrix unit, in a type the unit has — the
@@ -456,15 +615,20 @@ def _grouped_aggregate_on_order(
 #   int32 dot, counts as four more value vectors, rows per slot by an int32
 #   segment_sum — took 85-97 ms, 73 of them the segment_sum (PR 31's
 #   micro, PERF.md section 6).
-# - sums into more slots ("chunk_offset"): chunk-offset int32 segment_sums
-#   of 16-bit limbs, recombined the same way; past the sizes that holds,
-#   the plain int64 segment_sum ("scatter").
-# - min/max: lexicographic two-pass over (hi32, lo32-with-flipped-sign)
-#   int32 segment_min/max; identity values recombine to exactly the int64
-#   idents, so empty slots stay mergeable (mesh pmin/pmax).
+# - sums into more slots, up to the dense domain's 2^16 ("chunk_offset"):
+#   chunk-offset int32 segment_sums of 16-bit limbs, recombined the same
+#   way; past the sizes that holds (2^27 chunk-slots: 2^26 rows into 2^16
+#   slots), the plain int64 segment_sum ("scatter").
+# - min/max (grouped_minmax_i64): lexicographic two-pass over (hi32,
+#   lo32-with-flipped-sign) int32 segment_min/max; identity values
+#   recombine to exactly the int64 idents, so empty slots stay mergeable
+#   (mesh pmin/pmax).
 #
-# The CPU backend keeps plain segment ops (its scatters are fast and the
-# matmul would cost O(n*segments) scalar FLOPs on a host core).
+# Callers of grouped_sums_i64 since PR 33: dense_group_states (through
+# grouped_sums_and_rows_i64) alone, every branch of it.  The CPU backend
+# keeps plain segment ops there (its scatters are fast and the matmul would
+# cost O(n*segments) scalar FLOPs on a host core); the sorting family is
+# the same program on every backend.
 
 
 @lru_cache(maxsize=1)
@@ -491,12 +655,13 @@ _MAX_CHUNKS = 1 << 15
 
 def i64_sum_path(num_segments: int, n: int) -> str:
     """The way the int64 sums (and counts) of ``n`` rows into
-    ``num_segments`` slots go, from what is static: ``"contraction"`` (the
-    matrix unit), ``"chunk_offset"`` (int32 limb segment_sums) or
-    ``"scatter"`` (plain segment ops: the CPU backend, and sizes past what
-    the 32-bit recombination or the chunk-offset ids hold).  The single
-    authority: the kernels branch on it and the operators count
-    ``mxu_grouped_sums`` by it."""
+    ``num_segments`` slots go where the slot IS the segment id (the dense
+    path; groups found by sorting have no segment ids and never ask), from
+    what is static: ``"contraction"`` (the matrix unit), ``"chunk_offset"``
+    (int32 limb segment_sums) or ``"scatter"`` (plain segment ops: the CPU
+    backend, and sizes past what the 32-bit recombination or the
+    chunk-offset ids hold).  The single authority: ``grouped_sums_i64``
+    branches on it and the operators count ``mxu_grouped_sums`` by it."""
     if not _tpu_backend():
         return "scatter"
     n_chunks = -(-n // _SEG_CHUNK)

@@ -476,6 +476,9 @@ class MeshAggregateExec(ExecutionPlan):
                 final_capacity=final_cap, retries=retries)
             overflow, need, groups_max, _groups_out = (int(v) for v in stats)
             self.metrics().add("exchange_collective", 1)
+            # the program's partial and final aggregates (keys, no dense
+            # domain) each reduce runs of equal keys by segmented scans
+            self.metrics().add("run_scan_aggregates", 2)
             if not overflow:
                 return fk, fv, fmask, min(final_cap, _pow2(groups_max))
             del fk, fv, fmask
@@ -574,6 +577,8 @@ class MeshPartialAggregateExec(ExecutionPlan):
             domain = dense_domain(key_ranges)
             if domain is not None:
                 per_dev_cap = min(per_dev_cap, domain)
+            else:
+                self.metrics().add("run_scan_aggregates", 1)
             # one program for a stage's N partition tasks and for every
             # later job of the same plan shape (the lookup tables are
             # operands, so per-partition dictionaries share it too)

@@ -801,10 +801,11 @@ class HashAggregateExec(ExecutionPlan):
         return [result]
 
     def _presorted(self) -> bool:
-        """Clustered single-key partials group WITHOUT sorting (input is in
-        key order by construction; kernels.grouped_aggregate_presorted) —
-        on TPU the sort program is the one that compiles for minutes.
-        ``_no_presort`` latches after a runtime disorder detection."""
+        """Clustered single-key partials group WITHOUT the first sort
+        (input is in key order by construction;
+        kernels.grouped_aggregate_presorted reduces the rows where they
+        lie).  ``_no_presort`` latches after a runtime disorder
+        detection."""
         return (self.mode == "partial"
                 and getattr(self, "clustered", None) is not None
                 and len(self.group_exprs) == 1
@@ -959,6 +960,11 @@ class HashAggregateExec(ExecutionPlan):
             if not self._presorted() and K.i64_sum_path(
                     domain + 1, big.capacity) == "contraction":
                 self.metrics().add("mxu_grouped_sums", 1)
+        if group_c and (domain is None or self._presorted()):
+            # keys and no dense domain (the presorted entry takes no
+            # ranges): the kernel reduces runs of equal keys by segmented
+            # scans and moves no row by an index
+            self.metrics().add("run_scan_aggregates", 1)
         disorder = None
         with self.metrics().timer("agg_time"):
             aux = comp.aux_arrays(big.dicts)

@@ -266,7 +266,7 @@ def test_i64_limb_reductions_match_plain_paths(monkeypatch):
             K.grouped_minmax_i64(vals[0], ok, seg, Sgap, is_min)))
         assert np.array_equal(f, s)
 
-    # full sort-path grouped_aggregate equivalence (cumsum differences)
+    # the sort path (run scans: one program on every backend)
     keys = [jnp.asarray(rng.integers(0, 50, n).astype(np.int64))]
     mask = jnp.asarray(rng.random(n) < 0.9)
     vcols = [(vals[0], K.AGG_SUM), (vals[1], K.AGG_SUM),
@@ -537,17 +537,22 @@ def test_global_aggregate_matches_numpy(case, out_capacity):
             assert got[0] == exp, (how, got[0], exp)
 
 
-def _primitives(jaxpr):
-    """Every primitive's name, through the sub-jaxprs that eqn params hold
-    (pjit, cond branches, loop bodies)."""
+def _equations(jaxpr):
+    """Every equation, through the sub-jaxprs that eqn params hold (pjit,
+    cond branches, loop bodies)."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
+        yield eqn
         for param in eqn.params.values():
             for sub in param if isinstance(param, (tuple, list)) else (param,):
                 if isinstance(sub, ClosedJaxpr):
                     sub = sub.jaxpr
                 if isinstance(sub, Jaxpr):
-                    yield from _primitives(sub)
+                    yield from _equations(sub)
+
+
+def _primitives(jaxpr):
+    """Every primitive's name."""
+    return (eqn.primitive.name for eqn in _equations(jaxpr))
 
 
 def test_keyless_program_is_reductions_into_one_row():
@@ -580,3 +585,214 @@ def test_keyless_program_is_reductions_into_one_row():
            if any(w in p for w in ("scatter", "gather", "sort", "cum"))}
     assert not bad, bad
     assert closed.out_avals and all(a.shape == (1,) for a in closed.out_avals)
+
+
+# --------------------------------------------------------------------------
+# the sort path and the presorted path: runs reduced by segmented scans
+# --------------------------------------------------------------------------
+
+def _run_scan_case(name, rng):
+    """-> (keys: list of int arrays, vals: int64[n], mask, out_capacity)."""
+    i64 = _I64
+    n = 1000
+    keys = [rng.integers(-20, 20, n).astype(np.int64)]
+    vals = rng.integers(-2**40, 2**40, n).astype(np.int64)
+    mask = rng.random(n) < 0.7
+    cap = 64
+    if name == "three_keys":
+        keys = [rng.integers(-3, 3, n).astype(np.int64),
+                rng.integers(0, 4, n).astype(np.int32),
+                rng.integers(-2, 2, n).astype(np.int64)]
+        cap = 128
+    elif name == "extreme_keys_live":
+        keys = [rng.choice(np.array([i64.min, i64.min + 1, -1, 0, 1,
+                                     i64.max - 1, i64.max]), n)]
+    elif name == "all_dead":
+        mask = np.zeros(n, bool)
+    elif name == "one_group":
+        keys = [np.full(n, -7, np.int64)]
+    elif name == "one_live_row":
+        mask = np.zeros(n, bool)
+        mask[n // 3] = True
+    elif name == "every_row_its_own_group":
+        keys = [rng.permutation(n).astype(np.int64) - n // 2]
+        mask = np.ones(n, bool)
+        cap = n
+    elif name == "capacity_past_the_rows":
+        cap = n + 24
+    elif name == "capacity_under_the_groups":
+        cap = 7
+    elif name == "sums_past_2_32":
+        vals = rng.integers(2**31, 2**33, n).astype(np.int64)
+    elif name == "sums_wrap_mod_2_64":
+        keys = [rng.integers(0, 3, n).astype(np.int64)]
+        vals = rng.choice(np.array([i64.max, i64.min, i64.max - 5, -1, 1]),
+                          n)
+    elif name == "rows_not_a_power_of_two":
+        n = 777
+        keys = [keys[0][:n]]
+        vals, mask = vals[:n], mask[:n]
+    elif name == "two_rows":
+        n = 2
+        keys, vals = [np.array([5, 5], np.int64)], vals[:n]
+        mask = np.array([True, True])
+        cap = 2
+    elif name != "dead_rows_interleaved":
+        raise AssertionError(name)
+    return keys, vals, mask, cap
+
+
+_RUN_SCAN_CASES = [
+    "dead_rows_interleaved", "three_keys", "extreme_keys_live", "all_dead",
+    "one_group", "one_live_row", "every_row_its_own_group",
+    "capacity_past_the_rows", "capacity_under_the_groups", "sums_past_2_32",
+    "sums_wrap_mod_2_64", "rows_not_a_power_of_two", "two_rows"]
+
+
+def _expected_groups(keys, vals, mask):
+    """numpy/pandas: groups in ascending key order, int64 sums mod 2^64."""
+    df = pd.DataFrame({f"k{i}": k[mask] for i, k in enumerate(keys)})
+    df["v"] = vals[mask]
+    names = [f"k{i}" for i in range(len(keys))]
+    rows = []
+    for key, g in df.groupby(names, sort=True):
+        v = g["v"].to_numpy()
+        with np.errstate(over="ignore"):
+            s = np.add.reduce(v, dtype=np.int64)
+        rows.append((key if isinstance(key, tuple) else (key,),
+                     int(s), len(v), int(v.min()), int(v.max())))
+    return rows
+
+
+def _check_groups(out, keys, vals, mask, cap):
+    out_keys, out_vals, out_mask, overflow = out
+    exp = _expected_groups(keys, vals, mask)
+    n = len(mask)
+    if cap >= n:
+        assert overflow is None
+    else:
+        assert bool(overflow) == (len(exp) > cap)
+    kept = exp[:cap]
+    m = np.asarray(out_mask)
+    assert m.shape == (cap,) and m.tolist() == \
+        [True] * len(kept) + [False] * (cap - len(kept))
+    for i, k in enumerate(out_keys):
+        assert k.shape == (cap,) and k.dtype == keys[i].dtype
+        assert np.asarray(k)[m].tolist() == [r[0][i] for r in kept]
+    s, c, mn, mx = (np.asarray(v) for v in out_vals)
+    for got, col in ((s, 1), (c, 2), (mn, 3), (mx, 4)):
+        assert got.dtype == np.int64 and got.shape == (cap,)
+        assert got[m].tolist() == [r[col] for r in kept]
+    # an empty slot holds what a merge can take: the identities
+    assert (s[~m] == 0).all() and (c[~m] == 0).all()
+    assert (mn[~m] == _I64.max).all() and (mx[~m] == _I64.min).all()
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu_branches"])
+@pytest.mark.parametrize("case", _RUN_SCAN_CASES)
+def test_sort_path_groups_match_pandas(request, rng, case, backend):
+    """``grouped_aggregate`` with keys and no dense domain: int64 sum,
+    count, min and max of every group, keys in ascending order at the front
+    of ``out_capacity`` slots, the overflow contract."""
+    if backend == "tpu_branches":
+        request.getfixturevalue("tpu_branches")
+    keys, vals, mask, cap = _run_scan_case(case, rng)
+    v = jnp.asarray(vals)
+    out = jax.jit(
+        lambda ks, v, m: K.grouped_aggregate(
+            ks, [(v, K.AGG_SUM), (v, K.AGG_COUNT), (v, K.AGG_MIN),
+                 (v, K.AGG_MAX)], m, cap))(
+        [jnp.asarray(k) for k in keys], v, jnp.asarray(mask))
+    _check_groups(out, keys, vals, mask, cap)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu_branches"])
+@pytest.mark.parametrize("case", [
+    c for c in _RUN_SCAN_CASES if c != "three_keys"] + ["disorder"])
+def test_presorted_groups_match_pandas(request, rng, case, backend):
+    """``grouped_aggregate_presorted`` on rows whose live keys are in
+    order, dead rows lying between them: the same groups, and ``disorder``
+    False; on rows out of order the flag and nothing else is promised."""
+    if backend == "tpu_branches":
+        request.getfixturevalue("tpu_branches")
+    keys, vals, mask, cap = _run_scan_case(
+        "dead_rows_interleaved" if case == "disorder" else case, rng)
+    k = keys[0].copy()
+    live = np.flatnonzero(mask)
+    if case == "disorder":
+        k[live[0]] = k[live].max() + 1    # the first live key is the largest
+    else:
+        k[live] = np.sort(k[live])        # dead rows keep whatever they hold
+    v = jnp.asarray(vals)
+    *out, disorder = jax.jit(
+        lambda k, v, m: K.grouped_aggregate_presorted(
+            [k], [(v, K.AGG_SUM), (v, K.AGG_COUNT), (v, K.AGG_MIN),
+                  (v, K.AGG_MAX)], m, cap))(jnp.asarray(k), v,
+                                            jnp.asarray(mask))
+    assert bool(disorder) == (case == "disorder")
+    if case != "disorder":
+        _check_groups(out, [k], vals, mask, cap)
+
+
+def _moved_by_index(jaxpr, n):
+    """(primitive, operand shapes) of every scatter, and of every gather
+    whose operand has ``n`` or more elements."""
+    out = []
+    for eqn in _equations(jaxpr):
+        name = eqn.primitive.name
+        shapes = [v.aval.shape for v in eqn.invars]
+        if "scatter" in name or (
+                "gather" in name and int(np.prod(shapes[0])) >= n):
+            out.append((name, shapes))
+    return out
+
+
+@pytest.mark.parametrize("entry", ["sort_path", "sort_path_three_keys",
+                                   "presorted"])
+def test_run_scan_program_moves_no_row_by_index(tpu_branches, entry):
+    """int64 sum, count, min and max over a batch of 2^20 slots: one sort
+    (none presorted) that carries the columns, scans, one sort that brings
+    the run ends to the front — no scatter, no gather of a column, no
+    ``cumsum`` (which compiles for 5-25 s a shape for the TPU)."""
+    n = 1 << 20
+    hows = [K.AGG_SUM, K.AGG_COUNT, K.AGG_MIN, K.AGG_MAX]
+    i64 = jax.ShapeDtypeStruct((n,), np.int64)
+    keys = [i64, jax.ShapeDtypeStruct((n,), np.int32), i64][
+        :3 if entry == "sort_path_three_keys" else 1]
+    fn = K.grouped_aggregate_presorted if entry == "presorted" \
+        else K.grouped_aggregate
+    closed = jax.make_jaxpr(
+        lambda ks, v, m: fn(ks, [(v, h) for h in hows], m, n))(
+        keys, i64, jax.ShapeDtypeStruct((n,), np.bool_))
+    assert not _moved_by_index(closed.jaxpr, n)
+    prims = list(_primitives(closed.jaxpr))
+    assert prims.count("sort") == (1 if entry == "presorted" else 2)
+    assert not [p for p in prims if p.startswith("cum")]
+
+
+def test_exchange_program_scatters_only_into_its_send_buckets(tpu_branches):
+    """``distributed_filter_aggregate`` (q18's inner aggregate: one int64
+    key, one int64 sum) over four devices: the partial and the final
+    aggregate scatter and gather nothing; what is left is
+    ``dispatch_to_buckets``' own (the key, the state and the mask byte into
+    the send buffer, and its four ``cumsum``s)."""
+    from jax.sharding import Mesh
+
+    from arrow_ballista_tpu.ops.mesh_exec import _exchange_bounds
+    from arrow_ballista_tpu.parallel import distributed
+
+    per = 1 << 12
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("part",))
+    partial, shuffle, final = _exchange_bounds(per, 4)
+    run = distributed.distributed_grouped_aggregate(
+        mesh, ["k"], [("v", "sum")], partial_capacity=partial,
+        final_capacity=final, axis="part", shuffle_capacity=shuffle)
+    cols = {c: jax.ShapeDtypeStruct((4 * per,), np.int64) for c in "kv"}
+    closed = jax.make_jaxpr(run.jit.__wrapped__)(
+        cols, jax.ShapeDtypeStruct((4 * per,), np.bool_))
+    moved = _moved_by_index(closed.jaxpr, per)
+    send = (4 * shuffle + 1,)
+    assert sorted(name for name, _ in moved) == ["scatter"] * 3, moved
+    assert all(shapes[0] == send for _, shapes in moved), moved
+    prims = list(_primitives(closed.jaxpr))
+    assert prims.count("sort") == 4 and prims.count("cumsum") == 4

@@ -112,6 +112,9 @@ def test_inner_aggregate_with_more_groups_than_agg_capacity(
 
     (metrics,) = _mesh_aggregates(report)
     assert metrics["exchange_collective"] == 1
+    # the exchange's partial and final aggregates, by run scans both
+    assert metrics["run_scan_aggregates"] == 2
+    assert "mxu_grouped_sums" not in metrics
     assert "exchange_retries" not in metrics
     assert metrics["mesh_devices"] == 4
     s1 = device_obs.STATS.snapshot()

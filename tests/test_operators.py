@@ -176,6 +176,32 @@ def test_mxu_grouped_sums_counts_kernel_calls_on_the_matrix_unit(
             == df.groupby(name).size().to_dict()
 
 
+@pytest.mark.parametrize("case,expected", [
+    ("sort_path_sums", 2),
+    ("dense_domain_sums_and_counts", 0),
+    ("keyless", 0),
+])
+def test_run_scan_aggregates_counts_keyed_non_dense_kernel_calls(
+        case, expected):
+    """One a kernel call whose groups were runs of equal keys reduced by
+    segmented scans: keys present and no dense domain (what
+    ``kernels.grouped_aggregate`` branches on), on any backend; q1's
+    dictionary keys (a dense domain) and q6 (no keys) never."""
+    df = lineitem_like()
+    aggs = [AggSpec("sum", E.Column("qty"), "s"), AggSpec("count", None, "c")]
+    keys = {"dense_domain_sums_and_counts": [(E.Column("flag"), "flag")],
+            "sort_path_sums": [(E.Column("k"), "k")],
+            "keyless": []}[case]
+    partial = HashAggregateExec(scan_of(df, 2), keys, aggs, "partial")
+    got = run_all(partial)
+    assert partial.metrics().to_dict().get("run_scan_aggregates", 0) \
+        == expected
+    if keys:
+        name = keys[0][1]
+        assert got.groupby(name)["c"].sum().to_dict() \
+            == df.groupby(name).size().to_dict()
+
+
 @pytest.mark.parametrize("mode", ["single", "final"])
 def test_keyless_aggregate_over_empty_input_is_one_row(mode):
     """SQL: count = 0 and sum/min/max = NULL, from 'single' directly and

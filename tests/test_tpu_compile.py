@@ -152,6 +152,26 @@ def test_grouped_minmax_i64_tpu_branch(one_chip, tpu_branches, is_min):
         v, ok, seg)
 
 
+@pytest.mark.parametrize("entry", ["sort_path", "presorted"])
+def test_run_scan_aggregate_sf1_batch(one_chip, tpu_branches, entry):
+    """q18's partial aggregate over one lineitem batch at SF1 (2^20 slots,
+    ``l_orderkey`` -> ``sum(l_quantity)``, as many group slots), by the sort
+    path and by the presorted entry (``agg_grouped__partial_k1_presorted``,
+    the first of ``sf1_join``'s device seconds): sorts and scans, nothing
+    scattered and nothing gathered."""
+    fn = K.grouped_aggregate_presorted if entry == "presorted" \
+        else K.grouped_aggregate
+    text = compile_for_chip(
+        f"grouped_aggregate, {entry}, 2^20 slots",
+        lambda k, v, m: fn([k], [(v, K.AGG_SUM)], m, BATCH),
+        sds((BATCH,), jnp.int64, one_chip), sds((BATCH,), jnp.int64, one_chip),
+        sds((BATCH,), jnp.bool_, one_chip)).as_text()
+    assert not has_scatter(text)
+    assert re.search(r"\bgather\(", text) is None
+    assert len(re.findall(r"\bsort\(", text)) >= (
+        1 if entry == "presorted" else 2)
+
+
 def test_pack_for_host_mixed_columns(one_chip):
     """int64, f64 and 32-bit columns in one packed transfer: the s32<->s64
     bitcasts under x64 emulation and the separate f64 leaf."""
@@ -308,8 +328,8 @@ def test_mesh_exchange_at_sf10_q18_shards(topo, tpu_branches):
     """The same at the benchmark cell's shapes (``sf10_mesh4_q18``:
     60 030 976 scanned slots, 15 007 744 a device; send buckets of
     7 503 872 states, 30 015 488 final slots a device): the chips' compiler
-    takes it (3.5 minutes here, so not in Tier-1), and what it asks of a
-    chip (2 GB) leaves room beside chip 0's scan cache in 16 GB."""
+    takes it (minutes here, so not in Tier-1), and what it asks of a chip
+    leaves room beside chip 0's scan cache in 16 GB."""
     mem = _compile_exchange(topo, 15_007_744)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         + mem.output_size_in_bytes < 4 << 30
