@@ -641,34 +641,49 @@ class HashAggregateExec(ExecutionPlan):
         ranges = cl[2] if cl is not None and len(cl) > 2 else None
         if not ranges or not (0 <= partition < len(ranges)):
             return None
-        comp, group_c = self._compiled[0], self._compiled[1]
-        kc, key_name = group_c[0]
+        comp = self._compiled[0]
         with self.xla_lock():
             if getattr(self, "_range_check", None) is None:
-                field = self._schema.field(key_name)
-                # NULL keys ride an in-band sentinel that parquet min/max
-                # stats exclude — it must not trip the range check
-                sent = int(field.dtype.null_sentinel) if field.nullable \
-                    else None
-
-                def check(cols, mask, aux, lo, hi):
-                    k = kc.fn(cols, aux)
-                    if k.ndim == 0:
-                        k = jnp.broadcast_to(k, mask.shape)
-                    k = k.astype(jnp.int64)
-                    live = mask if sent is None else mask & (k != sent)
-                    kmin = jnp.min(jnp.where(live, k,
-                                             jnp.iinfo(jnp.int64).max))
-                    kmax = jnp.max(jnp.where(live, k,
-                                             jnp.iinfo(jnp.int64).min))
-                    return jnp.any(live) & ((kmin < lo) | (kmax > hi))
-
-                self._range_check = observed_jit("sort.range_check", check)
+                self._range_check = self._make_range_check(comp.schema)
         lo, hi = ranges[partition]
         aux = comp.aux_arrays(big.dicts)
+        # host scalars: an eager jnp.asarray of a Python int is a program
+        # of its own (convert_element_type) per bound per task
         return self._range_check(big.columns, big.mask, aux,
-                                 jnp.asarray(int(lo), jnp.int64),
-                                 jnp.asarray(int(hi), jnp.int64))
+                                 np.int64(lo), np.int64(hi))
+
+    def _make_range_check(self, in_schema):
+        """The range check's program, shared across jobs like the
+        aggregate's own (``_make_compiled``): it bakes in the compiled
+        group key and the key's NULL sentinel, nothing of the job.  The
+        key expression is a bare integer column (the planner annotates
+        nothing else), so ``kc`` reads no aux slot and any instance's
+        compiler serves it."""
+        kc, key_name = self._compiled[1][0]
+        field = self._schema.field(key_name)
+        # NULL keys ride an in-band sentinel that parquet min/max
+        # stats exclude — it must not trip the range check
+        sent = int(field.dtype.null_sentinel) if field.nullable else None
+
+        def build():
+            def check(cols, mask, aux, lo, hi):
+                k = kc.fn(cols, aux)
+                if k.ndim == 0:
+                    k = jnp.broadcast_to(k, mask.shape)
+                k = k.astype(jnp.int64)
+                live = mask if sent is None else mask & (k != sent)
+                kmin = jnp.min(jnp.where(live, k,
+                                         jnp.iinfo(jnp.int64).max))
+                kmax = jnp.max(jnp.where(live, k,
+                                         jnp.iinfo(jnp.int64).min))
+                return jnp.any(live) & ((kmin < lo) | (kmax > hi))
+
+            return observed_jit("sort.range_check", check)
+
+        return self._shared(
+            ("agg.range_check", schema_sig(in_schema),
+             exprs_sig([self.group_exprs[0][0]]), key_name,
+             "not null" if sent is None else sent), build)
 
     def _apply_clustered_filter(self, ctx, result, disorder, mismatch=None):
         """Clustered group-by early-HAVING (see
@@ -681,22 +696,6 @@ class HashAggregateExec(ExecutionPlan):
         pred_expr, intervals = self.clustered[0], self.clustered[1]
         with self.xla_lock():
             if getattr(self, "_cl_compiled", None) is None:
-                comp = ExprCompiler(self._schema, "device")
-                pred = comp.compile_pred(
-                    _substitute_scalars(pred_expr, ctx.scalars))
-                key_name = self.group_exprs[0][1]
-
-                def keep_fn(cols, mask, aux, los, his):
-                    k = cols[key_name]
-                    shared = jnp.any(
-                        (k[:, None] >= los[None, :])
-                        & (k[:, None] <= his[None, :]), axis=1)
-                    keep = mask & (shared | pred.fn(cols, aux))
-                    # live count rides along: the result is tiny by
-                    # construction, so one scalar sync buys a shrink that
-                    # saves the shuffle writer a full-capacity repartition
-                    return keep, jnp.sum(keep)
-
                 # pad the window vectors to a power of two so every
                 # partition (and every instance at this schema) shares one
                 # compiled shape
@@ -708,9 +707,9 @@ class HashAggregateExec(ExecutionPlan):
                 his = np.full(padn, 0, dtype=np.int64)  # empty: lo > hi
                 for i, (lo, hi) in enumerate(intervals):
                     los[i], his[i] = lo, hi
-                self._cl_compiled = (comp,
-                                     observed_jit("agg.clustered_keep",
-                                                  keep_fn),
+                comp, keep_fn = self._make_clustered_keep(
+                    _substitute_scalars(pred_expr, ctx.scalars), padn)
+                self._cl_compiled = (comp, keep_fn,
                                      jnp.asarray(los), jnp.asarray(his))
         comp, keep_fn, los, his = self._cl_compiled
         aux = comp.aux_arrays(result.dicts)
@@ -741,48 +740,40 @@ class HashAggregateExec(ExecutionPlan):
                           result.dicts, num_rows=int(live_v))
         return out.shrink()
 
+    def _make_clustered_keep(self, pred_expr, padn):
+        """The early filter's compiler and program, shared across jobs:
+        they bake in the HAVING predicate as this job sees it (scalars
+        substituted: two jobs whose constant differs never meet in one
+        program) and the key's name; the windows stay arguments."""
+        key_name = self.group_exprs[0][1]
+
+        def build():
+            comp = ExprCompiler(self._schema, "device")
+            pred = comp.compile_pred(pred_expr)
+
+            def keep_fn(cols, mask, aux, los, his):
+                k = cols[key_name]
+                shared = jnp.any(
+                    (k[:, None] >= los[None, :])
+                    & (k[:, None] <= his[None, :]), axis=1)
+                keep = mask & (shared | pred.fn(cols, aux))
+                # live count rides along: the result is tiny by
+                # construction, so one scalar sync buys a shrink that
+                # saves the shuffle writer a full-capacity repartition
+                return keep, jnp.sum(keep)
+
+            return comp, observed_jit("agg.clustered_keep", keep_fn)
+
+        if has_scalar_subquery(self.clustered[0]):
+            return build()
+        return shared_program(
+            ("agg.clustered_keep", schema_sig(self._schema),
+             exprs_sig([pred_expr]), key_name, padn), build)
+
     def _execute_passthrough(self, ctx, big, in_schema):
         with self.xla_lock():
             if getattr(self, "_pt_compiled", None) is None:
-                comp = ExprCompiler(in_schema, "device")
-                group_c = [(comp.compile(_substitute_scalars(e, ctx.scalars)), n)
-                           for e, n in self.group_exprs]
-                agg_items = []
-                for a in self.aggs:
-                    f = self._schema.field(a.name)
-                    cc = comp.compile(_substitute_scalars(a.operand, ctx.scalars)) \
-                        if a.operand is not None else None
-                    nc = null_check_of(cc, a.operand, in_schema)
-                    agg_items.append((cc, a.func, a.name, nc, f.dtype))
-
-                def pt_fn(cols, mask, aux):
-                    out = {}
-                    for c, n in group_c:
-                        k = c.fn(cols, aux)
-                        out[n] = jnp.broadcast_to(k, mask.shape) if k.ndim == 0 else k
-                    for cc, how, name, nc, dt in agg_items:
-                        np_dt = dt.np_dtype
-                        if cc is None:  # count(*): one per row
-                            out[name] = jnp.ones(mask.shape, np_dt)
-                            continue
-                        v = cc.fn(cols, aux)
-                        if v.ndim == 0:
-                            v = jnp.broadcast_to(v, mask.shape)
-                        valid = valid_of(v, nc) if nc is not None else None
-                        if how == "count":
-                            ones = jnp.ones(mask.shape, np_dt)
-                            out[name] = (jnp.where(valid, ones, 0)
-                                         if valid is not None else ones)
-                        else:  # sum/min/max state = the value (NULL -> sentinel)
-                            v = v.astype(np_dt)
-                            if valid is not None:
-                                sent = jnp.asarray(dt.null_sentinel, dtype=np_dt)
-                                v = jnp.where(valid, v, sent)
-                            out[name] = v
-                    return out
-
-                self._pt_compiled = (comp, group_c,
-                                     observed_jit("agg.passthrough", pt_fn))
+                self._pt_compiled = self._make_passthrough(ctx, in_schema)
         comp, group_c, ptfn = self._pt_compiled
         with self.metrics().timer("agg_time"):
             aux = comp.aux_arrays(big.dicts)
@@ -799,6 +790,55 @@ class HashAggregateExec(ExecutionPlan):
         else:
             deferred_rows(self.metrics(), "output_rows", result)
         return [result]
+
+    def _make_passthrough(self, ctx, in_schema):
+        """The per-row states' compiler and program, shared across jobs
+        as ``_make_compiled`` shares the aggregate's; the output schema
+        is in the key for the state dtypes it bakes in."""
+        return self._shared(
+            ("agg.passthrough", schema_sig(self._schema))
+            + self._exprs_key(in_schema),
+            lambda: self._build_passthrough(ctx, in_schema))
+
+    def _build_passthrough(self, ctx, in_schema):
+        comp = ExprCompiler(in_schema, "device")
+        group_c = [(comp.compile(_substitute_scalars(e, ctx.scalars)), n)
+                   for e, n in self.group_exprs]
+        agg_items = []
+        for a in self.aggs:
+            f = self._schema.field(a.name)
+            cc = comp.compile(_substitute_scalars(a.operand, ctx.scalars)) \
+                if a.operand is not None else None
+            nc = null_check_of(cc, a.operand, in_schema)
+            agg_items.append((cc, a.func, a.name, nc, f.dtype))
+
+        def pt_fn(cols, mask, aux):
+            out = {}
+            for c, n in group_c:
+                k = c.fn(cols, aux)
+                out[n] = jnp.broadcast_to(k, mask.shape) if k.ndim == 0 else k
+            for cc, how, name, nc, dt in agg_items:
+                np_dt = dt.np_dtype
+                if cc is None:  # count(*): one per row
+                    out[name] = jnp.ones(mask.shape, np_dt)
+                    continue
+                v = cc.fn(cols, aux)
+                if v.ndim == 0:
+                    v = jnp.broadcast_to(v, mask.shape)
+                valid = valid_of(v, nc) if nc is not None else None
+                if how == "count":
+                    ones = jnp.ones(mask.shape, np_dt)
+                    out[name] = (jnp.where(valid, ones, 0)
+                                 if valid is not None else ones)
+                else:  # sum/min/max state = the value (NULL -> sentinel)
+                    v = v.astype(np_dt)
+                    if valid is not None:
+                        sent = jnp.asarray(dt.null_sentinel, dtype=np_dt)
+                        v = jnp.where(valid, v, sent)
+                    out[name] = v
+            return out
+
+        return comp, group_c, observed_jit("agg.passthrough", pt_fn)
 
     def _presorted(self) -> bool:
         """Clustered single-key partials group WITHOUT the first sort
@@ -822,21 +862,29 @@ class HashAggregateExec(ExecutionPlan):
         """Build (or fetch shared) compiled closures and RETURN them —
         callers assign to self._compiled in one atomic statement so
         concurrent tasks never observe a half-published state."""
-        all_exprs = [e for e, _ in self.group_exprs] + \
-            [a.operand for a in self.aggs]
-        if not has_scalar_subquery(*all_exprs):
-            # job-independent program: share across jobs (re-running a
-            # query re-traces every program otherwise, ~0.2 s each on
-            # the remote TPU backend)
-            key = ("agg", self.mode, self._presorted(),
-                   schema_sig(in_schema),
-                   exprs_sig([e for e, _ in self.group_exprs]),
-                   tuple(n for _, n in self.group_exprs),
-                   tuple((a.func, a.name) for a in self.aggs),
-                   exprs_sig([a.operand for a in self.aggs]))
-            return shared_program(
-                key, lambda: self._build_compiled(ctx, in_schema))
-        return self._build_compiled(ctx, in_schema)
+        return self._shared(
+            ("agg", self.mode, self._presorted()) + self._exprs_key(
+                in_schema),
+            lambda: self._build_compiled(ctx, in_schema))
+
+    def _shared(self, key, build):
+        """``build()`` shared across jobs under ``key`` (re-running a query
+        re-traces every program otherwise, ~0.2 s each on the remote TPU
+        backend), unless a group key or operand holds a scalar subquery:
+        a compiled closure bakes its value in per job (``ctx.scalars``)."""
+        if has_scalar_subquery(*[e for e, _ in self.group_exprs],
+                               *[a.operand for a in self.aggs]):
+            return build()
+        return shared_program(key, build)
+
+    def _exprs_key(self, in_schema) -> tuple:
+        """What a closure over this aggregate's keys and operands bakes
+        in, as part of a ``shared_program`` key."""
+        return (schema_sig(in_schema),
+                exprs_sig([e for e, _ in self.group_exprs]),
+                tuple(n for _, n in self.group_exprs),
+                tuple((a.func, a.name) for a in self.aggs),
+                exprs_sig([a.operand for a in self.aggs]))
 
     def _ensure_compiled(self, ctx, in_schema):
         if self._compiled is None:
@@ -1488,6 +1536,33 @@ class JoinExec(ExecutionPlan):
     #: alone, so peak build memory is ~1/8th of the in-memory path
     _SPILL_PARTS = 8
 
+    def _make_spill_part(self, rsch):
+        """The spill partitioner's compiler and program, shared across
+        jobs: they bake in the build side's key expressions and the
+        partition count.  The keys compile against a compiler of their
+        own: the join's is shared, and a string key registers aux slots
+        on the compiler it compiles against."""
+        rexprs = [re_ for _, re_ in self.on]
+        nparts = self._SPILL_PARTS
+
+        def build():
+            pcomp = ExprCompiler(rsch, "device")
+            rkeys = [pcomp.compile_key(re_) for re_ in rexprs]
+            bits = (nparts - 1).bit_length()
+
+            def part_fn(bcols, bmask, raux):
+                h = K.hash64([c.fn(bcols, raux) for c in rkeys])
+                # arithmetic shift + mask = top ``bits`` bits
+                return ((h >> (64 - bits)) & (nparts - 1)).astype(jnp.int32)
+
+            return pcomp, observed_jit("join.spill_part", part_fn)
+
+        if has_scalar_subquery(*rexprs):
+            return build()
+        return shared_program(
+            ("join.spill_part", schema_sig(rsch), exprs_sig(rexprs), nparts),
+            build)
+
     def _join_spilled(self, ctx, probe, build_parts, lsch, rsch):
         """Reservation denied: partitioned-build spill for
         inner/semi/anti.  Build batches are split by the TOP BITS OF THE
@@ -1513,19 +1588,10 @@ class JoinExec(ExecutionPlan):
 
         with self.xla_lock():
             self._ensure_compiled(ctx, lsch, rsch)
-            if getattr(self, "_spill_pfn", None) is None:
-                rcomp = self._compiled[1]
-                rkeys = [rcomp.compile_key(re_) for _, re_ in self.on]
-                bits = (self._SPILL_PARTS - 1).bit_length()
-
-                def part_fn(bcols, bmask, raux):
-                    h = K.hash64([c.fn(bcols, raux) for c in rkeys])
-                    # arithmetic shift + mask = top ``bits`` bits
-                    return ((h >> (64 - bits))
-                            & (self._SPILL_PARTS - 1)).astype(jnp.int32)
-
-                self._spill_pfn = observed_jit("join.spill_part", part_fn)
+            if getattr(self, "_spill_part", None) is None:
+                self._spill_part = self._make_spill_part(rsch)
         lcomp, rcomp, fcomp, jfn, cfn, pfn = self._compiled
+        pcomp, part_fn = self._spill_part
         nparts = self._SPILL_PARTS
         spiller = Spiller(ctx.work_dir, ctx.job_id, tag="join")
         runs: List[list] = [[] for _ in range(nparts)]
@@ -1533,8 +1599,8 @@ class JoinExec(ExecutionPlan):
             with self.metrics().timer("join_time"):
                 for b in build_parts:
                     ctx.check_cancelled()
-                    part = self._spill_pfn(b.columns, b.mask,
-                                           rcomp.aux_arrays(b.dicts))
+                    part = part_fn(b.columns, b.mask,
+                                   pcomp.aux_arrays(b.dicts))
                     cols, _n = b.packed_numpy(extra32={"__part": part})
                     pids = cols.pop("__part")
                     for p in range(nparts):

@@ -334,3 +334,199 @@ def test_stale_declared_ranges_disable_early_filter(tmp_path):
             "stale ranges must disable the early filter"
     finally:
         cluster.shutdown()
+
+
+# --- no closure of the clustered path is built per operator instance -------
+
+_KEYED = ("sort.range_check", "agg.clustered_keep")
+
+
+def _built(since):
+    """What the process built since ``since`` = (STATS snapshot, ns):
+    programs traced (compiles and retraces), closures ``shared_program``
+    had to build, and the signatures of the ``compile`` spans."""
+    from arrow_ballista_tpu.obs import device as device_obs
+    from arrow_ballista_tpu.obs.tracing import RING
+
+    s0, t0 = since
+    s1 = device_obs.STATS.snapshot()
+    delta = {k: s1[k] - s0[k] for k in ("jit_compiles", "jit_retraces",
+                                        "program_cache_misses")}
+    sigs = {s.attrs.get("sig") for s in RING.snapshot()
+            if s.name.startswith("compile ") and s.start_ns >= t0}
+    return (delta["jit_compiles"] + delta["jit_retraces"],
+            delta["program_cache_misses"], sigs)
+
+
+def _mark():
+    import time
+
+    from arrow_ballista_tpu.obs import device as device_obs
+
+    return device_obs.STATS.snapshot(), time.time_ns()
+
+
+def _cold_program_cache():
+    """Other tests of this process have run the same query: start the
+    closures cold, so that the first run is seen to build them."""
+    from arrow_ballista_tpu.ops import physical
+
+    with physical._program_cache_lock:
+        physical._program_cache.clear()
+
+
+def _tpch_twice(tmp_path):
+    """q3 and q18 as the chip benchmark's join mix runs them, at SF0.2: at
+    test_correct.py's SF0.02 lineitem is one row group and the planner
+    annotates nothing."""
+    import os
+
+    from benchmarks.chip import compare, datagen, traffic
+    from benchmarks.chip.oracles import q3 as oracle_q3, q18 as oracle_q18
+
+    tables = ["customer", "lineitem", "orders"]
+    ddir = datagen.write_data(str(tmp_path), 0.2, 2147496535, tables)
+    ctx = BallistaContext.standalone(
+        BallistaConfig({"ballista.shuffle.partitions": "8"}),
+        concurrent_tasks=4)
+    for t in tables:
+        ctx.register_parquet(t, os.path.join(ddir, f"{t}.parquet"))
+    want = {"q3": oracle_q3.answer(ddir), "q18": oracle_q18.answer(ddir)}
+
+    def run():
+        for q in ("q3", "q18"):
+            got = compare.table_rows(
+                ctx.sql(traffic.load_query(q)["sql"]).to_arrow())
+            fault, _gap = compare.compare(got, want[q])
+            assert fault is None, (q, fault)
+
+    return ctx, run
+
+
+def _clustered_twice(tmp_path, partitions):
+    path = str(tmp_path / "t.parquet")
+    ora = _oracle(_write_clustered(path))
+    ctx = BallistaContext.standalone(
+        BallistaConfig({"ballista.shuffle.partitions": partitions}),
+        concurrent_tasks=2)
+    ctx.register_parquet("t", path)
+
+    def run():
+        out = ctx.sql(SQL).to_pandas()
+        assert out.k.tolist() == ora.index.tolist()
+        assert out.sq.tolist() == ora.values.tolist()
+
+    return ctx, run
+
+
+@pytest.mark.parametrize("case", ["partitions_4", "partitions_auto",
+                                  "tpch_q3_q18"])
+def test_second_run_builds_no_program(tmp_path, case):
+    """Plan instances are per job; every closure of the clustered path is
+    keyed in ``shared_program``, so the second run of a statement traces
+    nothing and builds nothing."""
+    if case == "tpch_q3_q18":
+        ctx, run = _tpch_twice(tmp_path)
+    else:
+        ctx, run = _clustered_twice(tmp_path, case.split("_")[1])
+    try:
+        _cold_program_cache()
+        first = _mark()
+        run()
+        _traced, misses, sigs = _built(first)
+        assert misses > 0
+        if case != "partitions_auto":  # auto: one partition, no annotation
+            assert set(_KEYED) <= sigs, sigs
+        second = _mark()
+        run()
+        traced, misses, sigs = _built(second)
+        assert (traced, misses, sigs) == (0, 0, set())
+    finally:
+        ctx.shutdown()
+
+
+def _write_nullable(path):
+    """``_write_clustered``'s rows with a NULL key every 997th row."""
+    df = _write_clustered(path)
+    null = np.zeros(len(df), dtype=bool)
+    null[50::997] = True
+    pq.write_table(pa.table({
+        "k": pa.array(df.k.to_numpy(), type=pa.int64(), mask=null),
+        "q": pa.array(df.q.to_numpy())}), path, row_group_size=1000)
+    df = df.astype({"k": "float64"})
+    df.loc[null, "k"] = np.nan
+    return df
+
+
+def _answer(ctx, key, limit):
+    out = ctx.sql(f"select {key}, sum(q) as sq from t group by {key} "
+                  f"having sum(q) > {limit} order by {key}").to_pandas()
+    return _nulls_last((None if np.isnan(k) else int(k), int(v))
+                       for k, v in zip(out[key], out.sq))
+
+
+def _nulls_last(rows):
+    return sorted(rows, key=lambda r: (r[0] is None, r[0]))
+
+
+def _expected(df, key, limit):
+    g = df.groupby(key, dropna=False).q.sum()
+    g = g[g > limit]
+    return _nulls_last((None if np.isnan(k) else int(k), int(v))
+                       for k, v in g.items())
+
+
+@pytest.mark.parametrize("case", ["having_constant", "group_key_column",
+                                  "nullable_key"])
+def test_shared_closures_are_never_stale(tmp_path, case):
+    """Two statements whose early filter or range check differ must not
+    meet in one program: each answer is the oracle's, the second statement
+    builds closures of its own, and its early filter still engages (a range
+    check compiled for another key would see a mismatch and latch off)."""
+    path, path2 = str(tmp_path / "t.parquet"), str(tmp_path / "t2.parquet")
+    df = _write_clustered(path)
+    if case == "group_key_column":
+        df["k2"] = df.k * 3 + 10_000    # clustered too, other ranges
+        pq.write_table(pa.Table.from_pandas(df, preserve_index=False), path,
+                       row_group_size=1000)
+    # (file, key, HAVING constant) of the two statements
+    a, b = {"having_constant": ((path, "k", 150), (path, "k", 100)),
+            "group_key_column": ((path, "k", 150), (path, "k2", 150)),
+            "nullable_key": ((path, "k", 150), (path2, "k", 150))}[case]
+    frames = {path: df}
+    if case == "nullable_key":
+        frames[path2] = _write_nullable(path2)
+
+    def early_filters(ctx):
+        sched = ctx._standalone.scheduler
+        graph = sched.jobs.get_graph(list(sched.jobs._status)[-1])
+        return sum(v for st in graph.stages.values()
+                   for k, v in st.aggregate_metrics().items()
+                   if k.endswith("clustered_early_filters"))
+
+    _cold_program_cache()
+    for i, (file, key, limit) in enumerate((a, b, a)):
+        ctx = BallistaContext.standalone(
+            BallistaConfig({"ballista.shuffle.partitions": "4"}),
+            concurrent_tasks=2)
+        try:
+            ctx.register_parquet("t", file)
+            since = _mark()
+            assert _answer(ctx, key, limit) == \
+                _expected(frames[file], key, limit)
+            _traced, misses, sigs = _built(since)
+            assert early_filters(ctx) > 0
+        finally:
+            ctx.shutdown()
+        if i < 2:
+            # the first two statements share nothing of the clustered path
+            assert misses > 0
+            # (the early filter reads the partial aggregate's own columns,
+            # __g0 and __a0: another key column is the same program)
+            want = {"having_constant": {"agg.clustered_keep"},
+                    "group_key_column": {"sort.range_check"},
+                    "nullable_key": set(_KEYED)}[case] if i else set(_KEYED)
+            assert want <= sigs, sigs
+        else:
+            # the first statement again, after the other: its own closures
+            assert not (set(_KEYED) & sigs), sigs
