@@ -8,9 +8,10 @@ followed by M×N Arrow Flight fetches in ShuffleReaderExec
 `lax.all_to_all` over HBM buffers: no files, no serialization, no host.
 
 Static-shape discipline (XLA cannot all_to_all ragged rows):
-- each device ranks its live rows within their destination bucket and
-  scatters them into a ``[n_dest, capacity]`` send buffer (MoE-style
-  capacity-factor dispatch);
+- each device sorts its rows on their destination bucket, every column
+  carried along the one sort, and cuts the ``[n_dest, capacity]`` send
+  buffer out of the sorted columns as ``n_dest`` contiguous slices: no row
+  is moved by an index;
 - ``capacity`` rows a bucket bound skew; rows past it are not sent, set an
   ``overflow`` flag and are counted in ``need`` (the fullest bucket's live
   rows), so the host never takes a flagged result and re-runs at ``need``,
@@ -38,37 +39,44 @@ def dispatch_to_buckets(
     num_dest: int,
     capacity: int,
 ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Scatter rows into a ``[num_dest, capacity]`` send buffer per column.
+    """Lay rows into a ``[num_dest, capacity]`` send buffer per column.
 
-    Returns (send_cols, send_mask, overflow, need).  Rows whose
-    within-bucket rank exceeds ``capacity`` are left out and flagged via
-    ``overflow``; ``need`` (int32 scalar) is the fullest bucket's live rows,
-    the capacity at which nothing would have been left out.
+    Returns (send_cols, send_mask, overflow, need).  A bucket's live rows
+    past ``capacity`` are left out and flagged via ``overflow``; ``need``
+    (int32 scalar) is the fullest bucket's live rows, the capacity at which
+    nothing would have been left out.  Slots where ``send_mask`` is False
+    hold whatever the slice met (another bucket's rows, padding): every
+    consumer reads the mask.
     """
     dkey = jnp.where(mask, dest, num_dest).astype(jnp.int32)
-    # sort-free ranking: one cumsum per destination (num_dest = mesh size,
-    # small and static).  Data-dependent device sorts are the one XLA
-    # program measured to compile pathologically on TPU (kernels.py notes),
-    # and this dispatch runs inside the fused mesh program.
-    rank = jnp.zeros(mask.shape, dtype=jnp.int32)
-    counts = []
-    for b in range(num_dest):
-        is_b = dkey == b
-        within = jnp.cumsum(is_b.astype(jnp.int32))
-        rank = jnp.where(is_b, within - 1, rank)
-        counts.append(within[-1])
-    counts = jnp.stack(counts)
-    slot_ok = (dkey < num_dest) & (rank < capacity)
-    flat = jnp.where(slot_ok, dkey * capacity + rank, num_dest * capacity)
+    # One unstable sort on the bucket carries every column: bucket 0's live
+    # rows first, then bucket 1's, ..., dead rows last, so bucket b is the
+    # contiguous run from its start, and the starts are sums of num_dest
+    # counts (num_dest = mesh size, small and static).  The chip sorts a slot
+    # with its payload in 5 ns and scatters or gathers a 64-bit word in
+    # 44-130 ns; a row-long cumsum compiles for 5-25 s a shape (PERF.md
+    # section 6, PRs 29 and 33).  At sf10_mesh4_q18's shapes (15.0M rows in,
+    # an int64 key and an int64 state, 4 buckets of 7.5M) this takes 77 ms
+    # on one v5e, 72 of them the sort; ranking rows by a cumsum a bucket and
+    # scattering each column into the buffer took 3.8 s (PR 36's micro).
+    sorted_cols = lax.sort((dkey, *cols.values()), num_keys=1,
+                           is_stable=False)[1:]
+    per_bucket = [jnp.sum(dkey == b, dtype=jnp.int32)
+                  for b in range(num_dest)]
+    starts = [jnp.zeros((), jnp.int32)]
+    for c in per_bucket[:-1]:
+        starts.append(starts[-1] + c)
 
+    # capacity slots of padding keep the last start's slice inside the array
+    # (a start is at most the row count): dynamic_slice would clamp it
     send_cols = {}
-    for name, col in cols.items():
-        buf = jnp.zeros((num_dest * capacity + 1,), dtype=col.dtype)
-        buf = buf.at[flat].set(col, mode="drop")
-        send_cols[name] = buf[:-1].reshape(num_dest, capacity)
-    mbuf = jnp.zeros((num_dest * capacity + 1,), dtype=jnp.bool_)
-    mbuf = mbuf.at[flat].set(slot_ok, mode="drop")
-    send_mask = mbuf[:-1].reshape(num_dest, capacity)
+    for name, col in zip(cols, sorted_cols):
+        padded = jnp.pad(col, (0, capacity))
+        send_cols[name] = jnp.stack([
+            lax.dynamic_slice(padded, (s,), (capacity,)) for s in starts])
+    counts = jnp.stack(per_bucket)
+    send_mask = jnp.arange(capacity, dtype=jnp.int32)[None, :] \
+        < jnp.minimum(counts, capacity)[:, None]
     need = jnp.max(counts)
     return send_cols, send_mask, need > capacity, need
 

@@ -770,12 +770,14 @@ def test_run_scan_program_moves_no_row_by_index(tpu_branches, entry):
     assert not [p for p in prims if p.startswith("cum")]
 
 
-def test_exchange_program_scatters_only_into_its_send_buckets(tpu_branches):
+def test_exchange_program_moves_no_row_by_index(tpu_branches):
     """``distributed_filter_aggregate`` (q18's inner aggregate: one int64
-    key, one int64 sum) over four devices: the partial and the final
-    aggregate scatter and gather nothing; what is left is
-    ``dispatch_to_buckets``' own (the key, the state and the mask byte into
-    the send buffer, and its four ``cumsum``s)."""
+    key, one int64 sum) over four devices scatters nothing, gathers nothing
+    as long as a shard and holds no ``cumsum``: five sorts, two a
+    ``grouped_aggregate`` (partial and final: the one that carries the
+    columns, the one that brings the run ends to the front) and one
+    ``dispatch_to_buckets``' (rows onto their send bucket), whose buckets are
+    four ``dynamic_slice``s a column of the sorted shard."""
     from jax.sharding import Mesh
 
     from arrow_ballista_tpu.ops.mesh_exec import _exchange_bounds
@@ -790,9 +792,21 @@ def test_exchange_program_scatters_only_into_its_send_buckets(tpu_branches):
     cols = {c: jax.ShapeDtypeStruct((4 * per,), np.int64) for c in "kv"}
     closed = jax.make_jaxpr(run.jit.__wrapped__)(
         cols, jax.ShapeDtypeStruct((4 * per,), np.bool_))
-    moved = _moved_by_index(closed.jaxpr, per)
-    send = (4 * shuffle + 1,)
-    assert sorted(name for name, _ in moved) == ["scatter"] * 3, moved
-    assert all(shapes[0] == send for _, shapes in moved), moved
+    assert not _moved_by_index(closed.jaxpr, per)
     prims = list(_primitives(closed.jaxpr))
-    assert prims.count("sort") == 4 and prims.count("cumsum") == 4
+    assert not [p for p in prims if p.startswith("cum")]
+    # the aggregates' four sorts order by the group key (int64) or the group
+    # index and carry more; the dispatch's one has the int32 bucket as its
+    # key and the key and the state as its payload
+    sorts = [[v.aval for v in eqn.invars] for eqn in _equations(closed.jaxpr)
+             if eqn.primitive.name == "sort"]
+    assert len(sorts) == 5, sorts
+    dispatch = [avals for avals in sorts
+                if [(a.dtype, a.shape) for a in avals]
+                == [(np.int32, (partial,))] + [(np.int64, (partial,))] * 2]
+    assert len(dispatch) == 1, sorts
+    # two columns into four buckets of `shuffle` slots each
+    slices = [eqn for eqn in _equations(closed.jaxpr)
+              if eqn.primitive.name == "dynamic_slice"
+              and eqn.outvars[0].aval.shape == (shuffle,)]
+    assert len(slices) == 8, slices

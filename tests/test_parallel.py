@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from arrow_ballista_tpu.parallel import (
     PART_AXIS,
+    dispatch_to_buckets,
     distributed_filter_aggregate,
     distributed_grouped_aggregate,
     make_mesh,
@@ -31,6 +32,79 @@ def mesh():
 
 def _place(mesh, arr):
     return jax.device_put(arr, row_sharding(mesh))
+
+
+def _dispatch_case(case, rng):
+    """(dest, mask, num_dest, capacity) of one ``dispatch_to_buckets``
+    case, 96 rows in."""
+    rows, num_dest = 96, 4
+    dest = rng.integers(0, num_dest, rows).astype(np.int32)
+    mask = np.ones(rows, dtype=bool)
+    capacity = 64
+    if case == "dead_rows_interleaved":
+        mask = rng.random(rows) < 0.6
+    elif case == "empty_bucket":
+        dest[dest == 2] = 3
+        mask = rng.random(rows) < 0.8
+    elif case == "one_bucket_overflows":
+        dest[:] = 1
+        capacity = 40
+    elif case == "capacity_is_row_count":
+        dest[:] = num_dest - 1      # the last start is as late as it can be
+        capacity = rows
+    elif case == "capacity_past_row_count":
+        dest[dest == 0] = num_dest - 1
+        capacity = rows + 32
+    else:
+        raise ValueError(case)
+    return dest, mask, num_dest, capacity
+
+
+@pytest.mark.parametrize("n_cols", [2, 6])
+@pytest.mark.parametrize("case", [
+    "dead_rows_interleaved", "empty_bucket", "one_bucket_overflows",
+    "capacity_is_row_count", "capacity_past_row_count"])
+def test_dispatch_to_buckets_matches_numpy(rng, case, n_cols):
+    """The send buffer against numpy: a bucket holds, under its mask, the
+    multiset of the live rows bound for it (on overflow ``capacity`` of them,
+    each once), ``need`` is the fullest bucket's live rows and ``overflow``
+    whether that passed ``capacity``; columns keep their dtypes."""
+    dest, mask, num_dest, capacity = _dispatch_case(case, rng)
+    rows = dest.shape[0]
+    # `id` is unique, so a row is identified; dead rows hold values too
+    cols = {"id": rng.permutation(rows).astype(np.int64),
+            "i32": rng.integers(-9, 9, rows).astype(np.int32),
+            "flag": rng.random(rows) < 0.5,
+            "big": rng.integers(-(1 << 62), 1 << 62, rows).astype(np.int64),
+            "flag2": rng.random(rows) < 0.5,
+            "small": rng.integers(0, 1 << 30, rows).astype(np.int32)}
+    cols = dict(list(cols.items())[:n_cols])
+
+    send, send_mask, overflow, need = jax.jit(
+        dispatch_to_buckets, static_argnums=(3, 4))(
+        {m: jnp.asarray(c) for m, c in cols.items()}, jnp.asarray(dest),
+        jnp.asarray(mask), num_dest, capacity)
+
+    counts = [int((mask & (dest == b)).sum()) for b in range(num_dest)]
+    assert int(need) == max(counts) and need.dtype == jnp.int32
+    assert bool(overflow) == (max(counts) > capacity)
+    assert bool(overflow) == (case == "one_bucket_overflows")
+    send_mask = np.asarray(send_mask)
+    assert send_mask.shape == (num_dest, capacity)
+    assert set(send) == set(cols)
+    for m, c in cols.items():
+        assert send[m].shape == (num_dest, capacity)
+        assert send[m].dtype == c.dtype
+    for b in range(num_dest):
+        sent = sorted(zip(*(np.asarray(send[m])[b][send_mask[b]].tolist()
+                            for m in cols)))
+        bound = sorted(zip(*(c[mask & (dest == b)].tolist()
+                             for c in cols.values())))
+        if counts[b] <= capacity:
+            assert sent == bound
+        else:
+            assert len(sent) == capacity == len(set(sent))
+            assert set(sent) <= set(bound)
 
 
 def test_shuffle_rows_preserves_multiset(mesh, rng):
@@ -84,7 +158,7 @@ def test_shuffle_overflow_flag(mesh):
                            _place(mesh, mask))
     assert np.all(np.asarray(ovf))
     # every device's fullest bucket held its whole shard: what a re-run
-    # needs, and the rows that did fit are the first 8 of each shard
+    # needs, and 8 rows of each shard did fit (which 8 is the sort's choice)
     np.testing.assert_array_equal(np.asarray(need), np.full(N_DEV, 64))
     assert int(np.asarray(rm).sum()) == 8 * N_DEV
 
