@@ -121,9 +121,20 @@ class ExecutorMetrics:
             counter("device_d2h_bytes_total", int(dsnap["d2h_bytes"]),
                     "bytes moved device->host through accounted "
                     "device_get sites (packed host collects)")
+            counter("device_scalar_waits_total",
+                    int(dsnap["scalar_waits"]),
+                    "task-thread waits for one device scalar (a row count, "
+                    "a flag): device_wait spans with site=scalar")
+            counter("gc_collections_total", int(dsnap["gc_collections"]),
+                    "collections of the interpreter's garbage collector, "
+                    "each of which stops every thread of the process")
+            counter("gc_pause_seconds_total",
+                    round(float(dsnap["gc_pause_time"]), 6),
+                    "wall time inside those collections")
             lines.append("# HELP device_live_bytes_peak high-water mark of "
-                         "live device-buffer bytes sampled at task/operator "
-                         "boundaries (jax.live_arrays)")
+                         "device bytes in use sampled at task/operator "
+                         "boundaries (the allocator's bytes_in_use; the "
+                         "live arrays' bytes where the backend reports none)")
             lines.append("# TYPE device_live_bytes_peak gauge")
             lines.append(
                 f"device_live_bytes_peak {int(dsnap['device_live_peak_bytes'])}")

@@ -22,8 +22,15 @@ on an accelerator:
   and, as intervals, every place a task thread waits for the device:
   ``device_wait`` / ``h2d`` scopes that are spans of obs/tracing.py and
   feed the byte counters from the same two readings of the clock.
-- **Memory watermarks**: live device-buffer bytes (``jax.live_arrays``)
-  and host RSS peak, sampled at task and operator boundaries.
+- **Memory watermarks**: device bytes in use, read from the allocator
+  (``Device.memory_stats()``; a walk of ``jax.live_arrays`` only on a
+  backend that reports no such number, as the CPU's), and host RSS peak,
+  sampled at task and operator boundaries.
+- **Host events that stop the task threads**: scalar waits are counted
+  (``scalar_waits``), and collections of the interpreter's garbage
+  collector are counted and timed by one ``gc.callbacks`` hook
+  (``gc_collections``, ``gc_pause_time``); a long one is also kept with
+  its start and end on the spans' clock (``long_gc_pauses``).
 
 The static mirror of this runtime view is
 ``analysis/jit_discipline.py``: it models every ``observed_jit`` site
@@ -47,8 +54,10 @@ null context manager.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
+import gc
 import inspect
 import re
 import threading
@@ -88,6 +97,7 @@ _COUNTER_KEYS = (
     "program_cache_hits", "program_cache_misses",
     "mesh_reshard_bytes", "mesh_programs", "mesh_collective_bytes",
     "mesh_unshard_bytes", "mesh_exchange_retries",
+    "scalar_waits", "gc_collections", "gc_pause_time",
 )
 _PEAK_KEYS = ("device_live_peak_bytes", "host_rss_peak_bytes")
 
@@ -115,15 +125,66 @@ class _ProcessStats:
         with self._lock:
             out = dict(self._c)
             out.update(self._p)
-            return out
+        # the collector's totals live outside the lock (``_on_gc``)
+        out["gc_collections"] = _gc_count
+        out["gc_pause_time"] = _gc_pause_ns / 1e9
+        return out
 
     def reset(self) -> None:  # test hook
+        global _gc_count, _gc_pause_ns
         with self._lock:
             self._c = {k: 0 for k in _COUNTER_KEYS}
             self._p = {k: 0 for k in _PEAK_KEYS}
+        _gc_count, _gc_pause_ns = 0, 0
 
 
 STATS = _ProcessStats()
+
+# --------------------------------------------------------------------------
+# collections of the interpreter's garbage collector
+# --------------------------------------------------------------------------
+#
+# A collection runs on whichever thread allocated last, with every other
+# thread stopped, and possibly inside ``STATS.add`` or ``RING.add`` on that
+# thread: the hook takes no lock (the interpreter runs one collection at a
+# time), opens no span and touches no ring.  It adds to plain integers that
+# ``STATS.snapshot()`` folds in.
+
+LONG_GC_NS = 10_000_000         # a collection kept with its interval
+_gc_count = 0
+_gc_pause_ns = 0
+_gc_start_ns = 0
+_gc_hooked = False
+_long_gc = collections.deque(maxlen=256)
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    global _gc_count, _gc_pause_ns, _gc_start_ns
+    if phase == "start":
+        _gc_start_ns = tracing.now_ns() if _enabled else 0
+        return
+    t0, _gc_start_ns = _gc_start_ns, 0
+    if not t0:
+        return
+    t1 = tracing.now_ns()
+    _gc_count += 1
+    _gc_pause_ns += t1 - t0
+    if t1 - t0 >= LONG_GC_NS:
+        _long_gc.append((t0, t1, info.get("generation", -1)))
+
+
+def _hook_gc() -> None:
+    global _gc_hooked
+    if not _gc_hooked:
+        _gc_hooked = True
+        gc.callbacks.append(_on_gc)
+
+
+def long_gc_pauses() -> list:
+    """The newest collections of 10 ms or more, as ``(start_ns, end_ns,
+    generation)`` on the spans' clock, oldest first: what to set beside a
+    stalled query's span tree."""
+    return list(_long_gc)
 
 # --------------------------------------------------------------------------
 # scope stacks (thread-local: a task runs on one pool thread; work an
@@ -252,9 +313,11 @@ _NULL_TASK = _NullTaskScope()
 
 def task_scope():
     """Device-accounting scope for one executor task; yields the
-    accumulator (or None when the observatory is off)."""
+    accumulator (or None when the observatory is off).  The first one
+    hooks the garbage collector's callbacks, once a process."""
     if not _enabled:
         return _NULL_TASK
+    _hook_gc()
     return _TaskScope()
 
 
@@ -284,7 +347,8 @@ class _Boundary:
     feed ``record_transfer`` from the span's own two readings of the
     clock; set ``nbytes`` inside the block where the size is only known
     afterwards.  Scalar waits carry a handful of bytes, ``ready`` waits
-    none, and both stay out of the byte counters."""
+    none, and both stay out of the byte counters; each scalar wait counts
+    one ``scalar_waits``."""
     __slots__ = ("name", "site", "nbytes", "_scope", "_span", "_t0")
 
     def __init__(self, name: str, site: str, nbytes: int = 0):
@@ -301,10 +365,14 @@ class _Boundary:
         if self.nbytes:
             sp.set(bytes=int(self.nbytes))
         self._scope.__exit__(et, ev, tb)
-        if self.site in ("d2h", "h2d") and et is None:
+        if et is not None:
+            return False
+        if self.site in ("d2h", "h2d"):
             record_transfer(self.site, self.nbytes,
                             ((sp.end_ns or tracing.now_ns()) - self._t0)
                             / 1e9)
+        elif self.site == "scalar" and _enabled:
+            _record("scalar_waits", 1)
         return False
 
 
@@ -356,18 +424,31 @@ def record_program_cache(hit: bool) -> None:
     _record("program_cache_hits" if hit else "program_cache_misses", 1)
 
 
+def _device_bytes_in_use() -> int:
+    """Device bytes in use, summed over the local devices: the allocator's
+    own number where the backend reports one (O(1) a device), else the
+    bytes of every live array (a walk, on a backend such as the CPU's whose
+    ``memory_stats()`` is None)."""
+    import jax
+
+    total = 0
+    for d in jax.local_devices():
+        used = (d.memory_stats() or {}).get("bytes_in_use")
+        if used is None:
+            return sum(int(getattr(a, "nbytes", 0) or 0)
+                       for a in jax.live_arrays())
+        total += int(used)
+    return total
+
+
 def sample_watermarks() -> Optional[Tuple[int, int]]:
-    """Sample device live-buffer bytes + host RSS peak and fold the maxima
+    """Sample device bytes in use + host RSS peak and fold the maxima
     into the open task scope and the process stats.  Returns the sample
     (device_bytes, host_rss_bytes) or None when off."""
     if not (_enabled and _watermarks):
         return None
-    dev = 0
     try:
-        import jax
-
-        for a in jax.live_arrays():
-            dev += int(getattr(a, "nbytes", 0) or 0)
+        dev = _device_bytes_in_use()
     except Exception:  # noqa: BLE001 — watermarks are best-effort
         dev = 0
     rss = 0
@@ -385,7 +466,6 @@ def sample_watermarks() -> Optional[Tuple[int, int]]:
     if tasks:
         tasks[-1].peak("device_mem_peak", dev)
         tasks[-1].peak("host_mem_peak", rss)
-        tasks[-1].add("watermark_samples", 1)
     return dev, rss
 
 

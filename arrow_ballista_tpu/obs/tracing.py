@@ -18,6 +18,7 @@ clock, same store, no annotation.  The tree:
     client.sql, client.collect -> submit / wait / fetch / decode   (client)
       job -> admission / planning / execution -> stage <id>        (scheduler)
         task -> operator -> device_wait / h2d / compile / lock_wait
+                         -> shuffle_write -> shuffle.order / .encode / .checksum
 
 A finished span enters, once, the process-wide ``RING`` (bounded,
 drop-oldest, counted).  Task trees also ride ``TaskStatus.spans`` back to

@@ -21,26 +21,36 @@ the file hop with an all_to_all over the ICI mesh.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-import jax
-import jax.numpy as jnp
-
 from ..models import expr as E
 from ..models.batch import ColumnBatch, concat_batches
 from ..models.batch import round_capacity as _round_capacity
-from ..models.ipc import crc32_file, read_ipc_files, write_ipc_file, write_ipc_rows
+from ..models.ipc import crc32_file, read_ipc_files, write_ipc_rows
 from ..models.schema import Schema
+from ..obs import tracing
 from ..obs.device import observed_jit
 from ..utils.errors import FetchFailedError, InternalError
 from .expressions import ExprCompiler
 from . import kernels as K
 from .physical import (ExecutionPlan, Partitioning, TaskContext,
                        exprs_sig, schema_sig, shared_program)
+
+
+@contextlib.contextmanager
+def _phase(name: str, ms, metric: str):
+    """A span under the open one, whose own two readings of the clock also
+    add to the operator's ``metric`` (read from the clock where tracing is
+    off and the span is the null one)."""
+    with tracing.span(name, "operator") as sp:
+        t0 = sp.start_ns or tracing.now_ns()
+        yield sp
+    ms.add(metric, ((sp.end_ns or tracing.now_ns()) - t0) / 1e9)
 
 
 @dataclasses.dataclass
@@ -115,90 +125,93 @@ class ShuffleWriterExec(ExecutionPlan):
         ctx.check_cancelled()
         big = concat_batches(self.input.schema, batches).shrink()
         base = os.path.join(ctx.work_dir, ctx.job_id, str(self.stage_id), str(partition))
-
         if self.partitioning is None:
             # final stage: pass-through; output partition == input partition
-            path = os.path.join(base, "data-0.arrow")
-            with self.metrics().timer("write_time"):
-                rows, nbytes = write_ipc_file(big, path)
-            self.metrics().add("input_rows", big.num_rows)
-            self.metrics().add("output_rows", rows)
-            return [ShuffleWritePartition(partition, path, rows, nbytes,
-                                          checksum=crc32_file(path))]
-
+            host_cols, n = big.packed_numpy()
+            return self._write_host(big, host_cols, n, None, 1, base,
+                                    first_id=partition)
         num_out = self.partitioning.count
-        if self.partitioning.kind == "hash" and num_out > 1:
-            # Device computes only the per-row bucket id (elementwise hash —
-            # compiles in seconds); then ONE device->host transfer per
-            # column and a host-side stable grouping sort hand the writer
-            # contiguous per-partition slices that wrap zero-copy into
-            # arrow arrays.  The reference streams batches through
-            # BatchPartitioner+IPCWriter incrementally
-            # (shuffle_writer.rs:214-252); the earlier rendition here
-            # materialized num_out full-capacity host copies instead, which
-            # made write_time dominate q1 wall-clock.  Grouping stays OFF
-            # the device on purpose: data-dependent sorts are the one XLA
-            # program measured to compile pathologically on TPU
-            # (kernels.py grouped_aggregate notes).
-            with self.xla_lock():
-                if self._compiled is None:
-                    def build():
-                        comp = ExprCompiler(self.input.schema, "device")
-                        keys_c = [comp.compile_key(e)
-                                  for e in self.partitioning.exprs]
+        if not (self.partitioning.kind == "hash" and num_out > 1):
+            host_cols, n = big.packed_numpy()
+            return self._write_host(big, host_cols, n, None, num_out, base)
+        # Device computes only the per-row bucket id (elementwise hash —
+        # compiles in seconds); then ONE device->host transfer per
+        # column and a host-side stable grouping sort hand the writer
+        # contiguous per-partition slices that wrap zero-copy into
+        # arrow arrays.  The reference streams batches through
+        # BatchPartitioner+IPCWriter incrementally
+        # (shuffle_writer.rs:214-252); the earlier rendition here
+        # materialized num_out full-capacity host copies instead, which
+        # made write_time dominate q1 wall-clock.  Grouping stays OFF
+        # the device on purpose: data-dependent sorts are the one XLA
+        # program measured to compile pathologically on TPU
+        # (kernels.py grouped_aggregate notes).
+        with self.xla_lock():
+            if self._compiled is None:
+                def build():
+                    comp = ExprCompiler(self.input.schema, "device")
+                    keys_c = [comp.compile_key(e)
+                              for e in self.partitioning.exprs]
 
-                        def bucket_fn(cols, mask, aux):
-                            keys = [c.fn(cols, aux) for c in keys_c]
-                            return K.bucket_of(keys, num_out)
+                    def bucket_fn(cols, mask, aux):
+                        keys = [c.fn(cols, aux) for c in keys_c]
+                        return K.bucket_of(keys, num_out)
 
-                        return comp, observed_jit("shuffle.bucket",
-                                                  bucket_fn)
+                    return comp, observed_jit("shuffle.bucket",
+                                              bucket_fn)
 
-                    self._compiled = shared_program(
-                        ("bucket", num_out, schema_sig(self.input.schema),
-                         exprs_sig(self.partitioning.exprs)), build)
-            comp, bfn = self._compiled
-            with self.metrics().timer("repart_time"):
-                aux = comp.aux_arrays(big.dicts)
-                # ONE packed device->host transfer for columns + bucket ids
-                # + live-row count (compacted on device): a per-array fetch
-                # pays a fixed transfer latency each, and padded-capacity
-                # arrays multiply the bytes
-                host_cols, n = big.packed_numpy(
-                    hint=getattr(self, "_pack_hint", None),
-                    extra32={"__bucket__": bfn(big.columns, big.mask, aux)})
-                self._pack_hint = _round_capacity(n)
-                buckets = host_cols.pop("__bucket__")
-                order = np.argsort(buckets, kind="stable")
-                counts = np.bincount(buckets, minlength=num_out)[:num_out]
-                host_cols = {k: v[order] for k, v in host_cols.items()}
-            offsets = np.concatenate([[0], np.cumsum(counts)])
-            out: List[ShuffleWritePartition] = []
-            with self.metrics().timer("write_time"):
-                for q in range(num_out):
-                    lo, hi = int(offsets[q]), int(offsets[q + 1])
-                    data = {k: v[lo:hi] for k, v in host_cols.items()}
-                    path = os.path.join(base, f"data-{q}.arrow")
-                    rows, nbytes = write_ipc_rows(big.schema, data, big.dicts, path)
-                    out.append(ShuffleWritePartition(q, path, rows, nbytes,
-                                                     checksum=crc32_file(path)))
-            self.metrics().add("input_rows", n)
-            self.metrics().add("output_rows", sum(p.num_rows for p in out))
-            return out
+                self._compiled = shared_program(
+                    ("bucket", num_out, schema_sig(self.input.schema),
+                     exprs_sig(self.partitioning.exprs)), build)
+        comp, bfn = self._compiled
+        aux = comp.aux_arrays(big.dicts)
+        # ONE packed device->host transfer for columns + bucket ids
+        # + live-row count (compacted on device): a per-array fetch
+        # pays a fixed transfer latency each, and padded-capacity
+        # arrays multiply the bytes
+        host_cols, n = big.packed_numpy(
+            hint=getattr(self, "_pack_hint", None),
+            extra32={"__bucket__": bfn(big.columns, big.mask, aux)})
+        self._pack_hint = _round_capacity(n)
+        buckets = host_cols.pop("__bucket__")
+        return self._write_host(big, host_cols, n, buckets, num_out, base)
 
-        out = []
-        with self.metrics().timer("write_time"):
-            for q in range(num_out):
-                part_mask = big.mask if q == 0 else jnp.zeros_like(big.mask)
-                pb = ColumnBatch(big.schema, big.columns, part_mask, big.dicts)
-                path = os.path.join(base, f"data-{q}.arrow")
-                rows, nbytes = write_ipc_file(pb, path)
-                out.append(ShuffleWritePartition(q, path, rows, nbytes,
-                                                 checksum=crc32_file(path)))
-        self.metrics().add("input_rows", big.num_rows)
-        self.metrics().add(
-            "output_rows", sum(p.num_rows for p in out)
-        )
+    def _write_host(self, big: ColumnBatch, host_cols: Dict[str, np.ndarray],
+                    n: int, buckets: Optional[np.ndarray], num_out: int,
+                    base: str, first_id: int = 0) -> List[ShuffleWritePartition]:
+        """The writer's host half, after the packed fetch: one
+        ``shuffle_write`` span over three phases, each one span a task
+        (never one a bucket): ``shuffle.order`` groups the rows by bucket
+        (``buckets`` None: every row to the first file), ``shuffle.encode``
+        writes one IPC file a bucket, ``shuffle.checksum`` reads each back
+        for its CRC-32.  ``repart_time`` and ``write_time`` are the phases'
+        own readings of the clock."""
+        ms = self.metrics()
+        with tracing.span("shuffle_write", "operator") as sp:
+            with _phase("shuffle.order", ms, "repart_time"):
+                if buckets is None:
+                    counts = [n] + [0] * (num_out - 1)
+                else:
+                    order = np.argsort(buckets, kind="stable")
+                    counts = np.bincount(buckets, minlength=num_out)[:num_out]
+                    host_cols = {k: v[order] for k, v in host_cols.items()}
+            offsets = np.concatenate([[0], np.cumsum(counts)]).astype(int)
+            paths = [os.path.join(base, f"data-{q}.arrow")
+                     for q in range(num_out)]
+            with _phase("shuffle.encode", ms, "write_time"):
+                sizes = [write_ipc_rows(
+                    big.schema, {k: v[offsets[q]:offsets[q + 1]]
+                                 for k, v in host_cols.items()},
+                    big.dicts, paths[q]) for q in range(num_out)]
+            with _phase("shuffle.checksum", ms, "write_time"):
+                sums = [crc32_file(path) for path in paths]
+            out = [ShuffleWritePartition(first_id + q, paths[q], rows, nbytes,
+                                         checksum=crc)
+                   for q, ((rows, nbytes), crc) in enumerate(zip(sizes, sums))]
+            sp.set(rows=int(n), bytes=sum(p.num_bytes for p in out),
+                   files=num_out)
+        ms.add("input_rows", n)
+        ms.add("output_rows", sum(p.num_rows for p in out))
         return out
 
     def execute(self, partition: int, ctx: TaskContext) -> List[ColumnBatch]:

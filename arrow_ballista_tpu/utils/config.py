@@ -269,7 +269,7 @@ _ENTRIES: Dict[str, ConfigEntry] = {
                     "never triggers)"),
         ConfigEntry(MEM_DEVICE_BUDGET, "0", str,
                     "memory governor: device-HBM pool budget in bytes, "
-                    "checked against the live-buffer watermark sampler "
+                    "checked against the device-bytes watermark sampler "
                     "(obs/device.py).  'auto' = 12 GiB on accelerator "
                     "backends, unlimited on CPU; 0 = unlimited"),
         ConfigEntry(MEM_SPILL_ENABLED, True, _parse_bool,
@@ -336,7 +336,7 @@ _ENTRIES: Dict[str, ConfigEntry] = {
                     "operator and shipped as TaskStatus.device_stats "
                     "(False = every probe is a single predicate check)"),
         ConfigEntry(OBS_DEVICE_WATERMARKS, True, _parse_bool,
-                    "sample device live-buffer bytes and host RSS peaks at "
+                    "sample device bytes in use and host RSS peaks at "
                     "task/operator boundaries (requires "
                     "ballista.observability.device.enabled; False drops "
                     "only the watermark sampling, keeping compile/transfer "

@@ -212,8 +212,6 @@ def test_task_scope_snapshot_and_watermarks():
         dev.record_transfer("h2d", 64, 0.001)
     snap = acc.snapshot()
     assert snap["h2d_bytes"] == 64
-    # entry + exit watermark samples at minimum
-    assert snap["watermark_samples"] >= 2
     assert snap["host_mem_peak"] > 0  # ru_maxrss is always nonzero on Linux
     assert snap["device_mem_peak"] >= held.nbytes
     json.dumps(snap)  # wire-framing safe

@@ -361,6 +361,11 @@ def test_reader_parts_sum_to_query_s_on_a_rehearsal():
     assert m["task_host_s_per_query"] > 0
     assert (m["task_device_wait_s_per_query"] + m["task_host_s_per_query"]
             <= t["task"] / t["completed"] / 1e9 + 1e-9)
+    # the writer's host half is part of the task's host seconds
+    assert 0 < m["shuffle_write_host_s_per_query"] \
+        <= m["task_host_s_per_query"]
+    assert m["scalar_waits_per_query"] >= 0
+    assert m["gc_pause_s_per_query"] >= 0
 
 
 def test_executor_profile_switch_writes_the_task_spans(tmp_path):
