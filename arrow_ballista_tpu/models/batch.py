@@ -187,7 +187,7 @@ class ColumnBatch:
         """Re-wrap raw kernel outputs, keeping host-side metadata."""
         return ColumnBatch(schema or self.schema, columns, mask, dicts if dicts is not None else self.dicts)
 
-    def shrink(self) -> "ColumnBatch":
+    def shrink(self, fetch_count: bool = False) -> "ColumnBatch":
         """Compact live rows to the front and drop to the smallest
         power-of-two capacity.  A host decision (syncs on num_rows), used at
         blocking boundaries (agg/join/sort/shuffle inputs) so downstream
@@ -196,8 +196,10 @@ class ColumnBatch:
         Where remote_device() holds, an unknown num_rows costs a
         fixed-latency fetch, and skipping the shrink merely keeps the
         producer's (already shape-bucketed) capacity — fewer distinct
-        compile shapes, cheap extra FLOPs — so the sync is not paid there."""
-        if self._num_rows is None and remote_device():
+        compile shapes, cheap extra FLOPs — so the sync is not paid there
+        unless ``fetch_count`` asks for it (a batch that many programs
+        will read, such as a broadcast join's build)."""
+        if self._num_rows is None and remote_device() and not fetch_count:
             return self
         n = self.num_rows
         target = round_capacity(n)
@@ -449,7 +451,16 @@ _concat_device = device_obs.observed_jit("batch.concat", _concat_impl,
 
 @device_obs.observed_jit("batch.shrink", static_argnames=("target",))
 def _shrink_device(cols, mask, target: int):
-    from ..ops.kernels import compaction_order
-
-    order = compaction_order(mask)[:target]
-    return {k: v[order] for k, v in cols.items()}, mask[order]
+    """The first ``target`` slots with the live rows in front, in their
+    order, then the dead ones in theirs: the order of
+    ``kernels.compaction_order``.  One sort finds it, of each slot's index
+    plus the capacity where the slot is dead (keys are distinct, so no
+    stability is needed): the chip sorts a slot in a few nanoseconds where
+    a scatter across the capacity costs tens.  Only ``target`` rows of each
+    column are gathered."""
+    n = mask.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    key = jax.lax.sort(jnp.where(mask, idx, idx + n))[:target]
+    live = key < n
+    order = jnp.where(live, key, key - n)
+    return {k: v[order] for k, v in cols.items()}, live

@@ -191,6 +191,23 @@ def test_pack_for_host_mixed_columns(one_chip):
         cols, mask)
 
 
+def test_shrink_q18_broadcast_build(one_chip):
+    """q18's HAVING-filtered build over the mesh: the 4 x 2^22 slots
+    ``_unshard`` hands over, a key and a sum, compacted into 1024 slots by
+    one sort; nothing is scattered across the capacity."""
+    from arrow_ballista_tpu.models.batch import _shrink_device
+
+    cap = 1 << 24
+    cols = {"l_orderkey": sds((cap,), jnp.int64, one_chip),
+            "sum_qty": sds((cap,), jnp.int64, one_chip)}
+    text = compile_for_chip(
+        "batch.shrink 2^24 -> 1024",
+        lambda cols, mask: _shrink_device.__wrapped__(cols, mask, 1024),
+        cols, sds((cap,), jnp.bool_, one_chip)).as_text()
+    assert not has_scatter(text)
+    assert len(re.findall(r"\bsort\(", text)) == 1
+
+
 def test_join_build_sort_and_probe_q3_shapes(one_chip):
     """q3's orders x lineitem join: one partition of orders as the build
     side (2^18 slots), one lineitem batch as the probe."""
