@@ -97,6 +97,7 @@ _COUNTER_KEYS = (
     "program_cache_hits", "program_cache_misses",
     "mesh_reshard_bytes", "mesh_programs", "mesh_collective_bytes",
     "mesh_unshard_bytes", "mesh_exchange_retries",
+    "mesh_join_collective_bytes", "mesh_join_retries",
     "scalar_waits", "gc_collections", "gc_pause_time",
 )
 _PEAK_KEYS = ("device_live_peak_bytes", "host_rss_peak_bytes")
@@ -392,13 +393,16 @@ def record_mesh_reshard(nbytes: int) -> None:
         _record("mesh_reshard_bytes", int(nbytes))
 
 
-def record_mesh_program(collective_bytes: int) -> None:
+def record_mesh_program(collective_bytes: int, join: bool = False) -> None:
     """One dispatched mesh program and the bytes it hands to its
     collective, summed over the devices (parallel/distributed.py
-    ``MeshProgram.collective_bytes``)."""
+    ``MeshProgram.collective_bytes``); a join program's bytes also count
+    in ``mesh_join_collective_bytes``."""
     if _enabled:
         _record("mesh_programs", 1)
         _record("mesh_collective_bytes", int(collective_bytes))
+        if join:
+            _record("mesh_join_collective_bytes", int(collective_bytes))
 
 
 def record_mesh_unshard(nbytes: int) -> None:
@@ -410,10 +414,19 @@ def record_mesh_unshard(nbytes: int) -> None:
 
 
 def record_mesh_exchange_retry() -> None:
-    """One exchange program run again at the send-bucket capacity its
-    first run found it needed (ops/mesh_exec.py ``MeshAggregateExec``)."""
+    """One exchange aggregate program run again at the send-bucket
+    capacity its first run found it needed (ops/mesh_exec.py
+    ``MeshAggregateExec``)."""
     if _enabled:
         _record("mesh_exchange_retries", 1)
+
+
+def record_mesh_join_retry() -> None:
+    """One mesh join program run again at the bounds a flagged run
+    reported it needed: a send bucket of either side, or the candidate
+    pairs (ops/mesh_exec.py ``MeshJoinExec``)."""
+    if _enabled:
+        _record("mesh_join_retries", 1)
 
 
 def record_program_cache(hit: bool) -> None:

@@ -118,10 +118,12 @@ def _dispatch(prog, *args, describe=None, **attrs):
     """One call of a mesh program (parallel/distributed.py ``MeshProgram``):
     a ``mesh_program`` span (``attrs`` beside its own) from the dispatch,
     under the process-wide dispatch lock, until the outputs are ready.  The
-    program's last output is its overflow flag, or an exchange's ``stats``
-    whose first element is one, so fetching it (a ``device_wait``) waits for
-    all of them.  Returns the outputs with the last as a host array;
-    ``describe(last)`` gives the span what only that tells."""
+    program's last output is its overflow flag, or the ``stats`` of an
+    exchange or a join whose first element is one, so fetching it (a
+    ``device_wait``) waits for all of them.  Returns the outputs with the
+    last as a host array; ``describe(last)`` gives the span what only that
+    tells.  A join program's (``mesh_join_*``) collective bytes also count
+    as the joins' own."""
     from ..parallel.mesh import MESH_DISPATCH_LOCK
 
     with span("mesh_program", "device", program=prog.name,
@@ -132,7 +134,8 @@ def _dispatch(prog, *args, describe=None, **attrs):
             last = np.asarray(last)
         if describe is not None:
             sp.set(**describe(last))
-    device_obs.record_mesh_program(prog.collective_bytes(*args))
+    device_obs.record_mesh_program(prog.collective_bytes(*args),
+                                   join=prog.name.startswith("mesh_join"))
     return (*out, last)
 
 
@@ -153,10 +156,24 @@ def _exchange_bounds(rows: int, n_dev: int, shuffle_cap: Optional[int] = None):
     return rows, shuffle_cap, n_dev * shuffle_cap
 
 
-# send-bucket capacities that first runs found they needed, by what the
-# program is shared under and the shard's rows: a later execution of the
-# same statement over the same rows starts there and pays no re-run
-_EXCHANGE_NEED: Dict[tuple, int] = {}
+def _join_send_capacity(live: int, shard: int, n_dev: int) -> int:
+    """Rows a destination bucket of one side's send buffer holds in a
+    partitioned mesh join, from the side's live rows and not its slots:
+    twice a bucket's even share of a device's even share of them
+    (``shuffle_capacity_of``), rounded up to a power of two so that seeds
+    of one scale share the program, and never more than a device's shard
+    of ``shard`` slots could fill it with."""
+    from ..parallel.distributed import shuffle_capacity_of
+
+    share = shuffle_capacity_of(-(-live // n_dev), n_dev)
+    return max(1, min(_pow2(share), shard))
+
+
+# bounds that first runs found they needed, by what the program is shared
+# under and the rows: a later execution of the same statement over the same
+# rows starts there and pays no re-run.  An exchange aggregate keeps its
+# send-bucket capacity, a join its (probe, build, output) capacities.
+_EXCHANGE_NEED: Dict[tuple, object] = {}
 
 
 # --- shared pieces of the two mesh aggregate operators ---------------------
@@ -624,6 +641,21 @@ class MeshJoinExec(ExecutionPlan):
     reference materializes two shuffles and a reduce stage (exchange rules
     planner.rs:133-152; SURVEY.md §2.5 TP row).  Results are identical to
     the file-shuffle JoinExec path — verified by tests/test_mesh_exec.py.
+
+    Its bounds come from each side's live rows, fetched once a join (each
+    side compacted where that frees a power of two of slots), not from the
+    slots they arrive in: a build side of at most
+    ``ballista.shuffle.mesh.broadcast_rows`` rows is all_gathered instead
+    and the probe rows stay where they are; otherwise each side's send
+    bucket holds twice its even share (``_join_send_capacity``), and the
+    candidate pairs a device may expand are
+    ``ballista.join.output_factor`` times a device's even share of the
+    smaller side's rows (of the probe side's in a broadcast join).  A
+    program that passes a bound reports what it needed; the join runs
+    again at that, and a later execution over the same rows starts there
+    (``_EXCHANGE_NEED``).  A send bucket's need is exact at once, the
+    pairs' once no send bucket dropped a row, so a join re-runs at most
+    twice.
     """
 
     def __init__(self, left: ExecutionPlan, right: ExecutionPlan,
@@ -677,20 +709,24 @@ class MeshJoinExec(ExecutionPlan):
         assert partition == 0
         lsch, rsch = self.left.schema, self.right.schema
         probe = concat_batches(lsch, [b for p in range(self.left.output_partition_count())
-                                      for b in self.left.execute(p, ctx)]).shrink()
+                                      for b in self.left.execute(p, ctx)])
         build = concat_batches(rsch, [b for p in range(self.right.output_partition_count())
-                                      for b in self.right.execute(p, ctx)]).shrink()
+                                      for b in self.right.execute(p, ctx)])
         return self._join_batches(probe, build, ctx)
 
     def _join_batches(self, probe: ColumnBatch, build: ColumnBatch,
                       ctx: TaskContext) -> List[ColumnBatch]:
-        from ..parallel.distributed import (distributed_broadcast_join,
-                                            distributed_hash_join)
         from ..parallel.mesh import make_mesh
 
         lsch, rsch = self.left.schema, self.right.schema
         n_dev = len(jax.devices())
         mesh = make_mesh(n_dev)
+        # each side's live rows, one scalar fetch a side where the count is
+        # not known yet, and the side compacted where that frees a power of
+        # two of slots
+        probe = probe.shrink(fetch_count=True)
+        build = build.shrink(fetch_count=True)
+        p_live, b_live = probe.num_rows, build.num_rows
 
         # the compiled keys are shared across a stage's tasks
         # (MeshTaskJoinExec runs one task per partition)
@@ -717,6 +753,9 @@ class MeshJoinExec(ExecutionPlan):
 
         pcols = with_keys(probe.columns, probe.mask, lkeys, laux)
         bcols = with_keys(build.columns, build.mask, rkeys, raux)
+        if self.join_type in ("semi", "anti"):
+            # no build column leaves the join: only its keys travel
+            bcols = {k: v for k, v in bcols.items() if k.startswith("__jk")}
 
         # NULL join keys never match (SQL): drop NULL-key build rows always;
         # drop NULL-key probe rows too for inner/semi (left/anti must keep
@@ -737,36 +776,61 @@ class MeshJoinExec(ExecutionPlan):
             pmask_in = key_valid(lcomp, [le for le, _ in self.on],
                                  probe.columns, probe.mask, laux)
 
-        # shard rows over the mesh (pad to a multiple of the device count)
+        dicts = dict(probe.dicts)
+        if self.join_type in ("inner", "left"):
+            dicts.update(build.dicts)
+        # shard rows over the mesh (pad to a multiple of the device count);
+        # the concatenated copies are dead once their shards are in place
         dp, dpm, p_rows = _shard_rows(pcols, pmask_in, mesh, n_dev)
         db, dbm, b_rows = _shard_rows(bcols, bmask_in, mesh, n_dev)
+        del probe, build, pcols, bcols, pmask_in, bmask_in
 
-        out_factor = ctx.config.get(JOIN_OUTPUT_FACTOR)
+        out_cols, out_mask, rows_out = self._run(
+            ctx, mesh, n_dev, p_live, b_live, p_rows // n_dev,
+            b_rows // n_dev, sflags, dp, dpm, db, dbm)
+        del dp, dpm, db, dbm
+
+        out_cols, out_mask = _unshard((out_cols, out_mask))
+        result = ColumnBatch(self._schema, out_cols, out_mask, dicts,
+                             num_rows=rows_out)
+        self.metrics().add("output_rows", rows_out)
+        self.metrics().add("mesh_devices", n_dev)
+        return [result]
+
+    def _run(self, ctx, mesh, n_dev, p_live, b_live, p_shard, b_shard,
+             sflags, *args):
+        """The join program at the bounds of the class docstring, run again
+        at what a flagged run reported it needed.  ``(out_cols, out_mask,
+        rows_out)``."""
+        from ..parallel.distributed import (distributed_broadcast_join,
+                                            distributed_hash_join)
+
+        lsch, rsch = self.left.schema, self.right.schema
         rfill = {f.name: f.dtype.null_sentinel for f in rsch}
         sentinel = int(ExprCompiler.NULL_KEY_SENTINEL)
-        broadcast = build.num_rows <= ctx.config.get(MESH_BROADCAST_ROWS)
+        broadcast = b_live <= ctx.config.get(MESH_BROADCAST_ROWS)
         # the join programs depend on names, dtypes (through the shapes jit
         # keys on) and these statics alone, so jobs share them
         base = (n_dev, len(self.on), tuple(lsch.names()),
                 tuple(rsch.names()), self.join_type,
                 tuple(sorted(rfill.items())), tuple(sflags), sentinel)
-
-        # per-device output bound: start at the EXPECTED per-device probe
-        # share x fan-out factor, not the worst-case receive bound — a
-        # too-small guess recompiles via the overflow-retry doubling, a
-        # too-large one allocates (and gathers into) multi-GB outputs
-        # every run (measured: q3's old 2x-shuffle-capacity bound put a
-        # 24M-row output gather on a 30k-row result)
-        out_cap = _pow2(out_factor * (p_rows // n_dev))
-        # per-device shuffle capacity: worst case every row of a side
-        # hashes to one bucket of one device's send buffer; factor 2
-        # covers skew, overflow re-runs at the true bound.  A small build
-        # side is all_gathered instead and the probe rows never move
-        # (CollectLeft analog, distributed_broadcast_join)
-        shuf_cap = None if broadcast \
-            else _pow2(2 * max(p_rows, b_rows) // n_dev)
-        attempts = 0
-        while True:
+        factor = ctx.config.get(JOIN_OUTPUT_FACTOR)
+        if broadcast:
+            # the probe rows stay where they are: no send bucket
+            bounds = (None, None,
+                      _pow2(factor * -(-p_live // n_dev)))
+        else:
+            bounds = (_join_send_capacity(p_live, p_shard, n_dev),
+                      _join_send_capacity(b_live, b_shard, n_dev),
+                      _pow2(factor * -(-min(p_live, b_live) // n_dev)))
+        memo = ("mesh_join", broadcast, p_live, b_live, p_shard,
+                b_shard) + base
+        bounds = _EXCHANGE_NEED.get(memo, bounds)
+        attrs = {"probe_rows": p_live, "build_rows": b_live}
+        for retries in range(3):
+            p_cap, b_cap, out_cap = bounds
+            if not broadcast:
+                attrs.update(probe_capacity=p_cap, build_capacity=b_cap)
             with self.xla_lock():
                 if broadcast:
                     prog = _program(
@@ -778,41 +842,45 @@ class MeshJoinExec(ExecutionPlan):
                             null_key_sentinel=sentinel))
                 else:
                     prog = _program(
-                        self,
-                        ("mesh_join_partitioned", shuf_cap, out_cap) + base,
+                        self, ("mesh_join_partitioned", p_cap, b_cap,
+                               out_cap) + base,
                         lambda: distributed_hash_join(
                             mesh, len(self.on), list(lsch.names()),
-                            list(rsch.names()), self.join_type, shuf_cap,
-                            out_cap, rfill, string_key_flags=sflags,
+                            list(rsch.names()), self.join_type, p_cap,
+                            b_cap, out_cap, rfill, string_key_flags=sflags,
                             null_key_sentinel=sentinel))
-            out_cols, out_mask, overflow = _dispatch(prog, dp, dpm, db, dbm)
+            out_cols, out_mask, stats = _dispatch(
+                prog, *args, describe=lambda st: {"rows_out": int(st[4])},
+                out_capacity=out_cap, retries=retries, **attrs)
+            overflow, p_need, b_need, pairs, rows_out = (int(v)
+                                                         for v in stats)
             if not overflow:
+                self.metrics().add(
+                    "broadcast_joins" if broadcast else "partitioned_joins",
+                    1)
+                return out_cols, out_mask, rows_out
+            del out_cols, out_mask
+            passed = [side for side, need, cap in (
+                ("probe", p_need, p_cap), ("build", b_need, b_cap),
+                ("output", pairs, out_cap)) if cap is not None and need > cap]
+            if not passed:
                 break
-            attempts += 1
-            if attempts > 3:
-                raise CapacityError(
-                    f"mesh {'broadcast ' if broadcast else ''}join "
-                    f"overflowed its shuffle/output capacity (shuffle "
-                    f"{shuf_cap}, out {out_cap}) after retries")
-            out_cap *= 2
-            if shuf_cap is not None:
-                shuf_cap *= 2
-            self.metrics().add("capacity_recompiles", 1)
-        if broadcast:
-            self.metrics().add("broadcast_joins", 1)
-        del dp, dpm, db, dbm
-
-        dicts = dict(probe.dicts)
-        if self.join_type in ("inner", "left"):
-            dicts.update(build.dicts)
-        out_cols, out_mask = _unshard((out_cols, out_mask))
-        result = ColumnBatch(self._schema, out_cols, out_mask, dicts)
-        # deferred: the count becomes host-known for free when the shuffle
-        # writer's packed fetch materializes this batch (an eager .num_rows
-        # costs a scalar sync per task where remote_device() holds)
-        deferred_rows(self.metrics(), "output_rows", result)
-        self.metrics().add("mesh_devices", n_dev)
-        return [result]
+            # a send bucket's need is at most its shard, so the capped
+            # power of two still holds it
+            bounds = (
+                min(_pow2(p_need), p_shard) if "probe" in passed else p_cap,
+                min(_pow2(b_need), b_shard) if "build" in passed else b_cap,
+                _pow2(pairs) if "output" in passed else out_cap)
+            _EXCHANGE_NEED[memo] = bounds
+            attrs["side_overflowed"] = "+".join(passed)
+            self.metrics().add("exchange_retries", 1)
+            device_obs.record_mesh_join_retry()
+        raise CapacityError(
+            f"mesh {'broadcast' if broadcast else 'partitioned'} join passed "
+            f"a bound that its input cannot pass (send buckets {p_cap} / "
+            f"{b_cap} and {out_cap} pairs a device over {p_live} probe and "
+            f"{b_live} build rows; the run needed {p_need} / {b_need} and "
+            f"{pairs})")
 
     def _label(self):
         on = ", ".join(f"{l} = {r}" for l, r in self.on)
@@ -842,9 +910,9 @@ class MeshTaskJoinExec(MeshJoinExec):
 
     def _execute(self, partition: int, ctx: TaskContext) -> List[ColumnBatch]:
         probe = concat_batches(
-            self.left.schema, self.left.execute(partition, ctx)).shrink()
+            self.left.schema, self.left.execute(partition, ctx))
         build = concat_batches(
-            self.right.schema, self.right.execute(partition, ctx)).shrink()
+            self.right.schema, self.right.execute(partition, ctx))
         return self._join_batches(probe, build, ctx)
 
     def _label(self):

@@ -301,7 +301,9 @@ def _probe_emit(join_type, key_names, sflags, null_key_sentinel, probe_names,
     variants: sorted build, one range lookup, pair expansion and collision
     re-verification, then emit by join type.  Both sides are already
     device-local (either shuffled to the bucket owner, or the build side
-    all_gathered)."""
+    all_gathered).  Returns ``(out_cols, out_mask, pairs)``: ``pairs``, the
+    candidate pairs the lookup found, past ``out_capacity`` means pairs were
+    left out."""
     rpk = [p_cols[k] for k in key_names]
     rbk = [b_cols[k] for k in key_names]
 
@@ -316,7 +318,6 @@ def _probe_emit(join_type, key_names, sflags, null_key_sentinel, probe_names,
         if sflags[i]:
             ok = ok & (a[pi] != jnp.asarray(null_key_sentinel,
                                             dtype=a.dtype))
-    ovf_j = total > out_capacity
 
     if join_type in ("semi", "anti"):
         hit = K.segment_any(ok, pi, p_mask.shape[0])
@@ -338,7 +339,24 @@ def _probe_emit(join_type, key_names, sflags, null_key_sentinel, probe_names,
                 for m in out_cols
             }
             out_mask = jnp.concatenate([out_mask, miss])
-    return out_cols, out_mask, ovf_j
+    return out_cols, out_mask, total
+
+
+def _join_stats(axis, overflow, probe_need, build_need, pairs, out_mask):
+    """A join program's last output, replicated int32 ``[overflow,
+    probe_need, build_need, pairs_need, rows_out]``: whether any bound was
+    passed on any device (the result is then short of rows and must not be
+    used), the fullest probe and build send bucket on any device (the
+    capacities at which nothing would have been left out), the most
+    candidate pairs a device found (the ``out_capacity`` a re-run needs;
+    exact where no send bucket overflowed, for a dropped row finds no pair)
+    and the rows out in all."""
+    return jnp.stack([
+        lax.psum(overflow.astype(jnp.int32), axis),
+        lax.pmax(probe_need.astype(jnp.int32), axis),
+        lax.pmax(build_need.astype(jnp.int32), axis),
+        lax.pmax(pairs.astype(jnp.int32), axis),
+        lax.psum(jnp.sum(out_mask, dtype=jnp.int32), axis)])
 
 
 def distributed_broadcast_join(
@@ -366,7 +384,8 @@ def distributed_broadcast_join(
     gates this on build-side size (MESH_BROADCAST_ROWS).
 
     Returns ``run(pcols, pmask, bcols, bmask)`` like
-    ``distributed_hash_join``; outputs stay probe-sharded.
+    ``distributed_hash_join``, whose ``stats`` has no send bucket to report
+    (both needs 0); outputs stay probe-sharded.
     """
     n = mesh_axis_size(mesh, axis)
     key_names = [f"__jk{i}" for i in range(n_keys)]
@@ -376,12 +395,13 @@ def distributed_broadcast_join(
         b_all = {k: lax.all_gather(v, axis, tiled=True)
                  for k, v in bcols.items()}
         bm_all = lax.all_gather(bmask, axis, tiled=True)
-        out_cols, out_mask, ovf_j = _probe_emit(
+        out_cols, out_mask, pairs = _probe_emit(
             join_type, key_names, sflags, null_key_sentinel, probe_names,
             build_names, build_fill, out_capacity,
             pcols, pmask, b_all, bm_all)
-        overflow = lax.psum(ovf_j.astype(jnp.int32), axis) > 0
-        return out_cols, out_mask, overflow
+        none = jnp.zeros((), jnp.int32)
+        return out_cols, out_mask, _join_stats(
+            axis, pairs > out_capacity, none, none, pairs, out_mask)
 
     def gathered(pcols, pmask, bcols, bmask):
         # every device receives the whole build side
@@ -398,7 +418,8 @@ def distributed_hash_join(
     probe_names: Sequence[str],
     build_names: Sequence[str],
     join_type: str,
-    shuffle_capacity: int,
+    probe_capacity: int,
+    build_capacity: int,
     out_capacity: int,
     build_fill: Dict[str, object],
     string_key_flags: Sequence[bool] = (),
@@ -415,9 +436,14 @@ def distributed_hash_join(
     pass-through or stable string hashes, ops/expressions.compile_key) plus
     payload columns.  ``join_type``: inner | left | semi | anti.
 
-    Returns ``run(pcols, pmask, bcols, bmask) -> (out_cols, out_mask,
-    overflow)`` with outputs sharded over the mesh, ``out_capacity`` rows
-    per device (inner/left add probe capacity for unmatched-row append).
+    The static bounds, per device: ``probe_capacity`` and
+    ``build_capacity`` rows a destination bucket of each side's send buffer
+    (a device receives ``n`` such buckets a side), ``out_capacity``
+    candidate pairs.  Returns ``run(pcols, pmask, bcols, bmask) ->
+    (out_cols, out_mask, stats)`` with outputs sharded over the mesh,
+    ``out_capacity`` rows per device for inner joins (left joins add the
+    received probe rows for unmatched-row append, semi and anti joins are
+    the received probe rows), and ``stats`` as ``_join_stats`` gives it.
     """
     n = mesh_axis_size(mesh, axis)
     key_names = [f"__jk{i}" for i in range(n_keys)]
@@ -431,30 +457,34 @@ def distributed_hash_join(
             p_recv, p_rmask = pcols, pmask
             b_recv, b_rmask = bcols, bmask
             ovf_exchange = jnp.zeros((), bool)
+            p_need = b_need = jnp.zeros((), jnp.int32)
         else:
             pk = [pcols[k] for k in key_names]
             bk = [bcols[k] for k in key_names]
             # ship rows to their key-hash bucket owner (both sides agree)
             pdest = K.bucket_of(pk, n)
             bdest = K.bucket_of(bk, n)
-            p_recv, p_rmask, ovf_p, _ = shuffle_rows(
-                pcols, pdest, pmask, axis, n, shuffle_capacity)
-            b_recv, b_rmask, ovf_b, _ = shuffle_rows(
-                bcols, bdest, bmask, axis, n, shuffle_capacity)
+            p_recv, p_rmask, ovf_p, p_need = shuffle_rows(
+                pcols, pdest, pmask, axis, n, probe_capacity)
+            b_recv, b_rmask, ovf_b, b_need = shuffle_rows(
+                bcols, bdest, bmask, axis, n, build_capacity)
             ovf_exchange = ovf_p[0] | ovf_b[0]
-        out_cols, out_mask, ovf_j = _probe_emit(
+            p_need, b_need = p_need[0], b_need[0]
+        out_cols, out_mask, pairs = _probe_emit(
             join_type, key_names, sflags, null_key_sentinel, probe_names,
             build_names, build_fill, out_capacity,
             p_recv, p_rmask, b_recv, b_rmask)
-        overflow = lax.psum(
-            (ovf_exchange | ovf_j).astype(jnp.int32), axis) > 0
-        return out_cols, out_mask, overflow
+        return out_cols, out_mask, _join_stats(
+            axis, ovf_exchange | (pairs > out_capacity), p_need, b_need,
+            pairs, out_mask)
 
     def sent(pcols, pmask, bcols, bmask):
-        # every device sends n buckets of shuffle_capacity rows a side
-        rows = 0 if n == 1 else n * n * shuffle_capacity
-        return rows * (_row_bytes(pcols.values())
-                       + _row_bytes(bcols.values()) + 2)
+        # every device sends n buckets of each side's capacity, with a mask
+        # byte a row
+        if n == 1:
+            return 0
+        return n * n * (probe_capacity * (_row_bytes(pcols.values()) + 1)
+                        + build_capacity * (_row_bytes(bcols.values()) + 1))
 
     return _make_join_runner("mesh.join_partitioned", per_shard, mesh,
                              probe_names, build_names, join_type, axis,

@@ -21,7 +21,7 @@ REPARTITION_JOINS = "ballista.repartition.joins"
 REPARTITION_AGGREGATIONS = "ballista.repartition.aggregations"
 PARQUET_PRUNING = "ballista.parquet.pruning"
 # TPU-native knobs
-JOIN_OUTPUT_FACTOR = "ballista.join.output_factor"  # mesh joins: out_cap = factor * per-device probe share
+JOIN_OUTPUT_FACTOR = "ballista.join.output_factor"  # mesh joins: first bound on a device's candidate pairs
 JOIN_MAX_CAPACITY = "ballista.join.max_capacity"  # ceiling for adaptive retry
 COLLECT_STATISTICS = "ballista.collect_statistics"
 MESH_SHUFFLE = "ballista.shuffle.mesh"  # use ICI all-to-all when executors co-located on a mesh
@@ -209,8 +209,14 @@ _ENTRIES: Dict[str, ConfigEntry] = {
                     "accepted and propagated but not yet consulted"),
         ConfigEntry(PARQUET_PRUNING, True, _parse_bool, "row-group pruning on parquet scans"),
         ConfigEntry(JOIN_OUTPUT_FACTOR, 2, int,
-                    "mesh-join output capacity = factor * per-device probe "
-                    "share (plain joins size outputs by a count pass)"),
+                    "mesh joins: the candidate pairs a device may expand "
+                    "start at factor * a device's even share of the "
+                    "smaller side's live rows (of the probe side's in a "
+                    "broadcast join); each side's send bucket holds twice "
+                    "its even share of that side's live rows; a bound "
+                    "passed is reported and the join runs once more at "
+                    "what it needed (plain joins size outputs by a count "
+                    "pass)"),
         ConfigEntry(JOIN_MAX_CAPACITY, 1 << 26, int,
                     "hard ceiling for adaptive join-capacity growth (rows)"),
         ConfigEntry(COLLECT_STATISTICS, True, _parse_bool,

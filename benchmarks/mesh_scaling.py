@@ -67,20 +67,21 @@ def _child(n_dev: int, rows: int, iters: int) -> None:
              place(np.ones(rows, dtype=bool)))
     build = ({"__jk0": place(bk), "w": place(bk * 2)},
              place(np.ones(n_build, dtype=bool)))
-    # shuffle_capacity is PER (device, bucket) SLOT: expected load is
+    # a send capacity is PER (device, bucket) SLOT: expected load is
     # rows/n^2, 4x headroom; out_capacity is per device: ~rows/n matches
     jrun = distributed_hash_join(
         mesh, 1, ["__jk0", "v"], ["__jk0", "w"], "inner",
-        shuffle_capacity=max(1024, 4 * rows // (n_dev * n_dev)),
+        probe_capacity=max(1024, 4 * rows // (n_dev * n_dev)),
+        build_capacity=max(1024, 4 * n_build // (n_dev * n_dev)),
         out_capacity=max(2048, 2 * rows // n_dev), build_fill={"w": 0})
     t0 = time.perf_counter()
-    out = jrun(probe, build)
+    out = jrun(*probe, *build)
     jax.block_until_ready(out)
     jcompile_s = time.perf_counter() - t0
     ts = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        out = jrun(probe, build)
+        out = jrun(*probe, *build)
         jax.block_until_ready(out)
         ts.append(time.perf_counter() - t0)
     join_ms = float(np.median(ts)) * 1000

@@ -215,7 +215,7 @@ print("NAMES " + json.dumps([
                                    64, 64).name,
     D.distributed_partial_aggregate(mesh, ident, ["a", "b", "c"],
                                     [("v", "sum")], 64).name,
-    D.distributed_hash_join(mesh, 1, ["x"], ["y"], "inner", 64, 64,
+    D.distributed_hash_join(mesh, 1, ["x"], ["y"], "inner", 64, 64, 64,
                             {}).name,
     D.distributed_broadcast_join(mesh, 1, ["x"], ["y"], "semi", 64,
                                  {}).name]))
