@@ -13,12 +13,15 @@ Key techniques:
   flags -> a segmented scan per aggregate -> a second sort that brings the
   run ends to the front), exact for any key combination, no hash tables in
   HBM required and no row moved by an index;
-- joins sort the build side by a 64-bit mixed key; a probe batch finds every
-  row's run of equal hashes in it once (``probe_ranges``: one sort of
-  probe and build together, no search), and
+- joins that emit pairs sort the build side by a 64-bit mixed key; a probe
+  batch finds every row's run of equal hashes in it once (``probe_ranges``:
+  one sort of probe and build together, no search), and
   everything after it (``expand_pairs``: variable fan-out through a
   cumulative-offset inversion) takes that range as an operand; *real* key
   equality is then verified so hash collisions never corrupt results;
+- a mesh semi or anti join only asks whether a key is present
+  (``key_membership``): one sort of both sides' key values with a tag, one
+  scan and one sort back to probe order, no hash, pair or gather;
 - calendar decomposition (EXTRACT) uses the civil-from-days algorithm in
   pure integer arithmetic.
 """
@@ -1101,6 +1104,48 @@ def probe_ranges(
                                                      mode="drop")
     counts = jnp.where(probe_mask, counts, 0)
     return lo, counts, jnp.sum(counts)
+
+
+def key_membership(probe_keys: Sequence[jnp.ndarray], probe_mask: jnp.ndarray,
+                   build_keys: Sequence[jnp.ndarray], build_mask: jnp.ndarray):
+    """Whether a live build row has each live probe row's key: ``hit``, a
+    bool a probe slot (False on a dead one).  A semi or anti join's whole
+    question: no pair, build position or build payload is asked for.
+
+    Read off one sort of both sides' key values together, column by column
+    (each pair cast to ``jnp.promote_types`` of the two, the comparison a
+    join makes), with a tag as the last key: 0 a live build row, 1 a probe
+    row, 2 a dead build row.  A run of equal keys then starts with its live
+    build rows, so a probe row hits exactly when its run starts with tag 0;
+    one doubling scan carries that bit along each run, and a second sort on
+    the source slot brings the bits back to probe order.  The values
+    themselves are sorted, not their hashes, so the answer is exact with no
+    re-verification and ``force_hash_collisions`` cannot change it.  No
+    gather, scatter, search or segment reduction: the chip sorts a slot in
+    5 ns and gathers a 64-bit word in 44-130 (PERF.md section 6)."""
+    n_p = probe_mask.shape[0]
+    n = n_p + build_mask.shape[0]
+    assert 2 * n < 2**31, "slot positions and the run bit share an int32"
+    cols = []
+    for a, b in zip(probe_keys, build_keys):
+        dt = jnp.promote_types(a.dtype, b.dtype)
+        cols.append(jnp.concatenate([a.astype(dt), b.astype(dt)]))
+    tag = jnp.concatenate([jnp.ones(n_p, jnp.int32),
+                           jnp.where(build_mask, 0, 2).astype(jnp.int32)])
+    *keys, tag, src = jax.lax.sort(
+        (*cols, tag, jnp.arange(n, dtype=jnp.int32)),
+        num_keys=len(cols) + 1, is_stable=False)
+    new_run = keys[0][1:] != keys[0][:-1]
+    for k in keys[1:]:
+        new_run = new_run | (k[1:] != k[:-1])
+    first = jnp.concatenate([jnp.ones(1, bool), new_run])
+    pos = jnp.arange(n, dtype=jnp.int32)
+    # the latest run start at or before each slot, its bit: a live build row
+    lead = _shift_scan(jnp.where(first, 2 * pos + (tag == 0), -1),
+                       jnp.maximum, -1)
+    _, hit = jax.lax.sort((src, (lead & 1).astype(bool)), num_keys=1,
+                          is_stable=False)
+    return hit[:n_p] & probe_mask
 
 
 def _shift_scan(x: jnp.ndarray, op, identity, reverse: bool = False):

@@ -637,10 +637,15 @@ class MeshJoinExec(ExecutionPlan):
 
     Replaces JoinExec(partitioned) <- Repartition(hash) x2 when the mesh
     path is enabled: both sides all_to_all by key bucket, then a per-device
-    sorted-build/range-lookup join — ONE XLA program where the
+    join — ONE XLA program where the
     reference materializes two shuffles and a reduce stage (exchange rules
     planner.rs:133-152; SURVEY.md §2.5 TP row).  Results are identical to
     the file-shuffle JoinExec path — verified by tests/test_mesh_exec.py.
+    The per-device join takes one of two paths, by join type: an inner or
+    left join sorts the build, looks up each probe row's range and expands
+    the pairs; a semi or anti join asks only whether a key is present, by
+    one sort of both sides' keys and one back (``kernels.key_membership``),
+    and counts ``membership_joins``.
 
     Its bounds come from each side's live rows, fetched once a join (each
     side compacted where that frees a power of two of slots), not from the
@@ -650,7 +655,8 @@ class MeshJoinExec(ExecutionPlan):
     bucket holds twice its even share (``_join_send_capacity``), and the
     candidate pairs a device may expand are
     ``ballista.join.output_factor`` times a device's even share of the
-    smaller side's rows (of the probe side's in a broadcast join).  A
+    smaller side's rows (of the probe side's in a broadcast join; a semi or
+    anti join expands none and has no such bound).  A
     program that passes a bound reports what it needed; the join runs
     again at that, and a later execution over the same rows starts there
     (``_EXCHANGE_NEED``).  A send bucket's need is exact at once, the
@@ -814,27 +820,38 @@ class MeshJoinExec(ExecutionPlan):
         base = (n_dev, len(self.on), tuple(lsch.names()),
                 tuple(rsch.names()), self.join_type,
                 tuple(sorted(rfill.items())), tuple(sflags), sentinel)
-        factor = ctx.config.get(JOIN_OUTPUT_FACTOR)
+        # a semi or anti join asks only whether a key is present (the key
+        # sort of kernels.key_membership): no pair, so no pair bound
+        membership = self.join_type in ("semi", "anti")
+        out_cap = None
+        if not membership:
+            factor = ctx.config.get(JOIN_OUTPUT_FACTOR)
+            out_cap = _pow2(factor * -(-(p_live if broadcast
+                                         else min(p_live, b_live)) // n_dev))
         if broadcast:
             # the probe rows stay where they are: no send bucket
-            bounds = (None, None,
-                      _pow2(factor * -(-p_live // n_dev)))
+            bounds = (None, None, out_cap)
         else:
             bounds = (_join_send_capacity(p_live, p_shard, n_dev),
-                      _join_send_capacity(b_live, b_shard, n_dev),
-                      _pow2(factor * -(-min(p_live, b_live) // n_dev)))
+                      _join_send_capacity(b_live, b_shard, n_dev), out_cap)
         memo = ("mesh_join", broadcast, p_live, b_live, p_shard,
                 b_shard) + base
         bounds = _EXCHANGE_NEED.get(memo, bounds)
         attrs = {"probe_rows": p_live, "build_rows": b_live}
+        if membership:
+            attrs["membership"] = 1
         for retries in range(3):
             p_cap, b_cap, out_cap = bounds
             if not broadcast:
                 attrs.update(probe_capacity=p_cap, build_capacity=b_cap)
+            if out_cap is not None:
+                attrs["out_capacity"] = out_cap
+            # the bounds a program has (a key holding None is not shared)
+            held = tuple(b for b in bounds if b is not None)
             with self.xla_lock():
                 if broadcast:
                     prog = _program(
-                        self, ("mesh_join_broadcast", out_cap) + base,
+                        self, ("mesh_join_broadcast",) + held + base,
                         lambda: distributed_broadcast_join(
                             mesh, len(self.on), list(lsch.names()),
                             list(rsch.names()), self.join_type, out_cap,
@@ -842,8 +859,7 @@ class MeshJoinExec(ExecutionPlan):
                             null_key_sentinel=sentinel))
                 else:
                     prog = _program(
-                        self, ("mesh_join_partitioned", p_cap, b_cap,
-                               out_cap) + base,
+                        self, ("mesh_join_partitioned",) + held + base,
                         lambda: distributed_hash_join(
                             mesh, len(self.on), list(lsch.names()),
                             list(rsch.names()), self.join_type, p_cap,
@@ -851,13 +867,15 @@ class MeshJoinExec(ExecutionPlan):
                             null_key_sentinel=sentinel))
             out_cols, out_mask, stats = _dispatch(
                 prog, *args, describe=lambda st: {"rows_out": int(st[4])},
-                out_capacity=out_cap, retries=retries, **attrs)
+                retries=retries, **attrs)
             overflow, p_need, b_need, pairs, rows_out = (int(v)
                                                          for v in stats)
             if not overflow:
                 self.metrics().add(
                     "broadcast_joins" if broadcast else "partitioned_joins",
                     1)
+                if membership:
+                    self.metrics().add("membership_joins", 1)
                 return out_cols, out_mask, rows_out
             del out_cols, out_mask
             passed = [side for side, need, cap in (

@@ -15,7 +15,7 @@ transport per stage boundary.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -298,14 +298,27 @@ def _probe_emit(join_type, key_names, sflags, null_key_sentinel, probe_names,
                 build_names, build_fill, out_capacity,
                 p_cols, p_mask, b_cols, b_mask):
     """Local half of a hash join, shared by the partitioned and broadcast
-    variants: sorted build, one range lookup, pair expansion and collision
-    re-verification, then emit by join type.  Both sides are already
-    device-local (either shuffled to the bucket owner, or the build side
-    all_gathered).  Returns ``(out_cols, out_mask, pairs)``: ``pairs``, the
-    candidate pairs the lookup found, past ``out_capacity`` means pairs were
-    left out."""
+    variants.  Both sides are already device-local (either shuffled to the
+    bucket owner, or the build side all_gathered).  A semi or anti join
+    asks only whether a live build row has the probe row's key: one sort
+    of both sides' keys and one back (``K.key_membership``), no pair, and
+    ``out_capacity`` unused.  An inner or left join takes the sorted build,
+    one range lookup, pair expansion and collision re-verification, then
+    emits by join type.  Returns ``(out_cols, out_mask, pairs)``:
+    ``pairs``, the candidate pairs the lookup found (0 in a semi or anti
+    join), past ``out_capacity`` means pairs were left out."""
     rpk = [p_cols[k] for k in key_names]
     rbk = [b_cols[k] for k in key_names]
+
+    if join_type in ("semi", "anti"):
+        hit = K.key_membership(rpk, p_mask, rbk, b_mask)
+        for i, a in enumerate(rpk):
+            if sflags[i]:   # a NULL string key meets nothing
+                hit = hit & (a != jnp.asarray(null_key_sentinel,
+                                              dtype=a.dtype))
+        out_mask = p_mask & (hit if join_type == "semi" else ~hit)
+        return ({m: p_cols[m] for m in probe_names}, out_mask,
+                jnp.zeros((), jnp.int32))
 
     bh_sorted, border, _ = K.build_side_sort(rbk, b_mask)
     lo, counts, _ = K.probe_ranges(K.hash64(rpk), p_mask, bh_sorted)
@@ -319,26 +332,21 @@ def _probe_emit(join_type, key_names, sflags, null_key_sentinel, probe_names,
             ok = ok & (a[pi] != jnp.asarray(null_key_sentinel,
                                             dtype=a.dtype))
 
-    if join_type in ("semi", "anti"):
+    out_cols = {m: p_cols[m][pi] for m in probe_names}
+    out_cols.update({m: b_cols[m][bidx] for m in build_names})
+    out_mask = ok
+    if join_type == "left":
         hit = K.segment_any(ok, pi, p_mask.shape[0])
-        out_mask = p_mask & (hit if join_type == "semi" else ~hit)
-        out_cols = {m: p_cols[m] for m in probe_names}
-    else:
-        out_cols = {m: p_cols[m][pi] for m in probe_names}
-        out_cols.update({m: b_cols[m][bidx] for m in build_names})
-        out_mask = ok
-        if join_type == "left":
-            hit = K.segment_any(ok, pi, p_mask.shape[0])
-            miss = p_mask & ~hit
-            out_cols = {
-                m: jnp.concatenate([
-                    out_cols[m],
-                    p_cols[m] if m in probe_names else jnp.full(
-                        p_mask.shape[0], build_fill[m], out_cols[m].dtype),
-                ])
-                for m in out_cols
-            }
-            out_mask = jnp.concatenate([out_mask, miss])
+        miss = p_mask & ~hit
+        out_cols = {
+            m: jnp.concatenate([
+                out_cols[m],
+                p_cols[m] if m in probe_names else jnp.full(
+                    p_mask.shape[0], build_fill[m], out_cols[m].dtype),
+            ])
+            for m in out_cols
+        }
+        out_mask = jnp.concatenate([out_mask, miss])
     return out_cols, out_mask, total
 
 
@@ -359,13 +367,24 @@ def _join_stats(axis, overflow, probe_need, build_need, pairs, out_mask):
         lax.psum(jnp.sum(out_mask, dtype=jnp.int32), axis)])
 
 
+def _pair_bound(join_type: str, out_capacity: Optional[int]) -> int:
+    """``out_capacity`` as a join program compares its pairs with it: a
+    semi or anti join expands no pair (its ``pairs`` is 0), so its bound
+    may be None; an inner or left join's may not."""
+    if out_capacity is None:
+        if join_type not in ("semi", "anti"):
+            raise ValueError(f"a {join_type} join needs an out_capacity")
+        return 0
+    return out_capacity
+
+
 def distributed_broadcast_join(
     mesh: Mesh,
     n_keys: int,
     probe_names: Sequence[str],
     build_names: Sequence[str],
     join_type: str,
-    out_capacity: int,
+    out_capacity: Optional[int],
     build_fill: Dict[str, object],
     string_key_flags: Sequence[bool] = (),
     null_key_sentinel: int = 0,
@@ -388,6 +407,7 @@ def distributed_broadcast_join(
     (both needs 0); outputs stay probe-sharded.
     """
     n = mesh_axis_size(mesh, axis)
+    out_capacity = _pair_bound(join_type, out_capacity)
     key_names = [f"__jk{i}" for i in range(n_keys)]
     sflags = list(string_key_flags) or [False] * n_keys
 
@@ -420,14 +440,15 @@ def distributed_hash_join(
     join_type: str,
     probe_capacity: int,
     build_capacity: int,
-    out_capacity: int,
+    out_capacity: Optional[int],
     build_fill: Dict[str, object],
     string_key_flags: Sequence[bool] = (),
     null_key_sentinel: int = 0,
     axis: str = PART_AXIS,
 ):
     """Fused partitioned hash join over the ICI mesh: key-bucket all_to_all
-    of BOTH sides, then per-device sorted-build/range-lookup join —
+    of BOTH sides, then the per-device join of ``_probe_emit`` (a key sort
+    for a semi or anti join, the sorted-build range lookup otherwise) —
     one XLA program replacing the reference's two shuffle stage pairs +
     reduce tasks (reference planner.rs:133-152 inserts hash RepartitionExec
     under each join side; exchange inventory SURVEY.md §2.5).
@@ -439,13 +460,15 @@ def distributed_hash_join(
     The static bounds, per device: ``probe_capacity`` and
     ``build_capacity`` rows a destination bucket of each side's send buffer
     (a device receives ``n`` such buckets a side), ``out_capacity``
-    candidate pairs.  Returns ``run(pcols, pmask, bcols, bmask) ->
+    candidate pairs (None in a semi or anti join, which expands none).
+    Returns ``run(pcols, pmask, bcols, bmask) ->
     (out_cols, out_mask, stats)`` with outputs sharded over the mesh,
     ``out_capacity`` rows per device for inner joins (left joins add the
     received probe rows for unmatched-row append, semi and anti joins are
     the received probe rows), and ``stats`` as ``_join_stats`` gives it.
     """
     n = mesh_axis_size(mesh, axis)
+    out_capacity = _pair_bound(join_type, out_capacity)
     key_names = [f"__jk{i}" for i in range(n_keys)]
     sflags = list(string_key_flags) or [False] * n_keys
 

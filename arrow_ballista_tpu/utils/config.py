@@ -209,10 +209,12 @@ _ENTRIES: Dict[str, ConfigEntry] = {
                     "accepted and propagated but not yet consulted"),
         ConfigEntry(PARQUET_PRUNING, True, _parse_bool, "row-group pruning on parquet scans"),
         ConfigEntry(JOIN_OUTPUT_FACTOR, 2, int,
-                    "mesh joins: the candidate pairs a device may expand "
-                    "start at factor * a device's even share of the "
-                    "smaller side's live rows (of the probe side's in a "
-                    "broadcast join); each side's send bucket holds twice "
+                    "mesh inner and left joins: the candidate pairs a "
+                    "device may expand start at factor * a device's even "
+                    "share of the smaller side's live rows (of the probe "
+                    "side's in a broadcast join; a semi or anti join "
+                    "expands no pair and ignores it); each side's send "
+                    "bucket holds twice "
                     "its even share of that side's live rows; a bound "
                     "passed is reported and the join runs once more at "
                     "what it needed (plain joins size outputs by a count "

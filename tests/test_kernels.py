@@ -182,6 +182,71 @@ def test_probe_join_expansion(rng):
     assert got == exp
 
 
+def _membership_case(name, rng):
+    """(probe key columns, probe mask, build key columns, build mask)."""
+    i64 = lambda lo, hi, n: rng.integers(lo, hi, n).astype(np.int64)
+    if name in ("duplicates_on_both_sides", "every_hash_equal"):
+        pk, bk = [i64(0, 60, 700)], [i64(0, 40, 900)]
+        pm, bm = rng.random(700) < 0.8, rng.random(900) < 0.7
+    elif name == "dead_build_row_holds_the_key":
+        pk, bk = [np.array([5, 6, 7, 8], np.int64)], [
+            np.array([6, 7, 7, 9, 5], np.int64)]
+        pm = np.ones(4, bool)
+        bm = np.array([False, True, False, True, False])  # 5 and 6 only dead
+    elif name == "empty_build":
+        pk, bk = [i64(0, 9, 50)], [np.zeros(0, np.int64)]
+        pm, bm = rng.random(50) < 0.9, np.zeros(0, bool)
+    elif name == "all_dead_build":
+        pk = [i64(0, 9, 50)]
+        bk = [pk[0].copy()]
+        pm, bm = np.ones(50, bool), np.zeros(50, bool)
+    elif name == "two_columns_each_alone_matches":
+        pk = [np.array([1, 2, 1, 2, 3], np.int64),
+              np.array([10, 20, 20, 10, 30], np.int32)]
+        bk = [np.array([1, 2, 3, 3], np.int64),
+              np.array([10, 20, 31, 30], np.int32)]
+        pm, bm = np.ones(5, bool), np.array([True, True, True, False])
+    elif name == "int32_probe_int64_build":
+        pk = [rng.integers(-5, 5, 300).astype(np.int32)]
+        bk = [np.concatenate([i64(-5, 0, 100), i64(2**32, 2**32 + 5, 100),
+                              i64(-(2**40), -(2**40) + 5, 100)])]
+        pm, bm = rng.random(300) < 0.9, rng.random(300) < 0.9
+    else:
+        raise AssertionError(name)
+    return pk, pm, bk, bm
+
+
+@pytest.mark.parametrize("case", [
+    "duplicates_on_both_sides", "dead_build_row_holds_the_key", "empty_build",
+    "all_dead_build", "two_columns_each_alone_matches",
+    "int32_probe_int64_build", "every_hash_equal"])
+def test_key_membership_equals_isin_over_live_rows(case, rng, monkeypatch):
+    """``key_membership`` against ``np.isin`` of the probe rows' key tuples
+    in the live build rows' (by value, across dtypes); dead probe rows never
+    hit.  With every hash equal (``force_hash_collisions``) the answer is
+    the same: the kernel sorts values, not hashes."""
+    pk, pm, bk, bm = _membership_case(case, rng)
+    if case == "every_hash_equal":
+        monkeypatch.setattr(K, "_FORCE_COLLISIONS", True)
+        assert not np.asarray(K.hash64([jnp.asarray(pk[0])])).any()
+    hit = np.asarray(K.key_membership([jnp.asarray(a) for a in pk],
+                                      jnp.asarray(pm),
+                                      [jnp.asarray(b) for b in bk],
+                                      jnp.asarray(bm)))
+    assert hit.dtype == np.bool_ and hit.shape == pm.shape
+    live = {tuple(int(c[j]) for c in bk) for j in np.flatnonzero(bm)}
+    want = np.array([bool(pm[i]) and tuple(int(c[i]) for c in pk) in live
+                     for i in range(pm.size)])
+    if len(pk) == 1:
+        assert np.array_equal(
+            want, pm & np.isin(pk[0].astype(np.int64), bk[0][bm]))
+    assert np.array_equal(hit, want)
+    if case == "dead_build_row_holds_the_key":
+        assert hit.tolist() == [False, False, True, False]
+    if case == "two_columns_each_alone_matches":
+        assert hit.tolist() == [True, True, False, False, False]
+
+
 def test_civil_from_days():
     dates = pd.to_datetime(["1970-01-01", "1992-02-29", "1998-12-01", "2049-07-04", "1901-03-01"])
     days = (dates - pd.Timestamp("1970-01-01")).days.to_numpy().astype(np.int32)
@@ -810,3 +875,57 @@ def test_exchange_program_moves_no_row_by_index(tpu_branches):
               if eqn.primitive.name == "dynamic_slice"
               and eqn.outvars[0].aval.shape == (shuffle,)]
     assert len(slices) == 8, slices
+
+
+@pytest.mark.parametrize("join_type", ["semi", "anti"])
+def test_membership_join_moves_no_row_by_index(join_type):
+    """A semi or anti join's local half (``distributed._probe_emit``) over
+    2^12 probe and 2^14 build slots of two key columns: two sorts (the keys
+    with their tag, the bits back to probe order), no scatter, no gather,
+    no search loop, no ``cumsum``, and no pair."""
+    from arrow_ballista_tpu.parallel import distributed
+
+    n_p, n_b = 1 << 12, 1 << 14
+    keys = ["__jk0", "__jk1"]
+
+    def emit(pcols, pmask, bcols, bmask):
+        return distributed._probe_emit(
+            join_type, keys, [False, False], 0, ["x"], [], {}, None,
+            pcols, pmask, bcols, bmask)
+
+    col = lambda n, dt: jax.ShapeDtypeStruct((n,), dt)
+    closed = jax.make_jaxpr(emit)(
+        {"__jk0": col(n_p, np.int64), "__jk1": col(n_p, np.int32),
+         "x": col(n_p, np.int64)}, col(n_p, np.bool_),
+        {"__jk0": col(n_b, np.int64), "__jk1": col(n_b, np.int64)},
+        col(n_b, np.bool_))
+    prims = list(_primitives(closed.jaxpr))
+    assert prims.count("sort") == 2, prims
+    assert not _moved_by_index(closed.jaxpr, 1)
+    assert not [p for p in prims if p.startswith("cum") or p == "while"]
+    # the probe rows' column and mask, no pair
+    assert [a.shape for a in closed.out_avals] == [(n_p,), (n_p,), ()]
+
+
+@pytest.mark.parametrize("string_key", [True, False])
+@pytest.mark.parametrize("join_type", ["semi", "anti"])
+def test_membership_join_never_matches_a_null_string_key(join_type,
+                                                         string_key):
+    """A string key column's NULL (``NULL_KEY_SENTINEL``) meets nothing,
+    even a build row that holds the sentinel: a semi join leaves such a
+    probe row out, an anti join keeps it.  A numeric key column has no
+    such value."""
+    from arrow_ballista_tpu.ops.expressions import ExprCompiler
+    from arrow_ballista_tpu.parallel import distributed
+
+    s = ExprCompiler.NULL_KEY_SENTINEL
+    pk = jnp.asarray(np.array([s, 1, 2, s], np.uint64))
+    bk = jnp.asarray(np.array([s, 1], np.uint64))
+    out_cols, out_mask, pairs = distributed._probe_emit(
+        join_type, ["__jk0"], [string_key], int(s), ["x"], [], {}, None,
+        {"__jk0": pk, "x": jnp.arange(4)}, jnp.ones(4, bool),
+        {"__jk0": bk}, jnp.ones(2, bool))
+    hit = [not string_key, True, False, not string_key]
+    want = hit if join_type == "semi" else [not h for h in hit]
+    assert np.asarray(out_mask).tolist() == want
+    assert list(out_cols) == ["x"] and int(pairs) == 0

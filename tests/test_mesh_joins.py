@@ -189,8 +189,16 @@ def test_partitioned_mesh_join_equals_the_reference(data, four_devices, q):
 
     p_cap, b_cap = span.attrs["probe_capacity"], span.attrs["build_capacity"]
     assert (p_cap, b_cap) == (send(p_live), send(b_live))
-    assert span.attrs["out_capacity"] == mesh_exec._pow2(
-        2 * -(-min(p_live, b_live) // 4))
+    if join_type == "semi":
+        # the key sort answers it: no pair, so no pair bound
+        assert "out_capacity" not in span.attrs
+        assert span.attrs["membership"] == 1
+        assert metrics["membership_joins"] == 1
+    else:
+        assert span.attrs["out_capacity"] == mesh_exec._pow2(
+            2 * -(-min(p_live, b_live) // 4))
+        assert "membership" not in span.attrs
+        assert "membership_joins" not in metrics
     # four devices send four buckets a side: the probe's columns and key,
     # and of the build side only its key in the semi join, a mask byte a row
     probe_row, build_row = {"q4": (8 + 4 + 4 + 8, 8),
@@ -228,6 +236,66 @@ def _spread(values: dict, slots: int, rng) -> tuple:
         full[where] = v
         cols[k] = full
     return cols, live
+
+
+@pytest.mark.parametrize("join_type", ["semi", "anti", "inner"])
+def test_a_membership_join_has_no_output_bound(four_devices, monkeypatch,
+                                               join_type):
+    """``MeshJoinExec._run`` builds a semi or anti join's program with no
+    pair bound (None), so ``ballista.join.output_factor`` enters neither its
+    program key nor its remembered bounds: two factors, one program, no
+    ``out_capacity`` on its spans, ``membership=1`` and one
+    ``membership_joins`` a join.  An inner join's bound follows the
+    factor."""
+    keys = []
+    real = mesh_exec._program
+
+    def spy(op, key, build):
+        keys.append(key)
+        return real(op, key, build)
+
+    monkeypatch.setattr(mesh_exec, "_program", spy)
+    mesh_exec._EXCHANGE_NEED.clear()
+    rng = np.random.default_rng(41)
+    probe = {"k": rng.integers(0, 6000, 5000), "v": rng.integers(0, 99, 5000)}
+    build = {"bk": np.arange(3000) * 2, "w": rng.integers(0, 99, 3000)}
+    op = mesh_exec.MeshJoinExec(
+        MemoryScanExec(KEY, pa.table({"k": [0], "v": [0]})),
+        MemoryScanExec(BKEY, pa.table({"bk": [0], "w": [0]})),
+        [(E.Column("k"), E.Column("bk"))], join_type)
+    found = set(probe["k"].tolist()) & set(build["bk"].tolist())
+    hit = np.isin(probe["k"], list(found))
+    for factor in (2, 8):
+        ctx = TaskContext(BallistaConfig(
+            {**PARTITIONED, "ballista.join.output_factor": str(factor)}))
+        t0 = time.time_ns()
+        with span("task t/1/0", "executor", ROOT):
+            (out,) = op._join_batches(_batch(KEY, probe, np.ones(5000, bool)),
+                                      _batch(BKEY, build, np.ones(3000, bool)),
+                                      ctx)
+        (program,) = _join_spans(t0)
+        want = {"semi": int(hit.sum()), "anti": int((~hit).sum()),
+                "inner": int(hit.sum())}[join_type]
+        assert out.num_rows == program.attrs["rows_out"] == want
+        if join_type == "inner":
+            assert program.attrs["out_capacity"] == mesh_exec._pow2(
+                factor * -(-3000 // 4))
+            assert "membership" not in program.attrs
+        else:
+            assert "out_capacity" not in program.attrs
+            assert program.attrs["membership"] == 1
+    # (name, probe send bucket, build send bucket[, pairs]) + what the
+    # program shares across jobs by, beginning with the devices
+    send = ("mesh_join_partitioned", 1024, 512)
+    if join_type == "inner":
+        assert [k[:5] for k in keys] == [send + (2048, 4), send + (8192, 4)]
+        assert "membership_joins" not in op.metrics().values
+    else:
+        assert [k[:4] for k in keys] == [send + (4,)] * 2
+        assert keys[0] == keys[1]   # one program for both factors, shared
+        assert None not in keys[0]
+        assert op.metrics().values["membership_joins"] == 2
+    assert op.metrics().values["partitioned_joins"] == 2
 
 
 def test_send_capacities_follow_live_rows_not_slots(four_devices):
@@ -367,13 +435,15 @@ def test_join_programs_report_their_needs(join_type, variant):
     if join_type in ("inner", "left"):
         bcols["y"] = place(bk * 5)
     build_names = [n for n in bcols if n != "__jk0"]
+    # a semi or anti join expands no pair: it takes no pair bound
+    out_cap = 1 << 14 if join_type in ("inner", "left") else None
     if variant == "partitioned":
         prog = distributed.distributed_hash_join(
-            mesh, 1, ["x"], build_names, join_type, rows, n_build, 1 << 14,
+            mesh, 1, ["x"], build_names, join_type, rows, n_build, out_cap,
             {"y": 0})
     else:
         prog = distributed.distributed_broadcast_join(
-            mesh, 1, ["x"], build_names, join_type, 1 << 14, {"y": 0})
+            mesh, 1, ["x"], build_names, join_type, out_cap, {"y": 0})
     args = (pcols, place(pmask), bcols, place(bmask))
     out_cols, out_mask, stats = prog(*args)
     overflow, p_need, b_need, pairs, rows_out = (int(v) for v in stats)
@@ -388,13 +458,15 @@ def test_join_programs_report_their_needs(join_type, variant):
                  "anti": int((~hit).sum()),
                  "left": n_pairs + int((~hit).sum())}[join_type]
     assert rows_out == want_rows
+    # a semi or anti join asks only whether a key is present: no pair
+    membership = join_type in ("semi", "anti")
     if variant == "broadcast":
         assert (p_need, b_need) == (0, 0)
         per = rows // n_dev
         dev_pairs = [sum(matches.get(k, 0) for k in
                          pk[d * per:(d + 1) * per][pmask[d * per:(d + 1) * per]])
                      for d in range(n_dev)]
-        assert pairs == max(dev_pairs)
+        assert pairs == (0 if membership else max(dev_pairs))
         assert prog.collective_bytes(*args) == n_dev * n_build * (
             8 * len(bcols) + 1)
         return
@@ -410,7 +482,7 @@ def test_join_programs_report_their_needs(join_type, variant):
     assert b_need == fullest(dest_b, bmask, n_build // n_dev)
     dev_pairs = [sum(matches.get(k, 0) for k in lp[dest_p[pmask] == d])
                  for d in range(n_dev)]
-    assert pairs == max(dev_pairs)
+    assert pairs == (0 if membership else max(dev_pairs))
     assert prog.collective_bytes(*args) == n_dev * n_dev * (
         rows * (16 + 1) + n_build * (8 * len(bcols) + 1))
 

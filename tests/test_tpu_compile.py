@@ -395,3 +395,38 @@ def test_mesh_dense_reduce_q1_shape_on_four_devices(topo, tpu_branches):
     print(f"[tpu-compile] q1 mesh program bytes per device: "
           f"args {mem.argument_size_in_bytes}, temp {mem.temp_size_in_bytes}")
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+def test_mesh_semi_join_at_sf10_q4_shards(topo, tpu_branches):
+    """q4's partitioned semi join program at the ``sf10_mesh4_joins`` cell's
+    shapes: ``orders`` compacted to 2^20 slots (2^18 a device) with three
+    columns and its key, ``lineitem``'s key alone over 60 817 408 slots,
+    send buckets of 2^17 probe and 2^23 build rows, so 2^19 probe and 2^25
+    build slots a device after the ``all_to_all``.  The membership path
+    (``kernels.key_membership``) compiles for the chips with no scatter,
+    and what it asks of a chip leaves room beside chip 0's inputs."""
+    from arrow_ballista_tpu.parallel import distributed
+
+    mesh = Mesh(np.asarray(topo.devices), ("part",))
+    rows = NamedSharding(mesh, P("part"))
+    n_p, n_b = 1 << 20, 60_817_408
+    run = distributed.distributed_hash_join(
+        mesh, 1, ["o_orderkey", "o_orderdate", "o_orderpriority"], [],
+        "semi", 1 << 17, 1 << 23, None, {})
+    assert run.name == "mesh_join_partitioned__semi"
+    pcols = {"o_orderkey": sds((n_p,), jnp.int64, rows),
+             "o_orderdate": sds((n_p,), jnp.int32, rows),
+             "o_orderpriority": sds((n_p,), jnp.int32, rows),
+             "__jk0": sds((n_p,), jnp.int64, rows)}
+    bcols = {"__jk0": sds((n_b,), jnp.int64, rows)}
+    compiled = compile_for_chip(
+        "mesh semi join, q4 at SF10", run.jit.__wrapped__, pcols,
+        sds((n_p,), jnp.bool_, rows), bcols, sds((n_b,), jnp.bool_, rows))
+    text = compiled.as_text()
+    assert "all-to-all" in text and not has_scatter(text)
+    mem = compiled.memory_analysis()
+    print(f"[tpu-compile] q4 semi join bytes per device: "
+          f"args {mem.argument_size_in_bytes}, temp {mem.temp_size_in_bytes}, "
+          f"out {mem.output_size_in_bytes}")
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes < 4 << 30
